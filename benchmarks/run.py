@@ -25,6 +25,7 @@ from benchmarks import (arena, bound_check, comm_overhead, completion_time,
                         round_engine, scenarios, serving, staleness_sweep,
                         v_sweep)
 from benchmarks.common import header, records
+from repro.launch.cache import enable_compile_cache
 
 SUITES = {
     # paper Fig. 4 / Fig. 20
@@ -70,6 +71,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None, choices=list(SUITES))

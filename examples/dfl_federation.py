@@ -9,6 +9,7 @@ import argparse
 
 from repro.core.baselines import get_mechanism
 from repro.dfl.simulator import SimConfig, run_simulation
+from repro.launch.cache import enable_compile_cache
 
 
 def first_time_to(hist, target):
@@ -19,6 +20,7 @@ def first_time_to(hist, target):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sim-time", type=float, default=1500.0)
     ap.add_argument("--workers", type=int, default=30)

@@ -14,10 +14,12 @@ import argparse
 
 from repro.core.protocol import DySTop
 from repro.dfl import lm_worker as LW
+from repro.launch.cache import enable_compile_cache
 from repro.models import registry as R
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=R.ARCH_IDS)
     ap.add_argument("--workers", type=int, default=8)

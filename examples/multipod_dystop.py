@@ -22,11 +22,14 @@ from repro.core.protocol import dystop_pod_mix
 from repro.core.staleness import StalenessState
 from repro.core.waa import worker_activation
 from repro.dfl import worker as WK
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def main():
+    enable_compile_cache()
     n_pods = 4
-    mesh = jax.make_mesh((n_pods, 2), ("pod", "data"))
+    mesh = make_mesh((n_pods, 2), ("pod", "data"))
 
     # four pod replicas, intentionally divergent, sharded over the pod axis
     keys = jax.random.split(jax.random.PRNGKey(0), n_pods)

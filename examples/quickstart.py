@@ -9,9 +9,11 @@ the paper reports (accuracy vs simulated wall-clock, communication, staleness).
 from repro.core.protocol import DySTop
 from repro.dfl.simulator import SimConfig, run_simulation
 from repro.kernels.config import KernelConfig
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = SimConfig(
         n_workers=20,
         n_rounds=80,

@@ -17,9 +17,11 @@ from repro.launch import steps as S
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.optim import get_optimizer
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b", choices=R.ARCH_IDS)
     ap.add_argument("--steps", type=int, default=40)

@@ -11,9 +11,11 @@ import numpy as np
 
 from repro.models import registry as R
 from repro.serving import GenerationConfig, ServeEngine
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=R.ARCH_IDS)
     ap.add_argument("--slots", type=int, default=4)

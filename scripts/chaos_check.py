@@ -17,17 +17,23 @@ The comparison is kill-point-independent: wherever the SIGKILL lands, the
 resumed run continues to the same ``n_rounds``, so the final histories must
 match the reference exactly.  Resuming from the OLDEST retained snapshot
 (not the newest) maximizes the replayed span under test.
+
+One process per device: the children inherit the parent's backend (the
+chip on a TPU host, the CPU under ``JAX_PLATFORMS=cpu``), and a device
+belongs to one process at a time.  So every plane's child runs and is
+killed first, while the parent has not initialised a backend (importing
+jax does not); only then does the parent run the references and resumes.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import shutil
 import signal
 import subprocess
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
@@ -80,17 +86,13 @@ def child_main(plane: str, ckpt_dir: str) -> None:
     RUNNERS[plane][0](ckpt_dir=ckpt_dir)
 
 
-def kill_and_resume(plane: str) -> dict:
-    """One plane's full cycle; returns the artifact record."""
+def kill_child(plane: str, ckpt_dir: pathlib.Path) -> dict:
+    """Run one plane's checkpointing child and SIGKILL it at its first
+    snapshot; returns the partial artifact record.  Touches no backend."""
     from repro.checkpoint.io import list_checkpoints
-    runner, model_fields = RUNNERS[plane]
-    ckpt_dir = pathlib.Path(f"/tmp/chaos_check_{plane}_{os.getpid()}")
-    shutil.rmtree(ckpt_dir, ignore_errors=True)
     rec = {"plane": plane, "passed": False, "killed_mid_run": False}
-
     child = subprocess.Popen(
-        [sys.executable, __file__, "--child", plane, "--dir", str(ckpt_dir)],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        [sys.executable, __file__, "--child", plane, "--dir", str(ckpt_dir)])
     try:
         deadline = time.time() + 600
         while time.time() < deadline:
@@ -107,7 +109,13 @@ def kill_and_resume(plane: str) -> dict:
         if child.poll() is None:
             child.kill()
             child.wait()
+    return rec
 
+
+def resume_and_compare(plane: str, ckpt_dir: pathlib.Path, rec: dict) -> dict:
+    """Reference run + resume from the killed child's oldest snapshot."""
+    from repro.checkpoint.io import list_checkpoints
+    runner, model_fields = RUNNERS[plane]
     cks = list_checkpoints(ckpt_dir)
     if not cks:
         rec["error"] = "child produced no checkpoint within the deadline"
@@ -133,7 +141,6 @@ def kill_and_resume(plane: str) -> dict:
     rec["mismatches"] = mismatches
     rec["passed"] = not mismatches
     rec["final_round"] = ref.rounds[-1] if ref.rounds else None
-    shutil.rmtree(ckpt_dir, ignore_errors=True)
     return rec
 
 
@@ -148,7 +155,13 @@ def main() -> int:
         child_main(args.child, args.dir)
         return 0
     planes = ["sim", "lm"] if args.plane == "both" else [args.plane]
-    records = [kill_and_resume(p) for p in planes]
+    root = pathlib.Path(tempfile.mkdtemp(prefix="chaos_check_"))
+    try:
+        dirs = {p: root / p for p in planes}
+        killed = {p: kill_child(p, dirs[p]) for p in planes}
+        records = [resume_and_compare(p, dirs[p], killed[p]) for p in planes]
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
     ok = all(r["passed"] for r in records)
     artifact = {"suite": "chaos_check", "passed": ok, "records": records}
     print(json.dumps(artifact, indent=2))

@@ -140,7 +140,7 @@ def dystop_pod_mix(stacked_params, W: jnp.ndarray, mesh):
             return mixed[None].astype(x.dtype)
 
         return shard_map(inner, mesh=mesh,
-                         in_specs=(P(), spec), out_specs=spec,
-                         check_vma=False)(W.astype(jnp.float32), leaf)
+                         in_specs=(P(), spec), out_specs=spec)(
+            W.astype(jnp.float32), leaf)
 
     return jax.tree.map(mix_leaf, stacked_params)

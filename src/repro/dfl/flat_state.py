@@ -16,6 +16,9 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
+
+from repro.sharding.rules import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +83,47 @@ def unravel_row(vec: jnp.ndarray, spec: FlatSpec) -> Any:
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
+def take_rows(buf: jnp.ndarray, ids: jnp.ndarray, shd=None) -> jnp.ndarray:
+    """``buf[ids]`` for a wide (N, P) buffer, as a loop of row slices.
+
+    XLA's TPU gather emits code linear in the row width: gathering rows of
+    smollm-135m's (N, 134.5M) buffer takes minutes to compile, while a
+    ``fori_loop`` of ``dynamic_slice``s compiles in constant time and copies
+    the same values.  ``ids`` must be in range.  With ``shd`` (a row-sharded
+    ``sharding.rules.FleetSharding`` buffer) each shard slices the rows it
+    holds, zeroes the others, and one ``psum`` assembles the replicated
+    (k, P) result — every row has exactly one nonzero term, so the sum is
+    the row itself.
+    """
+    k = ids.shape[0]
+
+    def local(x, rows, acc, valid=None):
+        def body(i, acc):
+            row = jax.lax.dynamic_slice_in_dim(x, rows[i], 1, 0)
+            if valid is not None:
+                row = jnp.where(valid[i], row, jnp.zeros_like(row))
+            return jax.lax.dynamic_update_slice_in_dim(acc, row, i, 0)
+        return jax.lax.fori_loop(0, k, body, acc)
+
+    out = jnp.zeros((k,) + buf.shape[1:], buf.dtype)
+    if shd is None:
+        return local(buf, ids.astype(jnp.int32), out)
+
+    ax = shd.axis
+
+    def fn(x_loc, ids_rep):
+        blk = x_loc.shape[0]
+        rows = ids_rep.astype(jnp.int32) - jax.lax.axis_index(ax) * blk
+        valid = (rows >= 0) & (rows < blk)
+        acc = jax.lax.pcast(out, ax, to="varying")
+        return jax.lax.psum(
+            local(x_loc, jnp.clip(rows, 0, blk - 1), acc, valid), ax)
+
+    return shard_map(fn, mesh=shd.mesh,
+                     in_specs=(PartitionSpec(ax), PartitionSpec()),
+                     out_specs=PartitionSpec())(buf, ids)
+
+
 def weighted_row(buf: jnp.ndarray, alpha: jnp.ndarray) -> jnp.ndarray:
     """Weight-averaged (P,) parameter vector straight from the flat buffer.
 
@@ -125,15 +169,3 @@ class FleetSpec:
     params: FlatSpec
     opt: FlatSpec
 
-
-def flatten_fleet(stacked_params: Any, stacked_opt: Any
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray, FleetSpec]:
-    """Stacked (params, opt) pytrees -> ((N, P), (N, S) f32 buffers, spec).
-
-    Integer leaves (optimizer step counters) are stored as f32 — exact for
-    any realistic round count (< 2^24) — and cast back by ``unflatten`` /
-    ``unravel_row`` through the spec's recorded dtypes.
-    """
-    pbuf, pspec = flatten_stacked(stacked_params)
-    obuf, ospec = flatten_stacked(stacked_opt)
-    return pbuf, obuf, FleetSpec(params=pspec, opt=ospec)

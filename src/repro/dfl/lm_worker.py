@@ -17,11 +17,11 @@ simulation plane:
     ``aggregation.prefer_cols`` traffic model and the ``mix_is_train``
     fusion feeding Eq. 4 output straight into Eq. 5.
   * local training is a GATHERED-ACTIVE-ROW step: only the k activated
-    workers' rows are gathered, vmapped through AD + the generic
+    workers' rows are read, one after another, through AD + the generic
     ``Optimizer.update`` (adam/sgd/adafactor — any state pytree), and
-    scattered back.  The pre-PR-4 architecture (per-call-flatten mixing +
-    train-all-N-and-mask step) is kept as the flag-gated correctness oracle
-    (``LMRunConfig.resident_fleet=False``).
+    written back in place.  The pre-PR-4 architecture (per-call-flatten
+    mixing + train-all-N-and-mask step) is kept as the flag-gated
+    correctness oracle (``LMRunConfig.resident_fleet=False``).
 
 CPU-budget note: use smoke-geometry configs (``registry.get_smoke_config``)
 for interactive runs; the code path is identical for full configs on real
@@ -39,6 +39,7 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.checkpoint import io as CIO
 from repro.configs.base import ModelConfig
@@ -55,6 +56,7 @@ from repro.dfl.pipeline import DispatchPipeline
 from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 from repro.optim import Optimizer, get_optimizer
+from repro.sharding.rules import shard_map
 
 Params = Dict[str, Any]
 
@@ -118,16 +120,28 @@ def _cached_optimizer(name: str, lr: float) -> Optimizer:
 def init_fleet(cfg: ModelConfig, n_workers: int, optimizer: str = "adam",
                lr: float = 1e-3, seed: int = 0) -> LMFleet:
     """All workers start from w_0 (paper Thm. 1's shared init) — flattened
-    ONCE into the resident buffers; no pytree survives past this call."""
+    ONCE into the resident buffers; no pytree survives past this call.
+
+    One worker's params and optimizer state are raveled into a row each and
+    the row is broadcast to N: stacking N pytree copies first would hold
+    the fleet twice over, which does not fit one chip at published widths.
+    """
     opt = _cached_optimizer(optimizer, lr)
     params, _ = R.init_params(cfg, jax.random.PRNGKey(seed))
     opt_state = opt.init(params)
 
-    def stack(tree):
-        return jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n_workers,) + l.shape).copy(), tree)
+    def stacked_spec(tree):
+        return FS.spec_of(jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct((n_workers,) + l.shape, l.dtype),
+            tree))
 
-    pbuf, obuf, spec = FS.flatten_fleet(stack(params), stack(opt_state))
+    spec = FS.FleetSpec(params=stacked_spec(params),
+                        opt=stacked_spec(opt_state))
+    prow = FS.ravel_row(params, spec.params)
+    orow = FS.ravel_row(opt_state, spec.opt)
+    del params, opt_state
+    pbuf = jnp.broadcast_to(prow, (n_workers, prow.shape[0]))
+    obuf = jnp.broadcast_to(orow, (n_workers, orow.shape[0]))
     return LMFleet(cfg=cfg, pbuf=pbuf, obuf=obuf, spec=spec, optimizer=opt,
                    n_workers=n_workers)
 
@@ -231,9 +245,10 @@ def fleet_mix(fleet: LMFleet, W: np.ndarray,
 
 def make_fleet_step(fleet: LMFleet):
     """Masked per-worker train step over STACKED pytrees: trains ALL N
-    workers and masks the inactive updates away.  The pre-PR-4 oracle the
-    gathered-active-row engine is pinned against — O(N) model-plane work per
-    round regardless of how few workers activated."""
+    workers, one after another as the resident engine does, and masks the
+    inactive updates away.  The pre-PR-4 oracle the gathered-active-row
+    engine is pinned against — O(N) model-plane work per round regardless of
+    how few workers activated."""
     return _fleet_step(fleet.cfg, fleet.optimizer)
 
 
@@ -254,7 +269,7 @@ def _fleet_step(cfg: ModelConfig, opt: Optimizer):
         return (jax.tree.map(mix, new_p, params),
                 jax.tree.map(mix, new_s, opt_state), loss)
 
-    return jax.jit(jax.vmap(one))
+    return jax.jit(lambda *xs: jax.lax.map(lambda x: one(*x), xs))
 
 
 def fleet_eval_stacked(cfg: ModelConfig, stacked_params: Params,
@@ -309,10 +324,10 @@ class LMEngine:
     ``dispatch_chunk`` executes a bucket-uniform chunk of ``PlannedRound``s
     as ONE donated ``lax.scan``: per scan step, Eq. 4 mixes the k
     non-identity rows (row- or column-sparse exactly like the simulation
-    plane, via ``worker.mix_flat`` / ``mix_flat_cols``), then the gathered
-    activated rows of BOTH buffers run one AD train step through the generic
-    ``Optimizer.update`` and scatter back — inactive rows are never touched,
-    so model-plane work is O(k), not O(N).  Under the ``mix_is_train``
+    plane, via ``worker.mix_flat`` / ``mix_flat_cols``), then the activated
+    rows of BOTH buffers run one AD train step each through the generic
+    ``Optimizer.update`` and are written back — inactive rows are never
+    touched, so model-plane work is O(k), not O(N).  Under the ``mix_is_train``
     fusion (mix rows == train rows, every DySTop round) the mixed sub-buffer
     feeds the train step directly, skipping the intermediate scatter.
 
@@ -324,8 +339,7 @@ class LMEngine:
     sharded: ``pbuf``/``obuf`` stay row-partitioned over the fleet axis
     across dispatches, the mix lowers to the collective contractions of
     ``kernels.aggregate`` (union all_gather / shard-local slabs + psum), and
-    the gathered-row train step splits its k workers over the shards
-    whenever k divides evenly.
+    each shard trains the activated rows it holds.
 
     ``pregather=True`` in ``dispatch_chunk`` gathers the k activated batch
     rows on HOST before the H2D transfer — batches ship (H, k, B, S) instead
@@ -342,34 +356,90 @@ class LMEngine:
         self.shd = shd
         self._mega_cache: dict = {}
 
-    # -- gathered-active-row train: vmap over the k activated workers only --
-    def _train_rows(self, psub, osub, mask, tok, lab):
+    def _train_one(self, pvec, ovec, m, t, l):
+        """One worker's AD train step on its flat rows; a padding row
+        (``m == 0``) comes back bit-identical."""
         cfg, opt, spec = self.cfg, self.opt, self.spec
-        if self.shd is not None:
-            sub_shd = self.shd.for_rows(psub.shape[0])
-            psub, osub, tok, lab = (
-                jax.lax.with_sharding_constraint(x, sub_shd)
-                for x in (psub, osub, tok, lab))
+        params = FS.unravel_row(pvec, spec.params)
+        state = FS.unravel_row(ovec, spec.opt)
+        batch = {"tokens": t, "labels": l,
+                 "loss_mask": jnp.ones(t.shape, jnp.float32)}
+        (loss, _), grads = jax.value_and_grad(
+            lambda p: R.compute_loss(cfg, p, batch), has_aux=True)(params)
+        new_p, new_s = opt.update(grads, state, params)
+        keep = m > 0
+        return (jnp.where(keep, FS.ravel_row(new_p, spec.params), pvec),
+                jnp.where(keep, FS.ravel_row(new_s, spec.opt), ovec),
+                loss * m)
 
-        def one(pvec, ovec, m, t, l):
-            params = FS.unravel_row(pvec, spec.params)
-            state = FS.unravel_row(ovec, spec.opt)
-            batch = {"tokens": t, "labels": l,
-                     "loss_mask": jnp.ones(t.shape, jnp.float32)}
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: R.compute_loss(cfg, p, batch),
-                has_aux=True)(params)
-            new_p, new_s = opt.update(grads, state, params)
-            keep = m > 0          # padding rows: bit-identical no-op
-            return (jnp.where(keep, FS.ravel_row(new_p, spec.params), pvec),
-                    jnp.where(keep, FS.ravel_row(new_s, spec.opt), ovec),
-                    loss * m)
+    def _train_rows(self, pbuf, obuf, sub, tids, mask, tok, lab):
+        """Train the k gathered rows one after another, each written back in
+        place; returns (pbuf, obuf, (N,) losses).  ``sub`` holds the mixed
+        rows of the fused path (None: read row ``tids[i]`` of ``pbuf``).
 
-        return jax.vmap(one)(psub, osub, mask, tok, lab)
+        In sequence, not vmapped, for two reasons.  Memory: a vmap over k
+        workers keeps k copies of every per-worker temporary (bf16 params,
+        grads, updated f32 rows), which for smollm-135m at its published
+        widths does not fit one 16 GB chip; in sequence one worker's are live
+        at a time.  Numerics: every row runs the same program whatever the
+        bucket size k, so ``min_bucket`` and the per-call-flatten oracle
+        (which maps over workers the same way) round alike.  Padding ids
+        repeat an idle row whose write-back is its own value, so the sequence
+        equals a batched scatter.
+
+        With ``shd`` each shard runs the loop over all k ids and trains the
+        rows it holds, so ``pbuf``/``obuf`` rows never leave their shard; the
+        per-row losses (zero elsewhere) meet in one psum.
+        """
+        n = pbuf.shape[0]
+        shd = self.shd
+
+        def loop(pb, ob, sub, first, blk, losses):
+            def body(i, carry):
+                r = tids[i] - first
+
+                def train(c):
+                    pb, ob, ls = c
+                    pvec = (sub[i] if sub is not None else
+                            jax.lax.dynamic_index_in_dim(pb, r, 0, False))
+                    ovec = jax.lax.dynamic_index_in_dim(ob, r, 0, False)
+                    new_p, new_o, loss = self._train_one(pvec, ovec, mask[i],
+                                                         tok[i], lab[i])
+                    put = jax.lax.dynamic_update_index_in_dim
+                    return (put(pb, new_p, r, 0), put(ob, new_o, r, 0),
+                            ls.at[tids[i]].set(loss))
+
+                if blk is None:
+                    return train(carry)
+                return jax.lax.cond((r >= 0) & (r < blk), train,
+                                    lambda c: c, carry)
+
+            return jax.lax.fori_loop(0, tids.shape[0], body, (pb, ob, losses))
+
+        losses = jnp.zeros((n,), jnp.float32)
+        if shd is None:
+            return loop(pbuf, obuf, sub, 0, None, losses)
+
+        ax = shd.axis
+
+        def per_shard(pb, ob, *sub_):
+            blk = pb.shape[0]
+            first = jax.lax.axis_index(ax) * blk
+            pb, ob, ls = loop(pb, ob, sub_[0] if sub_ else None, first, blk,
+                              losses)
+            return pb, ob, jax.lax.psum(ls, ax)
+
+        # check_vma=False: the model's Pallas kernels (KernelConfig pallas)
+        # run inside, and under JAX 0.9 the check needs every pallas_call
+        # output shape to declare its varying axes
+        rows, rep = PartitionSpec(ax), PartitionSpec()
+        args = (pbuf, obuf) + ((sub,) if sub is not None else ())
+        return shard_map(per_shard, mesh=shd.mesh,
+                         in_specs=(rows, rows) + (rep,) * (len(args) - 2),
+                         out_specs=(rows, rows, rep), check_vma=False)(*args)
 
     def _round_body(self, pbuf, obuf, w, mids, cids, tids, mask, tok, lab,
                     fuse: bool, pregather: bool):
-        n = pbuf.shape[0]
         shd = self.shd
 
         def pin(pb, ob, ls):
@@ -380,7 +450,6 @@ class LMEngine:
                     jax.lax.with_sharding_constraint(ls, shd.replicated()))
 
         k_mix, k_train = w.shape[0], tids.shape[0]
-        losses = jnp.zeros((n,), jnp.float32)
         # pregathered batches arrive (k, B, S) in train-row order; otherwise
         # the activated rows are gathered from the full-N batch on device
         tok_k = tok if pregather else (tok[tids] if k_train else tok)
@@ -388,22 +457,17 @@ class LMEngine:
         if fuse and k_mix and k_train:
             # mix rows == train rows: Eq. 4 output feeds Eq. 5 directly
             sub = WK._mix_rows(pbuf, w, cids, self.kernels, shd)
-            new_p, new_o, sl = self._train_rows(sub, obuf[tids], mask,
-                                                tok_k, lab_k)
-            return pin(pbuf.at[tids].set(new_p), obuf.at[tids].set(new_o),
-                       losses.at[tids].set(sl))
+            return pin(*self._train_rows(pbuf, obuf, sub, tids, mask,
+                                         tok_k, lab_k))
         if k_mix:
             pbuf = (WK.mix_flat_cols(pbuf, w, mids, cids, self.kernels,
                                      shd=shd)
                     if cids is not None
                     else WK.mix_flat(pbuf, w, mids, self.kernels, shd=shd))
         if k_train:
-            new_p, new_o, sl = self._train_rows(pbuf[tids], obuf[tids], mask,
-                                                tok_k, lab_k)
-            pbuf = pbuf.at[tids].set(new_p)
-            obuf = obuf.at[tids].set(new_o)
-            losses = losses.at[tids].set(sl)
-        return pin(pbuf, obuf, losses)
+            return pin(*self._train_rows(pbuf, obuf, None, tids, mask,
+                                         tok_k, lab_k))
+        return pin(pbuf, obuf, jnp.zeros((pbuf.shape[0],), jnp.float32))
 
     def _mega(self, col_sparse: bool, fuse: bool, pregather: bool):
         key = (col_sparse, fuse, pregather)
@@ -500,16 +564,29 @@ class LMEngine:
 
     @functools.cached_property
     def eval_global(self):
-        """Jitted Eq. 11 eval: ``alpha @ pbuf`` + unravel + one forward."""
-        cfg, spec = self.cfg, self.spec
+        """Jitted Eq. 11 eval: ``alpha @ pbuf`` + unravel + one forward.
+
+        With ``shd`` the forward runs replicated inside a ``shard_map``:
+        the partitioner cannot split the model's Mosaic kernels, so every
+        device computes the same loss on the replicated global row
+        (``check_vma=False`` for the reason in ``_train_rows``)."""
+        cfg, spec, shd = self.cfg, self.spec, self.shd
+
+        def loss_of(row, tokens, labels):
+            batch = {"tokens": tokens, "labels": labels,
+                     "loss_mask": jnp.ones(tokens.shape, jnp.float32)}
+            loss, _ = R.compute_loss(cfg, FS.unravel_row(row, spec.params),
+                                     batch)
+            return loss
+
+        if shd is not None:
+            rep = PartitionSpec()
+            loss_of = shard_map(loss_of, mesh=shd.mesh, in_specs=(rep,) * 3,
+                                out_specs=rep, check_vma=False)
 
         @jax.jit
         def ev(pbuf, alpha, tokens, labels):
-            gm = FS.unravel_row(FS.weighted_row(pbuf, alpha), spec.params)
-            batch = {"tokens": tokens, "labels": labels,
-                     "loss_mask": jnp.ones(tokens.shape, jnp.float32)}
-            loss, _ = R.compute_loss(cfg, gm, batch)
-            return loss
+            return loss_of(FS.weighted_row(pbuf, alpha), tokens, labels)
 
         return ev
 

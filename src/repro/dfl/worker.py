@@ -233,7 +233,7 @@ def _mix_rows(buf: jnp.ndarray, w_rows: jnp.ndarray, col_ids,
         return AGG.aggregate_rows(w_rows, buf, p_blk=kernels.agg_p_blk,
                                   interpret=interp)
     if col_ids is not None:
-        return w_rows.astype(jnp.float32) @ buf[col_ids]
+        return w_rows.astype(jnp.float32) @ FS.take_rows(buf, col_ids)
     return w_rows.astype(jnp.float32) @ buf
 
 
@@ -507,7 +507,7 @@ def _mix_train_body(buf: jnp.ndarray, w_rows: jnp.ndarray,
     losses = jnp.zeros((n,), jnp.float32)
     if k_train == 0:
         return buf, losses
-    new_sub, sub_loss = train_rows(buf[train_row_ids])
+    new_sub, sub_loss = train_rows(FS.take_rows(buf, train_row_ids, shd))
     buf = _pin_rows(buf.at[train_row_ids].set(new_sub), shd)
     if with_losses:
         losses = losses.at[train_row_ids].set(sub_loss * train_mask)

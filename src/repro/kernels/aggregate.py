@@ -29,11 +29,16 @@ column ids contribute exactly 0.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec
+
+from repro.dfl.flat_state import take_rows
+from repro.kernels.config import resolve_interpret
+from repro.sharding.rules import shard_map
 
 
 def _aggregate_kernel(w_ref, x_ref, o_ref):
@@ -41,25 +46,18 @@ def _aggregate_kernel(w_ref, x_ref, o_ref):
                          preferred_element_type=jnp.float32)
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Auto-select interpret mode: compile natively on TPU, interpret elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
 def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
-              interpret: Optional[bool] = None) -> jnp.ndarray:
+              interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Y = W @ X.  W: (N, N) f32; X: (N, P) f32 -> (N, P) f32."""
     n, p = X.shape
     assert W.shape == (n, n), (W.shape, X.shape)
-    return _panel_matmul(W, X, p_blk, _resolve_interpret(interpret))
+    return _panel_matmul(W, X, p_blk, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
 def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
-                   interpret: Optional[bool] = None) -> jnp.ndarray:
+                   interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Active-row sparse path: Y_rows = W_rows @ X.
 
     W_rows: (k, N) — the k gathered non-identity rows of the mixing matrix;
@@ -69,13 +67,13 @@ def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
     """
     k, n = W_rows.shape
     assert X.shape[0] == n, (W_rows.shape, X.shape)
-    return _panel_matmul(W_rows, X, p_blk, _resolve_interpret(interpret))
+    return _panel_matmul(W_rows, X, p_blk, resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
 def aggregate_rows_cols(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
                         X: jnp.ndarray, p_blk: int = 512,
-                        interpret: Optional[bool] = None) -> jnp.ndarray:
+                        interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Column-sparse Eq. 4: Y_rows = W_sub @ X[col_ids].
 
     W_sub: (k, u) — the k gathered non-identity rows of the mixing matrix
@@ -89,8 +87,8 @@ def aggregate_rows_cols(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
     """
     k, u = W_sub.shape
     assert col_ids.shape == (u,), (W_sub.shape, col_ids.shape)
-    slab = X[col_ids]                           # (u, P) gather, once
-    return _panel_matmul(W_sub, slab, p_blk, _resolve_interpret(interpret))
+    slab = take_rows(X, col_ids)                # (u, P) gather, once
+    return _panel_matmul(W_sub, slab, p_blk, resolve_interpret(interpret))
 
 
 # --------------------------------------------------------------------------- #
@@ -132,14 +130,15 @@ def aggregate_rows_cols_sharded(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
     cross-shard traffic floor of one DySTop round: u rows in, k/S rows of
     compute per shard, zero collective on the scatter for home rows.
     """
-    slab = jax.lax.with_sharding_constraint(X[col_ids], shd.replicated())
+    slab = jax.lax.with_sharding_constraint(take_rows(X, col_ids, shd),
+                                            shd.replicated())
     y = W_sub.astype(jnp.float32) @ slab
     return jax.lax.with_sharding_constraint(y, shd.for_rows(W_sub.shape[0]))
 
 
 def aggregate_rows_sharded_kernel(W_rows: jnp.ndarray, X: jnp.ndarray,
                                   shd, p_blk: int = 512,
-                                  interpret: Optional[bool] = None
+                                  interpret: Union[str, bool] = "auto"
                                   ) -> jnp.ndarray:
     """shard_map Pallas twin of ``aggregate_rows_sharded``.
 
@@ -147,13 +146,12 @@ def aggregate_rows_sharded_kernel(W_rows: jnp.ndarray, X: jnp.ndarray,
     textbook inner-product split: each shard runs the VMEM panel schedule on
     its resident ``(k, N_s) @ (N_s, P)`` slab of the row-partitioned buffer,
     then one ``psum`` over the fleet axis completes Eq. 4 and replicates the
-    (k, P) mixed rows.  ``check_vma=False`` because ``pallas_call`` has no
-    replication-tracking rule under the jax 0.4.x check; the psum makes the
-    replication claim true by construction.
+    (k, P) mixed rows.  ``check_vma=False``: under JAX 0.9 the check needs
+    every ``pallas_call`` output shape to declare its varying axes, which a
+    kernel written for one device does not; the psum makes the replication
+    claim true by construction.
     """
-    from jax.sharding import PartitionSpec
-    from repro.sharding.rules import shard_map
-    interp = _resolve_interpret(interpret)
+    interp = resolve_interpret(interpret)
     ax = shd.axis
 
     def fn(w_loc, x_loc):
@@ -170,62 +168,50 @@ def aggregate_rows_sharded_kernel(W_rows: jnp.ndarray, X: jnp.ndarray,
 def aggregate_rows_cols_sharded_kernel(W_sub: jnp.ndarray,
                                        col_ids: jnp.ndarray, X: jnp.ndarray,
                                        shd, p_blk: int = 512,
-                                       interpret: Optional[bool] = None
+                                       interpret: Union[str, bool] = "auto"
                                        ) -> jnp.ndarray:
     """shard_map Pallas twin of ``aggregate_rows_cols_sharded``.
 
-    Collective schedule (mirrors the GSPMD twin's traffic floor): each shard
-    masks the union gather to its resident row block — ``col_ids`` shifted
-    into local coordinates, out-of-block entries contributing zeros — and one
-    ``psum`` assembles the replicated (u, P) slab from exactly u rows of
-    cross-shard traffic.  The ``(k, u) @ (u, P)`` panel contraction then runs
-    per shard: over the k/S home output rows when k divides the mesh (the
-    scatter back is collective-free), else replicated whole, matching
-    ``FleetSharding.for_rows``.
+    Collective schedule (mirrors the GSPMD twin's traffic floor): the union
+    gather is ``take_rows`` over the row-sharded buffer — each shard slices
+    the union rows it holds, zeroes the rest, and one ``psum`` assembles the
+    replicated (u, P) slab from exactly u rows of cross-shard traffic.  The
+    ``(k, u) @ (u, P)`` panel contraction then runs per shard: over the k/S
+    home output rows when k divides the mesh (the scatter back is
+    collective-free), else replicated whole, matching
+    ``FleetSharding.for_rows``.  ``check_vma=False`` on the panel's
+    shard_map, for the reason given in ``aggregate_rows_sharded_kernel``.
     """
-    from jax.sharding import PartitionSpec
-    from repro.sharding.rules import shard_map
-    interp = _resolve_interpret(interpret)
-    ax = shd.axis
+    interp = resolve_interpret(interpret)
     k = W_sub.shape[0]
-    out_rows = bool(k) and k % shd.n_shards == 0
-
-    def fn(w_loc, cid, x_loc):
-        blk = x_loc.shape[0]
-        shard = jax.lax.axis_index(ax)
-        local = cid.astype(jnp.int32) - shard * blk
-        inb = (local >= 0) & (local < blk)
-        rows = x_loc[jnp.clip(local, 0, blk - 1)].astype(jnp.float32)
-        slab = jax.lax.psum(jnp.where(inb[:, None], rows, 0.0), ax)
-        return _panel_matmul(w_loc, slab, p_blk, interp)
-
-    row_spec = PartitionSpec(ax, None) if out_rows else PartitionSpec()
-    y = shard_map(fn, mesh=shd.mesh,
-                  in_specs=(row_spec, PartitionSpec(), PartitionSpec(ax, None)),
+    slab = take_rows(X, col_ids, shd).astype(jnp.float32)
+    row_spec = (PartitionSpec(shd.axis, None) if k and k % shd.n_shards == 0
+                else PartitionSpec())
+    y = shard_map(lambda w_loc, s: _panel_matmul(w_loc, s, p_blk, interp),
+                  mesh=shd.mesh, in_specs=(row_spec, PartitionSpec()),
                   out_specs=row_spec, check_vma=False)(
-        W_sub.astype(jnp.float32), col_ids, X)
+        W_sub.astype(jnp.float32), slab)
     return jax.lax.with_sharding_constraint(y, shd.for_rows(k))
 
 
 def _panel_matmul(W: jnp.ndarray, X: jnp.ndarray, p_blk: int,
                   interpret: bool) -> jnp.ndarray:
-    """(k, N) @ (N, P) with W VMEM-resident and X/Y in (·, p_blk) panels."""
+    """(k, N) @ (N, P) with W VMEM-resident and X/Y in (·, p_blk) panels.
+
+    The grid covers P with ``cdiv`` panels and no padding copy: output
+    columns depend only on their own X column, so the out-of-bounds lanes of
+    a ragged last panel never reach a stored column."""
     k, n = W.shape
     p = X.shape[1]
-    pad = (-p) % p_blk
-    if pad:
-        X = jnp.pad(X, ((0, 0), (0, pad)))
-    padded_p = p + pad
-    grid = (padded_p // p_blk,)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _aggregate_kernel,
-        grid=grid,
+        grid=(pl.cdiv(p, p_blk),),
+        name="dystop_aggregate_panel",
         in_specs=[
             pl.BlockSpec((k, n), lambda i: (0, 0)),          # W resident
             pl.BlockSpec((n, p_blk), lambda i: (0, i)),      # X panel
         ],
         out_specs=pl.BlockSpec((k, p_blk), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((k, padded_p), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, p), jnp.float32),
         interpret=interpret,
     )(W.astype(jnp.float32), X.astype(jnp.float32))
-    return out[:, :p]

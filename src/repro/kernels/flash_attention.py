@@ -13,12 +13,14 @@ lower on the host backend); both share the ``ref.py`` oracle.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.config import resolve_interpret
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -80,7 +82,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """q, k, v: (B, H, S, D) -> (B, H, S, D).  GQA callers broadcast kv heads."""
     b, h, s, d = q.shape
     assert k.shape == v.shape == (b, h, s, d)
@@ -102,6 +104,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out = pl.pallas_call(
         kernel,
         grid=grid,
+        name="flash_attention",
         in_specs=[
             pl.BlockSpec((1, 1, blk_q, d), lambda b_, h_, q_, k_: (b_, h_, q_, 0)),
             pl.BlockSpec((1, 1, blk_k, d), lambda b_, h_, q_, k_: (b_, h_, k_, 0)),
@@ -114,6 +117,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((blk_q, _LANES), jnp.float32),   # running denom l
             pltpu.VMEM((blk_q, d), jnp.float32),        # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
     return out[:, :, :s, :]

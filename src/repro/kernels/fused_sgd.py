@@ -5,28 +5,38 @@ The sim plane's training hot spot is ``local_sgd_flat_fused`` in
 ``local_steps`` SGD steps on a 3-layer relu MLP.  The jnp lowering is a chain
 of batched tiny gemms — every step re-reads and re-writes the (k, P) weight
 slab through HBM.  This kernel makes the weights RESIDENT: grid (k,), one
-worker row per program, the (1, P) buffer block loaded into VMEM once,
-sliced into the six MLP leaves, carried through the statically-unrolled step
-loop as values (registers/VMEM), and written back exactly once.  Per-worker
-minibatches for all steps ride in as one (1, steps, batch, dim) block.
+worker per program, its six MLP leaves loaded into VMEM once, carried through
+the statically-unrolled step loop as values, and written back exactly once.
+Per-worker minibatches for all steps ride in as one (1, steps, batch, dim)
+block.
+
+TPU tiling: Mosaic wants the last two dims of every block to be (8, 128)
+multiples or the whole array dims, and cannot reshape a lane vector into a
+matrix in VMEM.  So the (k, P) rows are split into their leaves by static
+slices outside the kernel (``flat_state.unflatten``), every operand carries
+a unit axis that makes its block's last two dims whole (biases (k, 1, h),
+labels (k, steps, batch, 1), the update scale (k, 1, 1)), all values in the
+kernel stay 2-D, and transposed products are ``dot_general`` contractions.
 
 Numerics mirror the manual-backward oracle op for op — same forward, same
 closed-form ``softmax(logits) - onehot`` cross-entropy backward, same
 ``with_losses`` split (``False`` drops the log-sum-exp chain and reports
 zeros), same zero-scaled update for inactive rows (their buffer row is
-bit-identical out).  The oracle stays the source of truth in tests; interpret
-mode is the CI gate (TPU numbers are a separate claim, docs/BENCHMARKS.md).
+bit-identical out).  The oracle stays the source of truth in tests.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple, Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec
 
-from repro.kernels.aggregate import _resolve_interpret
+from repro.dfl import flat_state as FS
+from repro.kernels.config import resolve_interpret
+from repro.sharding.rules import shard_map
 
 _LEAVES = ("b1", "b2", "b3", "w1", "w2", "w3")   # FlatSpec leaf (sort) order
 
@@ -35,27 +45,29 @@ def _dot(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _make_kernel(steps: int, shapes: tuple, offsets: tuple,
-                 with_losses: bool):
-    shp = dict(zip(_LEAVES, shapes))
-    off = dict(zip(_LEAVES, offsets))
-    d, h = shp["w1"]
-    g = shp["w2"][1]
-    c = shp["w3"][1]
+def _dot_tn(a, b):
+    """a.T @ b, contracting the leading (batch) axis of both."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
-    def kernel(buf_ref, x_ref, y_ref, scale_ref, out_ref, loss_ref):
-        row = buf_ref[0].astype(jnp.float32)                  # (P,) in VMEM
-        b1 = row[off["b1"]:off["b1"] + h]
-        b2 = row[off["b2"]:off["b2"] + g]
-        b3 = row[off["b3"]:off["b3"] + c]
-        w1 = row[off["w1"]:off["w1"] + d * h].reshape(d, h)
-        w2 = row[off["w2"]:off["w2"] + h * g].reshape(h, g)
-        w3 = row[off["w3"]:off["w3"] + g * c].reshape(g, c)
-        s = scale_ref[0, 0]                                   # active * lr
-        losses = []
+
+def _dot_nt(a, b):
+    """a @ b.T, contracting the trailing axis of both."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _make_kernel(steps: int, with_losses: bool):
+    def kernel(b1_ref, b2_ref, b3_ref, w1_ref, w2_ref, w3_ref, x_ref, y_ref,
+               scale_ref, ob1, ob2, ob3, ow1, ow2, ow3, loss_ref):
+        b1, b2, b3 = b1_ref[0], b2_ref[0], b3_ref[0]          # (1, ·)
+        w1, w2, w3 = w1_ref[0], w2_ref[0], w3_ref[0]          # (·, ·)
+        s = scale_ref[0]                                      # (1, 1) active*lr
+        c = w3.shape[1]
+        loss = jnp.zeros((1, 1), jnp.float32)
         for t in range(steps):                    # static, unrolled: weights
             x = x_ref[0, t].astype(jnp.float32)   # stay resident across steps
-            y = y_ref[0, t]
+            y = y_ref[0, t]                       # (batch, 1) int labels
             batch = x.shape[0]
             z1 = _dot(x, w1) + b1
             h1 = jax.nn.relu(z1)
@@ -63,31 +75,36 @@ def _make_kernel(steps: int, shapes: tuple, offsets: tuple,
             h2 = jax.nn.relu(z2)
             logits = _dot(h2, w3) + b3
             onehot = (jax.lax.broadcasted_iota(jnp.int32, (batch, c), 1)
-                      == y[:, None]).astype(jnp.float32)
+                      == y).astype(jnp.float32)
             if with_losses:
                 logp = jax.nn.log_softmax(logits, axis=-1)
-                losses.append(-jnp.sum(logp * onehot, -1).mean())
+                loss = loss - jnp.sum(logp * onehot, keepdims=True) / batch
                 probs = jnp.exp(logp)
             else:
                 probs = jax.nn.softmax(logits, axis=-1)
             dz = (probs - onehot) / batch         # d(mean CE)/d logits
-            g_w3 = _dot(h2.T, dz)
-            g_b3 = dz.sum(0)
-            dh2 = _dot(dz, w3.T) * (z2 > 0)
-            g_w2 = _dot(h1.T, dh2)
-            g_b2 = dh2.sum(0)
-            dh1 = _dot(dh2, w2.T) * (z1 > 0)
-            g_w1 = _dot(x.T, dh1)
-            g_b1 = dh1.sum(0)
+            g_w3 = _dot_tn(h2, dz)
+            g_b3 = jnp.sum(dz, axis=0, keepdims=True)
+            dh2 = _dot_nt(dz, w3) * (z2 > 0)
+            g_w2 = _dot_tn(h1, dh2)
+            g_b2 = jnp.sum(dh2, axis=0, keepdims=True)
+            dh1 = _dot_nt(dh2, w2) * (z1 > 0)
+            g_w1 = _dot_tn(x, dh1)
+            g_b1 = jnp.sum(dh1, axis=0, keepdims=True)
             w1, b1 = w1 - s * g_w1, b1 - s * g_b1
             w2, b2 = w2 - s * g_w2, b2 - s * g_b2
             w3, b3 = w3 - s * g_w3, b3 - s * g_b3
-        out_ref[0, :] = jnp.concatenate(
-            [b1, b2, b3, w1.reshape(-1), w2.reshape(-1), w3.reshape(-1)])
-        loss_ref[0, :] = (jnp.stack(losses) if with_losses
-                          else jnp.zeros((steps,), jnp.float32))
+        ob1[0], ob2[0], ob3[0] = b1, b2, b3
+        ow1[0], ow2[0], ow3[0] = w1, w2, w3
+        loss_ref[0] = loss / steps
 
     return kernel
+
+
+def _whole(shape):
+    """Block over one leading-axis slice holding the trailing dims whole."""
+    zeros = (0,) * (len(shape) - 1)
+    return pl.BlockSpec((1,) + tuple(shape[1:]), lambda i: (i,) + zeros)
 
 
 @functools.partial(jax.jit,
@@ -95,7 +112,7 @@ def _make_kernel(steps: int, shapes: tuple, offsets: tuple,
 def fused_sgd(buf: jnp.ndarray, xb: jnp.ndarray, yb: jnp.ndarray,
               active: jnp.ndarray, spec, lr: float,
               with_losses: bool = True,
-              interpret: Optional[bool] = None
+              interpret: Union[str, bool] = "auto"
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``local_sgd_flat_fused``'s contract on the Pallas kernel plane.
 
@@ -104,46 +121,41 @@ def fused_sgd(buf: jnp.ndarray, xb: jnp.ndarray, yb: jnp.ndarray,
     (k, P) rows and the (k,) per-worker mean loss over steps (zeros when
     ``with_losses=False``).  Requires ``fused_sgd_supported(spec)``.
     """
-    k, p = buf.shape
-    steps, batch = xb.shape[1], xb.shape[2]
-    scale = (active.astype(jnp.float32) * lr).reshape(k, 1)
-    kern = _make_kernel(steps, tuple(spec.shapes), tuple(spec.offsets),
-                        with_losses)
-    out, step_losses = pl.pallas_call(
-        kern,
+    k = buf.shape[0]
+    steps = xb.shape[1]
+    leaves = FS.unflatten(buf.astype(jnp.float32), spec)
+    ins = [leaves[name] for name in _LEAVES]
+    ins = [l[:, None, :] if l.ndim == 2 else l for l in ins]   # biases 3-D
+    scale = (active.astype(jnp.float32) * lr).reshape(k, 1, 1)
+    operands = ins + [xb, yb[..., None], scale]
+    out_shapes = [jax.ShapeDtypeStruct(l.shape, jnp.float32) for l in ins]
+    out_shapes.append(jax.ShapeDtypeStruct((k, 1, 1), jnp.float32))
+    *outs, loss = pl.pallas_call(
+        _make_kernel(steps, with_losses),
         grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, p), lambda i: (i, 0)),               # weights
-            pl.BlockSpec((1, steps, batch, xb.shape[3]),
-                         lambda i: (i, 0, 0, 0)),                 # minibatches
-            pl.BlockSpec((1, steps, batch), lambda i: (i, 0, 0)),  # labels
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),               # active*lr
-        ],
-        out_specs=[
-            pl.BlockSpec((1, p), lambda i: (i, 0)),
-            pl.BlockSpec((1, steps), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, p), jnp.float32),
-            jax.ShapeDtypeStruct((k, steps), jnp.float32),
-        ],
-        interpret=_resolve_interpret(interpret),
-    )(buf.astype(jnp.float32), xb, yb, scale)
-    return out, step_losses.mean(axis=1)
+        name="dystop_fused_sgd",
+        in_specs=[_whole(a.shape) for a in operands],
+        out_specs=[_whole(o.shape) for o in out_shapes],
+        out_shape=out_shapes,
+        interpret=resolve_interpret(interpret),
+    )(*operands)
+    out = jnp.concatenate([o.reshape(k, -1) for o in outs], axis=1)
+    return out, loss.reshape(k)
 
 
 def fused_sgd_sharded(buf: jnp.ndarray, xb: jnp.ndarray, yb: jnp.ndarray,
                       active: jnp.ndarray, spec, lr: float, shd,
                       with_losses: bool = True,
-                      interpret: Optional[bool] = None
+                      interpret: Union[str, bool] = "auto"
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """shard_map wrapper: Eq. 5 is row-local, so the SPMD program is
     embarrassingly parallel — the gathered rows (and their batches) split
     over the fleet axis when k divides the mesh (``FleetSharding.for_rows``
     row layout), with zero collectives; odd k falls back to replicated
-    compute, matching the engine's replication of small buckets."""
-    from jax.sharding import PartitionSpec
-    from repro.sharding.rules import shard_map
+    compute, matching the engine's replication of small buckets.
+    ``check_vma=False``: under JAX 0.9 the check needs the kernel's output
+    shapes to declare their varying axes (see
+    ``aggregate.aggregate_rows_sharded_kernel``)."""
     k = buf.shape[0]
     if not k or k % shd.n_shards:
         return fused_sgd(buf, xb, yb, active, spec, lr,

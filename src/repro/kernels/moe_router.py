@@ -8,10 +8,13 @@ aligned; the expert axis is small and stays whole in the panel.
 from __future__ import annotations
 
 import functools
+from typing import Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.config import resolve_interpret
 
 
 def _router_kernel(logits_ref, gates_ref, ids_ref, *, top_k: int):
@@ -39,7 +42,7 @@ def _router_kernel(logits_ref, gates_ref, ids_ref, *, top_k: int):
 
 @functools.partial(jax.jit, static_argnames=("top_k", "blk_t", "interpret"))
 def moe_router(logits: jnp.ndarray, top_k: int, blk_t: int = 256,
-               interpret: bool = True):
+               interpret: Union[str, bool] = "auto"):
     """logits: (T, E) -> (gates (T, k) f32 renormalized, ids (T, k) i32)."""
     t, e = logits.shape
     blk_t = min(blk_t, t)
@@ -49,11 +52,12 @@ def moe_router(logits: jnp.ndarray, top_k: int, blk_t: int = 256,
     gates, ids = pl.pallas_call(
         functools.partial(_router_kernel, top_k=top_k),
         grid=grid,
+        name="moe_router",
         in_specs=[pl.BlockSpec((blk_t, e), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((blk_t, top_k), lambda i: (i, 0)),
                    pl.BlockSpec((blk_t, top_k), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((lp.shape[0], top_k), jnp.float32),
                    jax.ShapeDtypeStruct((lp.shape[0], top_k), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lp)
     return gates[:t], ids[:t]

@@ -1,11 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; on the CPU container they execute in
+On TPU the kernels compile natively; on a CPU backend they execute in
 ``interpret=True`` mode (the kernel body runs step-by-step with the same
-block schedule), which is how all correctness tests validate them.  The
-interpret policy lives in ``kernels.config`` (``KernelConfig.interpret``,
-default ``"auto"`` = interpret everywhere except a real TPU backend); every
-wrapper here takes ``interpret=None`` meaning "auto".
+block schedule), which is how the correctness tests validate them.  The
+interpret policy lives in ``kernels.config.resolve_interpret``: every kernel
+and wrapper takes ``interpret="auto"`` (interpret everywhere except a real
+TPU backend) or an explicit boolean.
 
 The ``*_diff`` factories at the bottom are the model-plane entry points:
 ``jax.custom_vjp`` wrappers whose forward runs the Pallas kernel and whose
@@ -28,13 +28,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_router as _mr
 from repro.kernels import ref as _ref
 from repro.kernels import ssd_chunk as _sc
-from repro.kernels.config import KernelConfig, resolve_interpret
-
-
-def _interpret(interpret: Optional[Union[str, bool]] = None) -> bool:
-    # single source of the interpret-unless-TPU policy (kernels.config)
-    return resolve_interpret("auto" if interpret is None else interpret)
-
+from repro.kernels.config import KernelConfig
 
 def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512) -> jnp.ndarray:
     """Y = W @ X (mixing-matrix model aggregation, paper Eq. 4)."""
@@ -71,24 +65,24 @@ def aggregate_rows_cols_sharded(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
 def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None, blk_q: int = 128,
                     blk_k: int = 128,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Blockwise attention (B, H, S, D); kv heads pre-broadcast for GQA."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, blk_q=blk_q, blk_k=blk_k,
-                               interpret=_interpret(interpret))
+                               interpret=interpret)
 
 
 def moe_router(logits, top_k: int, blk_t: int = 256,
-               interpret: Optional[bool] = None):
+               interpret: Union[str, bool] = "auto"):
     """Fused softmax -> top-k -> renormalize."""
     return _mr.moe_router(logits, top_k, blk_t=blk_t,
-                          interpret=_interpret(interpret))
+                          interpret=interpret)
 
 
-def ssd_chunk(Bc, Cc, cum_la, xbar, interpret: Optional[bool] = None):
+def ssd_chunk(Bc, Cc, cum_la, xbar, interpret: Union[str, bool] = "auto"):
     """Fused Mamba-2 intra-chunk dual form (scores stay in VMEM)."""
     return _sc.ssd_chunk(Bc, Cc, cum_la, xbar,
-                         interpret=_interpret(interpret))
+                         interpret=interpret)
 
 
 # --------------------------------------------------------------------------- #

@@ -14,28 +14,35 @@ argument as flash attention, applied to the SSD dual form.  Q = chunk size
 (<= 256) and P = head_dim keep every tile 128-lane aligned.
 
 Heads share B/C (single group); the per-head decay enters via the cumulative
-log-a vector, so the grid is (batch, heads, n_chunks) with B/C indexed by
-(batch, chunk) only.
+log-a vector, so the grid is (batch*n_chunks, heads) with B/C indexed by the
+chunk only.  The log-a vector ships twice, as a (Q, 1) column and a (1, Q)
+row, so the (Q, Q) decay is one broadcast subtraction with no lane-to-sublane
+relayout in the kernel, and each block's last two dims are whole.
 """
 from __future__ import annotations
 
 import functools
+from typing import Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.config import resolve_interpret
 
-def _ssd_chunk_kernel(cb_ref, cc_ref, la_ref, x_ref, o_ref):
-    """Blocks: cb/cc (1, Q, N) chunk B/C; la (1, 1, Q) cumulative log-a for
-    this head; x (1, 1, Q, P) xbar; o (1, 1, Q, P)."""
+
+def _ssd_chunk_kernel(cb_ref, cc_ref, la_col_ref, la_row_ref, x_ref, o_ref):
+    """Blocks: cb/cc (1, Q, N) chunk B/C; la_col (1, 1, Q, 1) and la_row
+    (1, 1, 1, Q) cumulative log-a for this head; x (1, 1, Q, P) xbar;
+    o (1, 1, Q, P)."""
     C = cc_ref[0].astype(jnp.float32)                       # (Q, N)
     B = cb_ref[0].astype(jnp.float32)                       # (Q, N)
-    la = la_ref[0, 0].astype(jnp.float32)                   # (Q,)
     x = x_ref[0, 0].astype(jnp.float32)                     # (Q, P)
 
-    scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32)   # (Q, Q)
-    decay = la[:, None] - la[None, :]
+    scores = jax.lax.dot_general(                           # C @ B.T, (Q, Q)
+        C, B, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    decay = (la_col_ref[0, 0].astype(jnp.float32)
+             - la_row_ref[0, 0].astype(jnp.float32))
     q = scores.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
@@ -47,7 +54,8 @@ def _ssd_chunk_kernel(cb_ref, cc_ref, la_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_chunk(Bc: jnp.ndarray, Cc: jnp.ndarray, cum_la: jnp.ndarray,
-              xbar: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+              xbar: jnp.ndarray,
+              interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Intra-chunk SSD.
 
     Bc, Cc:  (batch*n_chunks, Q, N)   chunk B / C projections (shared by heads)
@@ -62,13 +70,15 @@ def ssd_chunk(Bc: jnp.ndarray, Cc: jnp.ndarray, cum_la: jnp.ndarray,
     return pl.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
+        name="ssd_chunk",
         in_specs=[
             pl.BlockSpec((1, Q, N), lambda g, h: (g, 0, 0)),
             pl.BlockSpec((1, Q, N), lambda g, h: (g, 0, 0)),
-            pl.BlockSpec((1, 1, Q), lambda g, h: (g, h, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda g, h: (g, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda g, h: (g, h, 0, 0)),
             pl.BlockSpec((1, 1, Q, P), lambda g, h: (g, h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Q, P), lambda g, h: (g, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((G, H, Q, P), jnp.float32),
-        interpret=interpret,
-    )(Bc, Cc, cum_la, xbar)
+        interpret=resolve_interpret(interpret),
+    )(Bc, Cc, cum_la[..., None], cum_la[..., None, :], xbar)

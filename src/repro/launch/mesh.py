@@ -9,17 +9,26 @@ the decentralized-FL worker axis (each pod holds one DFL replica).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes.  The engines place GSPMD sharding
+    constraints and let the partitioner route gathers and scatters; JAX
+    0.9's default Explicit axes would instead demand an ``out_sharding`` on
+    every gather or scatter into a sharded operand."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (shardings become no-ops)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 FLEET_AXIS = "fleet"
@@ -45,7 +54,7 @@ def make_fleet_mesh(mesh_shards: int):
             f"mesh_shards={mesh_shards} but only {n_dev} device(s) visible; "
             f"on CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{mesh_shards} (before jax initializes) to emulate the mesh")
-    return jax.make_mesh((mesh_shards,), (FLEET_AXIS,))
+    return make_mesh((mesh_shards,), (FLEET_AXIS,))
 
 
 # TPU v5e hardware constants used by the roofline analysis

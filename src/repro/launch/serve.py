@@ -24,6 +24,7 @@ from repro.data.synthetic import make_token_stream
 from repro.models import registry as R
 from repro.models import transformer as T
 from repro.models import encdec as E
+from repro.launch.cache import enable_compile_cache
 
 
 def serve(arch: str, smoke: bool, batch: int, prompt_len: int, gen: int,
@@ -79,6 +80,7 @@ def E_prefill(cfg, params, cache, prompt):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=R.ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")

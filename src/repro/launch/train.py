@@ -24,6 +24,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import registry as R
 from repro.optim import get_optimizer
 from repro.sharding.rules import use_sharding_rules
+from repro.launch.cache import enable_compile_cache
 
 
 def train(arch: str, smoke: bool, steps: int, batch: int, seq: int, lr: float,
@@ -77,6 +78,7 @@ def train(arch: str, smoke: bool, steps: int, batch: int, seq: int, lr: float,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=R.ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")

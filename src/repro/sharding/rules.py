@@ -6,8 +6,8 @@ active mesh, dropping any mesh axis that does not evenly divide the tensor
 dimension (e.g. smollm's 15 attention heads stay replicated on a 16-way model
 axis instead of forcing GSPMD padding).
 
-The mapping is a plain dict so the perf-hillclimb harness can override single
-rules (see EXPERIMENTS.md section "Perf").
+The mapping is a plain dict, so a caller can override single rules
+(``use_sharding_rules(mesh, overrides)``).
 
 ``FleetSharding`` is the sharded DFL engines' mesh handle: a hashable wrapper
 around the 1-D fleet mesh (``launch.mesh.make_fleet_mesh``) that rides through
@@ -25,18 +25,8 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                  # jax >= 0.5 top-level API
-    from jax import shard_map
-except ImportError:                   # jax 0.4.x: experimental API, and the
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        # the old API spells the replication check ``check_rep``
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_rep=check_vma)
 
 # Default logical->mesh rules.  Values are tuples of mesh axis names (applied
 # jointly to one tensor dim) or None (replicated).
@@ -103,18 +93,9 @@ def active_mesh() -> Optional[Mesh]:
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """Version-agnostic ``jax.sharding.AbstractMesh`` constructor.
-
-    jax 0.4.x takes ``shape_tuple=((name, size), ...)``; 0.5+ takes
-    ``(sizes, names)`` positionally.  Spec resolution (``logical_spec`` /
-    ``tree_shardings``) only reads ``.shape`` / ``.axis_names``, which both
-    layouts expose identically, so either construction works downstream.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(tuple(names), tuple(sizes))))
+    """A device-free ``jax.sharding.AbstractMesh`` for spec resolution
+    (``logical_spec`` / ``tree_shardings`` read only its shape and names)."""
+    return jax.sharding.AbstractMesh(tuple(sizes), tuple(names))
 
 
 def _resolve_dim(logical: Optional[str], dim: int, mesh: Mesh,

@@ -1,13 +1,43 @@
 """Per-kernel correctness: Pallas (interpret=True) vs the pure-jnp oracles,
 swept over shapes and dtypes (+ hypothesis for the aggregation kernel)."""
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
+from repro.kernels import aggregate as AGG
+from repro.kernels import flash_attention as FA
+from repro.kernels import fused_sgd as FSGD
+from repro.kernels import moe_router as MR
 from repro.kernels import ops as K
 from repro.kernels import ref as REF
+from repro.kernels import ssd_chunk as SC
+from repro.kernels.config import resolve_interpret
+
+
+# --------------------------------------------------------------------------- #
+# one interpret policy
+# --------------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("fn", [
+    AGG.aggregate, AGG.aggregate_rows, AGG.aggregate_rows_cols,
+    AGG.aggregate_rows_sharded_kernel, AGG.aggregate_rows_cols_sharded_kernel,
+    FSGD.fused_sgd, FSGD.fused_sgd_sharded, FA.flash_attention, SC.ssd_chunk,
+    MR.moe_router, K.flash_attention, K.moe_router, K.ssd_chunk])
+def test_kernel_interpret_defaults_to_auto(fn):
+    """No kernel entry point defaults to the interpreter: ``"auto"`` compiles
+    on a TPU backend and interprets elsewhere (``resolve_interpret``)."""
+    assert inspect.signature(fn).parameters["interpret"].default == "auto"
+
+
+def test_resolve_interpret_policy():
+    assert resolve_interpret("auto") == (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
 
 
 # --------------------------------------------------------------------------- #

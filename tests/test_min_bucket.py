@@ -1,9 +1,12 @@
 """Per-plane ``min_bucket`` knob: trajectory equivalence + compile count.
 
 Bucket padding only adds zero-weight rows / zero columns, so ANY min_bucket
-yields bit-identical trajectories on both planes; what the knob trades is
-compiled-shape count (coarse buckets collapse many activation counts onto
-one shape) against wasted padded row slots per dispatch."""
+replays the same control plane bit for bit on both planes.  The model state
+is bit-identical on the sim plane; on the LM plane the padded bucket shapes
+are different XLA programs whose f32 rounding may differ, so its state
+agrees to f32 tolerance.  What the knob trades is compiled-shape count
+(coarse buckets collapse many activation counts onto one shape) against
+wasted padded row slots per dispatch."""
 import numpy as np
 import pytest
 
@@ -33,9 +36,10 @@ def test_sim_min_bucket_bit_identical():
 
 
 def test_lm_min_bucket_bit_identical_and_compile_count():
-    """LM plane: min_bucket=8 vs 1 — identical fleet state bit for bit, and
-    the coarse bucket compiles strictly fewer mega-dispatch shape variants
-    (the whole point of the per-plane knob)."""
+    """LM plane: min_bucket=8 vs 1 — identical control plane bit for bit,
+    fleet state and losses to f32 tolerance (the padded shapes lower to
+    different programs), and the coarse bucket compiles strictly fewer
+    mega-dispatch shape variants (the whole point of the per-plane knob)."""
     cfg = R.get_smoke_config("smollm-135m")
     # unique lr -> a fresh LMEngine for this test (the engine cache keys on
     # the optimizer), so compiled-variant counts aren't polluted by other
@@ -55,9 +59,11 @@ def test_lm_min_bucket_bit_identical_and_compile_count():
                                   LW.LMRunConfig(min_bucket=1, **kw))
     assert h1.sim_time == h8.sim_time
     assert h1.round_active == h8.round_active
-    assert h1.loss_global == h8.loss_global           # bit-exact
-    np.testing.assert_array_equal(np.asarray(f1.pbuf), np.asarray(f8.pbuf))
-    np.testing.assert_array_equal(np.asarray(f1.obuf), np.asarray(f8.obuf))
+    np.testing.assert_allclose(h1.loss_global, h8.loss_global, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(f1.pbuf), np.asarray(f8.pbuf),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(f1.obuf), np.asarray(f8.obuf),
+                               rtol=1e-5, atol=1e-6)
 
     fine = sum(m._cache_size() for m in engine._mega_cache.values())
     # the same engine served both runs: min_bucket=8 collapsed every round

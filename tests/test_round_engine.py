@@ -56,6 +56,15 @@ def test_unravel_row_matches_leaf_slices():
     np.testing.assert_array_equal(FS.ravel_row(row3, spec), buf[3])
 
 
+@pytest.mark.parametrize("ids", [[3, 0, 11], [5, 5, 2, 5], [7]])
+def test_take_rows_equals_gather(ids):
+    """The row-slice loop returns exactly ``buf[ids]``, duplicates included."""
+    tree = _random_tree(jax.random.PRNGKey(2))
+    buf, _ = FS.flatten_stacked(tree)
+    ids = jnp.asarray(ids, jnp.int32)
+    np.testing.assert_array_equal(jax.jit(FS.take_rows)(buf, ids), buf[ids])
+
+
 # --------------------------------------------------------------------------- #
 # sparse aggregation vs dense
 # --------------------------------------------------------------------------- #

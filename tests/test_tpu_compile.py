@@ -1,0 +1,136 @@
+"""v5e compile rehearsals: every Pallas kernel, at the shapes the chip runs.
+
+Each test compiles one kernel for a described (not attached) TPU v5e with
+the TPU compiler installed next to JAX, and checks that the compiled program
+holds the Mosaic kernel (``tpu_custom_call``).  Interpret mode, which the
+rest of the suite runs on the CPU, accepts tilings and slices that Mosaic
+refuses; these compiles catch that without a chip.  Nothing runs, so they
+say nothing about values or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.  The fixture skips where the topology cannot be described.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from repro.dfl import flat_state as FS
+from repro.dfl.worker import init_mlp
+from repro.kernels import aggregate as AGG
+from repro.kernels import flash_attention as FA
+from repro.kernels import fused_sgd as FSGD
+from repro.kernels import moe_router as MR
+from repro.kernels import ssd_chunk as SC
+from repro.launch.mesh import FLEET_AXIS
+from repro.sharding.rules import FleetSharding
+
+SIM_P = 6922            # default sim MLP: dim 32, hidden 64, 10 classes
+SMOLLM_P = 134_515_008  # smollm-135m, one worker's flat row
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache, so keep it out of the cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def fleet4(topo):
+    mesh = Mesh(topo.devices[:4], (FLEET_AXIS,),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    return FleetSharding(mesh=mesh, axis=FLEET_AXIS)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("n,k,u,p", [
+    (100, 8, 64, SIM_P),      # sim plane: N=100 fleet, 64-column union
+    (4, 4, 4, SMOLLM_P),      # LM plane: 4 smollm-135m workers, all mixed
+])
+def test_aggregate_rows_cols_compiles(one_chip, n, k, u, p):
+    txt = _compiled_text(
+        lambda w, c, x: AGG.aggregate_rows_cols(w, c, x, interpret=False),
+        _sds((k, u), one_chip), _sds((u,), one_chip, jnp.int32),
+        _sds((n, p), one_chip))
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("cols", [True, False])
+def test_sharded_panels_compile_on_four_chips(fleet4, cols):
+    rows = fleet4.rows()
+    rep = fleet4.replicated()
+    x = _sds((128, SIM_P), rows)
+    if cols:
+        txt = _compiled_text(
+            lambda w, c, x: AGG.aggregate_rows_cols_sharded_kernel(
+                w, c, x, fleet4, interpret=False),
+            _sds((8, 64), rows), _sds((64,), rep, jnp.int32), x)
+    else:
+        txt = _compiled_text(
+            lambda w, x: AGG.aggregate_rows_sharded_kernel(
+                w, x, fleet4, interpret=False),
+            _sds((8, 128), rep), x)
+    assert "tpu_custom_call" in txt
+    assert "all-reduce" in txt            # the psum over the fleet axis
+
+
+def test_fused_sgd_compiles(one_chip):
+    params = init_mlp(jax.random.PRNGKey(0), 32, 64, 10)
+    spec = FS.spec_of(jax.tree.map(lambda a: a[None], params))
+    assert spec.n_params == SIM_P
+    k, steps, batch, dim = 16, 2, 32, 32
+    txt = _compiled_text(
+        lambda b, x, y, a: FSGD.fused_sgd(b, x, y, a, spec, 0.05,
+                                          interpret=False),
+        _sds((k, SIM_P), one_chip), _sds((k, steps, batch, dim), one_chip),
+        _sds((k, steps, batch), one_chip, jnp.int32), _sds((k,), one_chip))
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_attention_compiles(one_chip):
+    q = _sds((2, 9, 256, 64), one_chip, jnp.bfloat16)   # smollm-135m heads
+    txt = _compiled_text(
+        lambda q, k, v: FA.flash_attention(q, k, v, interpret=False), q, q, q)
+    assert "tpu_custom_call" in txt
+
+
+def test_ssd_chunk_compiles(one_chip):
+    g, h, q, n, p = 2, 80, 256, 128, 64                 # mamba2-2.7b chunk
+    txt = _compiled_text(
+        lambda b, c, la, x: SC.ssd_chunk(b, c, la, x, interpret=False),
+        _sds((g, q, n), one_chip), _sds((g, q, n), one_chip),
+        _sds((g, h, q), one_chip), _sds((g, h, q, p), one_chip))
+    assert "tpu_custom_call" in txt
+
+
+def test_moe_router_compiles(one_chip):
+    txt = _compiled_text(
+        lambda l: MR.moe_router(l, 8, interpret=False),
+        _sds((512, 384), one_chip))                     # kimi-k2 router
+    assert "tpu_custom_call" in txt
