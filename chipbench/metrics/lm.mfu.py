@@ -1,0 +1,16 @@
+"""Model FLOPs of the trained tokens over the window, as a share of the
+chip's bf16 peak.  FLOPs per token come from the configuration's shapes
+(``work.lm_train_flops_per_token``); padding rows of a bucket are not
+trained tokens."""
+import work
+
+
+def read(ctx):
+    sess, peaks = ctx["session"], ctx["peaks"]
+    if peaks is None or not sess.tokens():
+        return None
+    per_token = work.lm_train_flops_per_token(
+        ctx["config"]["model"], ctx["traffic"]["batch"]["seq"])
+    chips = ctx["cell"]["chips"]
+    return 100.0 * per_token * sess.tokens() / ctx["window_s"] / (
+        chips * peaks["bf16_flops"])
