@@ -32,7 +32,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import pathlib
-import time
 import warnings
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
@@ -47,12 +46,13 @@ from repro.core.aggregation import mixing_rows, prefer_cols
 from repro.core.planner import (HorizonPlanner, PlannedRound, bucket_key,
                                 chunk_spans, mix_is_train)
 from repro.core.scenarios import resolve_scenario
+from repro.core.trace import Trace
 from repro.data.synthetic import make_token_stream
 from repro.dfl import flat_state as FS
 from repro.dfl import worker as WK
 from repro.dfl.network import (EdgeNetwork, NetworkConfig,
                                heterogeneous_compute_times)
-from repro.dfl.pipeline import DispatchPipeline
+from repro.dfl.pipeline import DispatchPipeline, count_dispatch
 from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 from repro.optim import Optimizer, get_optimizer
@@ -360,17 +360,20 @@ class LMEngine:
         """One worker's AD train step on its flat rows; a padding row
         (``m == 0``) comes back bit-identical."""
         cfg, opt, spec = self.cfg, self.opt, self.spec
-        params = FS.unravel_row(pvec, spec.params)
-        state = FS.unravel_row(ovec, spec.opt)
-        batch = {"tokens": t, "labels": l,
-                 "loss_mask": jnp.ones(t.shape, jnp.float32)}
-        (loss, _), grads = jax.value_and_grad(
-            lambda p: R.compute_loss(cfg, p, batch), has_aux=True)(params)
-        new_p, new_s = opt.update(grads, state, params)
-        keep = m > 0
-        return (jnp.where(keep, FS.ravel_row(new_p, spec.params), pvec),
-                jnp.where(keep, FS.ravel_row(new_s, spec.opt), ovec),
-                loss * m)
+        with jax.named_scope("fwd_bwd"):
+            params = FS.unravel_row(pvec, spec.params)
+            batch = {"tokens": t, "labels": l,
+                     "loss_mask": jnp.ones(t.shape, jnp.float32)}
+            (loss, _), grads = jax.value_and_grad(
+                lambda p: R.compute_loss(cfg, p, batch), has_aux=True)(params)
+        with jax.named_scope(opt.name):
+            state = FS.unravel_row(ovec, spec.opt)
+            new_p, new_s = opt.update(grads, state, params)
+        with jax.named_scope("write_back"):
+            keep = m > 0
+            return (jnp.where(keep, FS.ravel_row(new_p, spec.params), pvec),
+                    jnp.where(keep, FS.ravel_row(new_s, spec.opt), ovec),
+                    loss * m)
 
     def _train_rows(self, pbuf, obuf, sub, tids, mask, tok, lab):
         """Train the k gathered rows one after another, each written back in
@@ -400,14 +403,16 @@ class LMEngine:
 
                 def train(c):
                     pb, ob, ls = c
-                    pvec = (sub[i] if sub is not None else
-                            jax.lax.dynamic_index_in_dim(pb, r, 0, False))
-                    ovec = jax.lax.dynamic_index_in_dim(ob, r, 0, False)
+                    with jax.named_scope("gather"):
+                        pvec = (sub[i] if sub is not None else
+                                jax.lax.dynamic_index_in_dim(pb, r, 0, False))
+                        ovec = jax.lax.dynamic_index_in_dim(ob, r, 0, False)
                     new_p, new_o, loss = self._train_one(pvec, ovec, mask[i],
                                                          tok[i], lab[i])
-                    put = jax.lax.dynamic_update_index_in_dim
-                    return (put(pb, new_p, r, 0), put(ob, new_o, r, 0),
-                            ls.at[tids[i]].set(loss))
+                    with jax.named_scope("write_back"):
+                        put = jax.lax.dynamic_update_index_in_dim
+                        return (put(pb, new_p, r, 0), put(ob, new_o, r, 0),
+                                ls.at[tids[i]].set(loss))
 
                 if blk is None:
                     return train(carry)
@@ -452,18 +457,22 @@ class LMEngine:
         k_mix, k_train = w.shape[0], tids.shape[0]
         # pregathered batches arrive (k, B, S) in train-row order; otherwise
         # the activated rows are gathered from the full-N batch on device
-        tok_k = tok if pregather else (tok[tids] if k_train else tok)
-        lab_k = lab if pregather else (lab[tids] if k_train else lab)
+        with jax.named_scope("gather"):
+            tok_k = tok if pregather else (tok[tids] if k_train else tok)
+            lab_k = lab if pregather else (lab[tids] if k_train else lab)
         if fuse and k_mix and k_train:
             # mix rows == train rows: Eq. 4 output feeds Eq. 5 directly
-            sub = WK._mix_rows(pbuf, w, cids, self.kernels, shd)
+            with jax.named_scope("mix"):
+                sub = WK._mix_rows(pbuf, w, cids, self.kernels, shd)
             return pin(*self._train_rows(pbuf, obuf, sub, tids, mask,
                                          tok_k, lab_k))
         if k_mix:
-            pbuf = (WK.mix_flat_cols(pbuf, w, mids, cids, self.kernels,
-                                     shd=shd)
-                    if cids is not None
-                    else WK.mix_flat(pbuf, w, mids, self.kernels, shd=shd))
+            with jax.named_scope("mix"):
+                pbuf = (WK.mix_flat_cols(pbuf, w, mids, cids, self.kernels,
+                                         shd=shd)
+                        if cids is not None
+                        else WK.mix_flat(pbuf, w, mids, self.kernels,
+                                         shd=shd))
         if k_train:
             return pin(*self._train_rows(pbuf, obuf, None, tids, mask,
                                          tok_k, lab_k))
@@ -479,20 +488,24 @@ class LMEngine:
             k_mix = w_rows.shape[1]
             u = w_rows.shape[2] if col_sparse and k_mix else 0
             mix_ids, col_ids, train_ids, masks = WK.split_ctrl(ctrl, k_mix, u)
+            # the scan step's ops carry one stable scope name, ``mega_round``
             if col_ids is not None:
                 def body(c, xs):
                     w, mi, ci, ti, m, tk, lb = xs
-                    pb, ob, ls = self._round_body(c[0], c[1], w, mi, ci, ti,
-                                                  m, tk, lb, fuse, pregather)
+                    with jax.named_scope("mega_round"):
+                        pb, ob, ls = self._round_body(c[0], c[1], w, mi, ci,
+                                                      ti, m, tk, lb, fuse,
+                                                      pregather)
                     return (pb, ob), ls
                 xs = (w_rows, mix_ids, col_ids, train_ids, masks,
                       tokens, labels)
             else:
                 def body(c, xs):
                     w, mi, ti, m, tk, lb = xs
-                    pb, ob, ls = self._round_body(c[0], c[1], w, mi, None,
-                                                  ti, m, tk, lb, fuse,
-                                                  pregather)
+                    with jax.named_scope("mega_round"):
+                        pb, ob, ls = self._round_body(c[0], c[1], w, mi, None,
+                                                      ti, m, tk, lb, fuse,
+                                                      pregather)
                     return (pb, ob), ls
                 xs = (w_rows, mix_ids, train_ids, masks, tokens, labels)
             (pbuf, obuf), losses = jax.lax.scan(body, (pbuf, obuf), xs)
@@ -503,8 +516,8 @@ class LMEngine:
 
     def dispatch_chunk(self, pbuf, obuf, chunk: List[PlannedRound],
                        tokens: np.ndarray, labels: np.ndarray, *,
-                       col_sparse: bool, fuse: bool, min_bucket: int = 8,
-                       pregather: bool = False, key=None, walls=None
+                       col_sparse: bool, fuse: bool, trace: Trace,
+                       min_bucket: int = 8, pregather: bool = False, key=None
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """One bucket-uniform chunk -> one donated scan dispatch.
 
@@ -520,47 +533,50 @@ class LMEngine:
         (``worker.pack_chunk`` — bit-identical output, much less host work)
         and stages all four host arrays with ONE fused non-blocking
         ``jax.device_put``; ``key=None`` keeps the original pack/stage path
-        verbatim (the depth-0 oracle).  ``walls`` (an ``LMHistory`` or any
-        object with ``pack_wall_s``/``stage_wall_s``) accumulates the
-        per-phase host wall time.
+        verbatim (the depth-0 oracle).  ``trace`` (the call's
+        ``core.trace.Trace``) takes the ``pack``, ``stage`` and ``enqueue``
+        spans and the chunk's counters (``pipeline.count_dispatch``).
 
         Returns (new pbuf, new obuf, (H, N) per-round losses — zero rows for
         idle workers).
         """
         shards = self.shd.n_shards if self.shd is not None else 1
-        t0 = time.perf_counter()
-        if key is not None:
-            w, c, _ = WK.pack_chunk(chunk, key, min_bucket=min_bucket,
-                                    col_sparse=col_sparse, shards=shards)
-        else:
-            w, c, _ = WK.pack_horizon(chunk, min_bucket=min_bucket,
-                                      col_sparse=col_sparse, shards=shards)
-        if self.shd is not None and not (col_sparse and w.shape[1]):
-            w = WK.pad_w_cols(w, pbuf.shape[0])
-        k_mix = w.shape[1]
-        u = w.shape[2] if col_sparse and k_mix else 0
-        # one ctrl-layout definition: the same split the device scan performs
-        _, _, tids, _ = WK.split_ctrl(c, k_mix, u)
-        k_train = tids.shape[-1]
-        if pregather and k_train:
-            h_ix = np.arange(len(chunk))[:, None]
-            tokens = tokens[h_ix, tids]                      # (H, k, B, S)
-            labels = labels[h_ix, tids]
-        t1 = time.perf_counter()
-        if self.shd is not None:
-            put = self.shd.put
-            w_j, c_j, tk_j, lb_j = put(w), put(c), put(tokens), put(labels)
-        elif key is not None:
-            w_j, c_j, tk_j, lb_j = jax.device_put((w, c, tokens, labels))
-        else:
-            w_j, c_j = jnp.asarray(w), jnp.asarray(c)
-            tk_j, lb_j = jnp.asarray(tokens), jnp.asarray(labels)
-        if walls is not None:
-            t2 = time.perf_counter()
-            walls.pack_wall_s += t1 - t0
-            walls.stage_wall_s += t2 - t1
-        return self._mega(col_sparse, fuse, pregather and bool(k_train))(
-            pbuf, obuf, w_j, c_j, tk_j, lb_j)
+        with trace.span("pack"):
+            if key is not None:
+                w, c, _ = WK.pack_chunk(chunk, key, min_bucket=min_bucket,
+                                        col_sparse=col_sparse, shards=shards)
+            else:
+                w, c, _ = WK.pack_horizon(chunk, min_bucket=min_bucket,
+                                          col_sparse=col_sparse,
+                                          shards=shards)
+            if self.shd is not None and not (col_sparse and w.shape[1]):
+                w = WK.pad_w_cols(w, pbuf.shape[0])
+            k_mix = w.shape[1]
+            u = w.shape[2] if col_sparse and k_mix else 0
+            # one ctrl-layout definition: the same split the device scan
+            # performs
+            _, _, tids, _ = WK.split_ctrl(c, k_mix, u)
+            k_train = tids.shape[-1]
+            if pregather and k_train:
+                h_ix = np.arange(len(chunk))[:, None]
+                tokens = tokens[h_ix, tids]                  # (H, k, B, S)
+                labels = labels[h_ix, tids]
+        with trace.span("stage"):
+            if self.shd is not None:
+                put = self.shd.put
+                w_j, c_j = put(w), put(c)
+                tk_j, lb_j = put(tokens), put(labels)
+            elif key is not None:
+                w_j, c_j, tk_j, lb_j = jax.device_put((w, c, tokens, labels))
+            else:
+                w_j, c_j = jnp.asarray(w), jnp.asarray(c)
+                tk_j, lb_j = jnp.asarray(tokens), jnp.asarray(labels)
+        with trace.span("enqueue"):
+            out = self._mega(col_sparse, fuse, pregather and bool(k_train))(
+                pbuf, obuf, w_j, c_j, tk_j, lb_j)
+        count_dispatch(trace, len(chunk), k_mix, k_train,
+                       w.nbytes + c.nbytes + tokens.nbytes + labels.nbytes)
+        return out
 
     @functools.cached_property
     def eval_global(self):
@@ -586,7 +602,8 @@ class LMEngine:
 
         @jax.jit
         def ev(pbuf, alpha, tokens, labels):
-            return loss_of(FS.weighted_row(pbuf, alpha), tokens, labels)
+            with jax.named_scope("eval"):
+                return loss_of(FS.weighted_row(pbuf, alpha), tokens, labels)
 
         return ev
 
@@ -718,14 +735,15 @@ class LMRunConfig:
 class LMHistory:
     """Trajectory of one LM federation run (units as ``simulator.History``:
     sim_time in simulated seconds, comm in GB, staleness in rounds,
-    ``wall_s``/``eval_wall_s``/``setup_wall_s`` in real host seconds).
+    ``wall_s`` and the ``*_wall_s`` spans in real host seconds).
 
-    The ``*_wall_s`` phase breakdown mirrors ``simulator.History``:
-    ``plan_wall_s`` host planner time (every depth), ``pack_wall_s`` /
-    ``stage_wall_s`` host packing and H2D staging (pipelined path),
-    ``drain_wall_s`` host time blocked on device completion — the device-
-    execute share of the round loop.  Emitted by ``benchmarks/run.py
-    --json`` via the lm_fleet suite."""
+    Host time and ``counts`` as ``simulator.History`` has them, written by
+    ``core.trace.Trace``, with one more span: ``stream`` (the round's token
+    batches drawn from ``worker_streams``).  Here ``pack`` also stacks the
+    chunk's token batches, ``eval`` reads the queued per-round losses back,
+    and ``drain`` (host blocked on the device, not the device's execute
+    time) also takes the last read of the losses.  Emitted by
+    ``benchmarks/run.py --json`` via the lm_fleet suite."""
     rounds: List[int] = dataclasses.field(default_factory=list)
     sim_time: List[float] = dataclasses.field(default_factory=list)
     comm_gb: List[float] = dataclasses.field(default_factory=list)
@@ -740,9 +758,13 @@ class LMHistory:
     eval_wall_s: float = 0.0
     setup_wall_s: float = 0.0
     plan_wall_s: float = 0.0
+    stream_wall_s: float = 0.0
     pack_wall_s: float = 0.0
     stage_wall_s: float = 0.0
+    enqueue_wall_s: float = 0.0
     drain_wall_s: float = 0.0
+    snapshot_wall_s: float = 0.0
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -767,112 +789,116 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
     fast-forwards past the checkpointed rounds (``worker_streams``
     ``skip_rounds``) — the continuation is bit-identical.
     """
-    t_wall = time.time()
-    n = run.n_workers
-    if run.kernels is not None and cfg.kernels != run.kernels:
-        # one kernel plane per run: the fleet's forward pass follows the same
-        # KernelConfig that drives the Eq. 4/5 aggregation kernels
-        cfg = dataclasses.replace(cfg, kernels=run.kernels)
-    shd = None
-    if run.mesh_shards > 1:
-        if not run.resident_fleet:
-            raise ValueError("mesh_shards > 1 requires the resident engine "
-                             "(resident_fleet=True)")
-        from repro.sharding.rules import FleetSharding
-        shd = FleetSharding.create(run.mesh_shards)
-    rng = np.random.default_rng(run.seed)
-    fleet = init_fleet(cfg, n, optimizer=run.optimizer, lr=run.lr,
-                       seed=run.seed)
-    if shd is not None:
-        fleet.pbuf = shd.put_rows_padded(fleet.pbuf)
-        fleet.obuf = shd.put_rows_padded(fleet.obuf)
-    streams = worker_streams(cfg, n, run.batch, run.seq, seed=run.seed)
-    ev = next(worker_streams(cfg, 1, run.batch, run.seq, seed=run.seed + 1))
-    eval_tok = jnp.asarray(ev["tokens"][0])
-    eval_lab = jnp.asarray(ev["labels"][0])
-    net = EdgeNetwork(NetworkConfig(n_workers=n,
-                                    comm_range_m=run.comm_range_m), rng)
-    h_i = heterogeneous_compute_times(n, 1.0, rng, sigma=run.compute_sigma)
-    model_bytes = float(fleet.model_bytes)
-    scen = resolve_scenario(run.scenario, n, run.n_rounds, dist=net.dist,
-                            comm_range_m=net.cfg.comm_range_m)
-    planner = HorizonPlanner(
-        mechanism, h_i=h_i, in_range=net.in_range(),
-        exp_link_time=net.expected_link_time(model_bytes),
-        model_bytes=model_bytes, class_counts=np.ones((n, 2)),
-        data_sizes=np.ones(n), net=net, rng=rng, tau_bound=run.tau_bound,
-        bandwidth_budget=run.bandwidth_budget,
-        link_timeout_s=run.link_timeout_s,
-        sync_link_timeout_s=run.sync_link_timeout_s,
-        failure_prob=run.failure_prob, failure_persist=run.failure_persist,
-        mesh_shards=run.mesh_shards, scenario=scen)
-    alpha = jnp.full((n,), 1.0 / n, jnp.float32)
-    # Eq. 11 weights over the PADDED row axis: padding rows weigh zero
-    alpha_eval = alpha if shd is None else shd.put(
-        jnp.concatenate([alpha, jnp.zeros((shd.pad(n),), jnp.float32)]))
     hist = LMHistory()
+    tr = Trace(hist)
+    with tr.span("setup"):
+        n = run.n_workers
+        if run.kernels is not None and cfg.kernels != run.kernels:
+            # one kernel plane per run: the fleet's forward pass follows the
+            # same KernelConfig that drives the Eq. 4/5 aggregation kernels
+            cfg = dataclasses.replace(cfg, kernels=run.kernels)
+        shd = None
+        if run.mesh_shards > 1:
+            if not run.resident_fleet:
+                raise ValueError("mesh_shards > 1 requires the resident "
+                                 "engine (resident_fleet=True)")
+            from repro.sharding.rules import FleetSharding
+            shd = FleetSharding.create(run.mesh_shards)
+        rng = np.random.default_rng(run.seed)
+        fleet = init_fleet(cfg, n, optimizer=run.optimizer, lr=run.lr,
+                           seed=run.seed)
+        if shd is not None:
+            fleet.pbuf = shd.put_rows_padded(fleet.pbuf)
+            fleet.obuf = shd.put_rows_padded(fleet.obuf)
+        streams = worker_streams(cfg, n, run.batch, run.seq, seed=run.seed)
+        ev = next(worker_streams(cfg, 1, run.batch, run.seq,
+                                 seed=run.seed + 1))
+        eval_tok = jnp.asarray(ev["tokens"][0])
+        eval_lab = jnp.asarray(ev["labels"][0])
+        net = EdgeNetwork(NetworkConfig(n_workers=n,
+                                        comm_range_m=run.comm_range_m), rng)
+        h_i = heterogeneous_compute_times(n, 1.0, rng, sigma=run.compute_sigma)
+        model_bytes = float(fleet.model_bytes)
+        scen = resolve_scenario(run.scenario, n, run.n_rounds, dist=net.dist,
+                                comm_range_m=net.cfg.comm_range_m)
+        planner = HorizonPlanner(
+            mechanism, h_i=h_i, in_range=net.in_range(),
+            exp_link_time=net.expected_link_time(model_bytes),
+            model_bytes=model_bytes, class_counts=np.ones((n, 2)),
+            data_sizes=np.ones(n), net=net, rng=rng, tau_bound=run.tau_bound,
+            bandwidth_budget=run.bandwidth_budget,
+            link_timeout_s=run.link_timeout_s,
+            sync_link_timeout_s=run.sync_link_timeout_s,
+            failure_prob=run.failure_prob, failure_persist=run.failure_persist,
+            mesh_shards=run.mesh_shards, scenario=scen)
+        alpha = jnp.full((n,), 1.0 / n, jnp.float32)
+        # Eq. 11 weights over the PADDED row axis: padding rows weigh zero
+        alpha_eval = alpha if shd is None else shd.put(
+            jnp.concatenate([alpha, jnp.zeros((shd.pad(n),), jnp.float32)]))
 
-    # --- crash-safe resume: overwrite the deterministic setup's mutable
-    # state (resident buffers, planner, rng stream, history) and fast-forward
-    # the token stream past the checkpointed rounds.  Placed BEFORE the
-    # engine/oracle setup so the oracle's stacked pytrees materialize from
-    # the restored buffers.
-    if resume_from is not None:
-        ck = pathlib.Path(resume_from)
-        if ck.is_dir():
-            found = CIO.latest_checkpoint(ck)
-            if found is None:
-                raise FileNotFoundError(
-                    f"resume_from={ck} is a directory with no "
-                    f"ckpt_round*.npz snapshot in it")
-            ck = found
-        arr_tmpl = {k: np.zeros_like(v)
-                    for k, v in planner.state_dict()["arrays"].items()}
-        model_tmpl = {
-            "pbuf": np.zeros((n, int(fleet.pbuf.shape[1])), np.float32),
-            "obuf": np.zeros((n, int(fleet.obuf.shape[1])), np.float32)}
-        model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl, arr_tmpl)
-        saved_cfg = extra.get("config", {})
-        checks = {"plane": "lm", "n_workers": n, "seed": run.seed,
-                  "resident_fleet": run.resident_fleet,
-                  "mesh_shards": run.mesh_shards,
-                  "scenario": scen.schedule.name if scen else None}
-        for k, want in checks.items():
-            if k in saved_cfg and saved_cfg[k] != want:
-                raise ValueError(
-                    f"resume config mismatch: snapshot {ck.name} was written "
-                    f"with {k}={saved_cfg[k]!r} but this run has {k}={want!r}"
-                    f" — resuming must use the identical configuration")
-        planner.load_state({"arrays": arrays,
-                            "scalars": extra["planner_scalars"],
-                            "rng_state": extra["planner_rng"]})
-        pbuf, obuf = jnp.asarray(model["pbuf"]), jnp.asarray(model["obuf"])
-        if shd is not None:   # rebuild padded residency exactly as init did
-            pbuf, obuf = shd.put_rows_padded(pbuf), shd.put_rows_padded(obuf)
-        fleet.pbuf, fleet.obuf = pbuf, obuf
-        streams = worker_streams(cfg, n, run.batch, run.seq, seed=run.seed,
-                                 skip_rounds=int(extra["round"]))
-        for k, v in extra["history"].items():
-            if hasattr(hist, k):
-                setattr(hist, k, v)
+        # --- crash-safe resume: overwrite the deterministic setup's mutable
+        # state (resident buffers, planner, rng stream, history but its host
+        # times) and fast-forward the token stream past the checkpointed
+        # rounds.  Placed BEFORE the engine/oracle setup so the oracle's
+        # stacked pytrees materialize from the restored buffers.
+        if resume_from is not None:
+            ck = pathlib.Path(resume_from)
+            if ck.is_dir():
+                found = CIO.latest_checkpoint(ck)
+                if found is None:
+                    raise FileNotFoundError(
+                        f"resume_from={ck} is a directory with no "
+                        f"ckpt_round*.npz snapshot in it")
+                ck = found
+            arr_tmpl = {k: np.zeros_like(v)
+                        for k, v in planner.state_dict()["arrays"].items()}
+            model_tmpl = {
+                "pbuf": np.zeros((n, int(fleet.pbuf.shape[1])), np.float32),
+                "obuf": np.zeros((n, int(fleet.obuf.shape[1])), np.float32)}
+            model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl,
+                                                       arr_tmpl)
+            saved_cfg = extra.get("config", {})
+            checks = {"plane": "lm", "n_workers": n, "seed": run.seed,
+                      "resident_fleet": run.resident_fleet,
+                      "mesh_shards": run.mesh_shards,
+                      "scenario": scen.schedule.name if scen else None}
+            for k, want in checks.items():
+                if k in saved_cfg and saved_cfg[k] != want:
+                    raise ValueError(
+                        f"resume config mismatch: snapshot {ck.name} was "
+                        f"written with {k}={saved_cfg[k]!r} but this run has "
+                        f"{k}={want!r} — resuming must use the identical "
+                        f"configuration")
+            planner.load_state({"arrays": arrays,
+                                "scalars": extra["planner_scalars"],
+                                "rng_state": extra["planner_rng"]})
+            pbuf, obuf = jnp.asarray(model["pbuf"]), jnp.asarray(model["obuf"])
+            if shd is not None:   # rebuild padded residency as init did
+                pbuf = shd.put_rows_padded(pbuf)
+                obuf = shd.put_rows_padded(obuf)
+            fleet.pbuf, fleet.obuf = pbuf, obuf
+            streams = worker_streams(cfg, n, run.batch, run.seq, seed=run.seed,
+                                     skip_rounds=int(extra["round"]))
+            for k, v in extra["history"].items():
+                if hasattr(hist, k) and not k.endswith("wall_s"):
+                    setattr(hist, k, v)
 
-    if run.resident_fleet:
-        engine = get_lm_engine(cfg, fleet.optimizer, fleet.spec,
-                               kernels=run.kernels, shd=shd)
-        horizon = max(1, run.scan_horizon)
-        sp = so = step = None
-    else:
-        engine = None
-        horizon = 1                       # the oracle dispatches per round
-        sp, so = fleet.stacked_params, fleet.stacked_opt   # pytrees, ONCE
-        step = make_fleet_step(fleet)
-    hist.setup_wall_s = time.time() - t_wall
+        if run.resident_fleet:
+            engine = get_lm_engine(cfg, fleet.optimizer, fleet.spec,
+                                   kernels=run.kernels, shd=shd)
+            horizon = max(1, run.scan_horizon)
+            sp = so = step = None
+        else:
+            engine = None
+            horizon = 1                       # the oracle dispatches per round
+            sp, so = fleet.stacked_params, fleet.stacked_opt   # pytrees, ONCE
+            step = make_fleet_step(fleet)
 
     # async dispatch pipeline (as run_simulation): depth >= 1 overlaps host
     # plan/pack/stage with the device scan, depth 0 keeps the original
     # lockstep dispatch path verbatim as the oracle
     pipelined = run.resident_fleet and run.pipeline_depth > 0
-    pipe = DispatchPipeline(run.pipeline_depth)
+    pipe = DispatchPipeline(run.pipeline_depth, tr)
 
     pending: List[Tuple[PlannedRound, Dict[str, np.ndarray]]] = []
     # per entry: (device losses, active mask(s)) — the oracle paths queue one
@@ -885,25 +911,25 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
         nonlocal sp, so
         plans = [p for p, _ in pending]
         if run.resident_fleet:
-            t0 = time.perf_counter()
-            spans = list(chunk_spans(plans, n,
-                                     col_sparse=run.col_sparse_mix,
-                                     min_bucket=run.min_bucket,
-                                     mesh_shards=run.mesh_shards))
-            hist.pack_wall_s += time.perf_counter() - t0
+            with tr.span("pack"):
+                spans = list(chunk_spans(plans, n,
+                                         col_sparse=run.col_sparse_mix,
+                                         min_bucket=run.min_bucket,
+                                         mesh_shards=run.mesh_shards))
             for lo, hi, key in spans:
                 chunk = plans[lo:hi]
                 col = run.col_sparse_mix and prefer_cols(key[0], key[2], n)
                 fuse = all(mix_is_train(p) for p in chunk)
-                t0 = time.perf_counter()
-                tokens = np.stack([b["tokens"] for _, b in pending[lo:hi]])
-                labels = np.stack([b["labels"] for _, b in pending[lo:hi]])
-                hist.pack_wall_s += time.perf_counter() - t0
+                with tr.span("pack"):
+                    tokens = np.stack([b["tokens"]
+                                       for _, b in pending[lo:hi]])
+                    labels = np.stack([b["labels"]
+                                       for _, b in pending[lo:hi]])
                 if pipelined:
                     fleet.pbuf, fleet.obuf, losses = engine.dispatch_chunk(
                         fleet.pbuf, fleet.obuf, chunk, tokens, labels,
                         col_sparse=col, fuse=fuse, min_bucket=run.min_bucket,
-                        pregather=run.host_batch_gather, key=key, walls=hist)
+                        pregather=run.host_batch_gather, key=key, trace=tr)
                     loss_rows.append((losses, [p.active for p in chunk]))
                     # the loss block is the non-donated output of the chunk's
                     # executable — the in-flight token (pbuf/obuf are donated
@@ -913,15 +939,19 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
                     fleet.pbuf, fleet.obuf, losses = engine.dispatch_chunk(
                         fleet.pbuf, fleet.obuf, chunk, tokens, labels,
                         col_sparse=col, fuse=fuse, min_bucket=run.min_bucket,
-                        pregather=run.host_batch_gather, walls=hist)
+                        pregather=run.host_batch_gather, trace=tr)
                     for j, p in enumerate(chunk):
                         loss_rows.append((losses[j], p.active))
         else:
             for p, b in pending:
-                sp = fleet_mix_stacked(sp, p.W, p.active, p.links,
-                                       kernels=run.kernels)
-                batch = {k: jnp.asarray(v) for k, v in b.items()}
-                sp, so, losses = step(sp, so, batch, jnp.asarray(p.active))
+                with tr.span("stage"):
+                    batch = {k: jnp.asarray(v) for k, v in b.items()}
+                with tr.span("enqueue"):
+                    sp = fleet_mix_stacked(sp, p.W, p.active, p.links,
+                                           kernels=run.kernels)
+                    sp, so, losses = step(sp, so, batch,
+                                          jnp.asarray(p.active))
+                count_dispatch(tr, 1, n, n)
                 loss_rows.append((losses, p.active))
         pending.clear()
 
@@ -949,8 +979,10 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
         else:
             pb, _ = FS.flatten_stacked(sp)
             ob, _ = FS.flatten_stacked(so)
-        model = {"pbuf": np.asarray(jax.block_until_ready(
-                     pb if pb.shape[0] == n else pb[:n])),
+        pb = pb if pb.shape[0] == n else pb[:n]
+        with tr.span("drain"):
+            jax.block_until_ready(pb)
+        model = {"pbuf": np.asarray(pb),
                  "obuf": np.asarray(ob if ob.shape[0] == n else ob[:n])}
         extra = {
             "round": t,
@@ -968,16 +1000,16 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
         CIO.prune_checkpoints(run.checkpoint_dir, run.checkpoint_keep)
 
     while planner.t < run.n_rounds:
-        t0p = time.perf_counter()
-        p = planner.plan_round()
-        if run.resident_fleet:
-            # resolve the shape-bucket key at plan time (memoized on the
-            # plan; as run_simulation) so chunk_spans only does lookups
-            bucket_key(p, n, col_sparse=run.col_sparse_mix,
-                       min_bucket=run.min_bucket,
-                       mesh_shards=run.mesh_shards)
-        hist.plan_wall_s += time.perf_counter() - t0p
-        b = next(streams)                 # one draw per round, EITHER path
+        with tr.span("plan"):
+            p = planner.plan_round()
+            if run.resident_fleet:
+                # resolve the shape-bucket key at plan time (memoized on the
+                # plan; as run_simulation) so chunk_spans only does lookups
+                bucket_key(p, n, col_sparse=run.col_sparse_mix,
+                           min_bucket=run.min_bucket,
+                           mesh_shards=run.mesh_shards)
+        with tr.span("stream"):
+            b = next(streams)             # one draw per round, EITHER path
         hist.round_durations.append(p.duration)
         hist.round_active.append(int(p.active.sum()))
         pending.append((p, b))
@@ -992,41 +1024,42 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
             if pipelined and (do_eval or do_ckpt or at_boundary):
                 pipe.drain()
         if do_eval:
-            jax.block_until_ready(fleet.pbuf if run.resident_fleet
-                                  else jax.tree.leaves(sp)[0])
-            t_ev = time.time()
-            drain_losses()
-            if run.resident_fleet:
-                lg = float(engine.eval_global(fleet.pbuf, alpha_eval,
-                                              eval_tok, eval_lab))
-            else:
-                lg = fleet_eval_stacked(
-                    cfg, sp, {"tokens": eval_tok, "labels": eval_lab,
-                              "loss_mask": jnp.ones(eval_tok.shape,
-                                                    jnp.float32)}, alpha)
-            hist.rounds.append(p.t)
-            hist.sim_time.append(planner.sim_clock)
-            hist.comm_gb.append(planner.comm_bytes / 1e9)
-            hist.loss_global.append(lg)
-            hist.loss_local.append(hist.round_loss[-1])
-            hist.staleness_avg.append(float(planner.st.tau.mean()))
-            hist.staleness_max.append(int(planner.st.tau.max()))
-            hist.eval_wall_s += time.time() - t_ev
+            with tr.span("drain"):
+                jax.block_until_ready(fleet.pbuf if run.resident_fleet
+                                      else jax.tree.leaves(sp)[0])
+            with tr.span("eval"):
+                drain_losses()
+                if run.resident_fleet:
+                    lg = float(engine.eval_global(fleet.pbuf, alpha_eval,
+                                                  eval_tok, eval_lab))
+                else:
+                    lg = fleet_eval_stacked(
+                        cfg, sp, {"tokens": eval_tok, "labels": eval_lab,
+                                  "loss_mask": jnp.ones(eval_tok.shape,
+                                                        jnp.float32)}, alpha)
+                hist.rounds.append(p.t)
+                hist.sim_time.append(planner.sim_clock)
+                hist.comm_gb.append(planner.comm_bytes / 1e9)
+                hist.loss_global.append(lg)
+                hist.loss_local.append(hist.round_loss[-1])
+                hist.staleness_avg.append(float(planner.st.tau.mean()))
+                hist.staleness_max.append(int(planner.st.tau.max()))
         if do_ckpt:
             # after the eval (snapshot history carries the eval point) and
             # with losses drained, so round_loss is complete up to round t
-            drain_losses()
-            save_snapshot(p.t)
+            with tr.span("snapshot"):
+                drain_losses()
+                save_snapshot(p.t)
 
     flush()
     pipe.drain()
-    hist.drain_wall_s += pipe.drain_wall_s
-    drain_losses()
+    with tr.span("drain"):
+        drain_losses()
     if not run.resident_fleet:
         fleet.stacked_params = sp         # write the oracle state back once
         fleet.stacked_opt = so
     if shd is not None and fleet.pbuf.shape[0] != n:
         fleet.pbuf = fleet.pbuf[:n]       # shed the shard padding: callers
         fleet.obuf = fleet.obuf[:n]       #   see the (N, ·) contract
-    hist.wall_s = time.time() - t_wall
+    tr.finish()
     return fleet, hist

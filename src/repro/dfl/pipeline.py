@@ -23,10 +23,11 @@ tests/test_pipeline.py and scripts/chaos_check.py).  Depth semantics:
   * ``depth >= 1`` — up to that many chunks in flight behind the one being
     staged (depth 1 is classic double buffering, the default on both planes).
 
-``drain_wall_s`` accounts every second the host spent blocked on device
-completion (back-pressure inside ``submit`` plus boundary drains) — the
-"device execute" column of the per-phase wall-time breakdown recorded in
-``History`` / ``LMHistory`` and emitted by the benchmarks.
+Every block is a ``drain`` span of the call's ``core.trace.Trace``
+(back-pressure inside ``submit``, counted as ``backpressure_waits``, and
+boundary drains): host time spent waiting on the device, which is not the
+device's execute time — that overlaps the host's other spans, and only the
+profiler's device trace gives it.
 
 This is also the dispatch discipline a multi-host ``jax.distributed`` lane
 would keep: the planner is model-value-independent, so broadcasting
@@ -35,43 +36,52 @@ same submit/drain contract with the network in the middle.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Any
 
 import jax
 
 
+def count_dispatch(trace, rounds: int, k_mix: int, k_train: int,
+                   h2d_bytes: int = 0) -> None:
+    """The counters of one dispatched chunk of ``rounds`` rounds, into the
+    call's ``core.trace.Trace``: its mix and train rows as dispatched
+    (``k_mix`` / ``k_train`` a round, bucket padding included) and the bytes
+    staged on the device for it."""
+    trace.count("dispatches")
+    trace.count("scan_dispatches", rounds > 1)
+    trace.count("mix_rows", rounds * k_mix)
+    trace.count("train_rows", rounds * k_train)
+    trace.count("h2d_bytes", h2d_bytes)
+
+
 class DispatchPipeline:
     """Bounded queue of in-flight device dispatches (see module docstring)."""
 
-    def __init__(self, depth: int):
+    def __init__(self, depth: int, trace):
         self.depth = max(0, int(depth))
+        self.trace = trace
         self._inflight: deque = deque()
-        self.drain_wall_s = 0.0
 
     def submit(self, token: Any) -> None:
         """Register one dispatched chunk's output (any jax array/pytree);
         blocks the OLDEST in-flight chunk(s) once more than ``depth`` are
         outstanding — back-pressure, so host plan-ahead stays bounded and
         donated buffers cannot pile up."""
-        if self.depth == 0:
-            t0 = time.perf_counter()
-            jax.block_until_ready(token)
-            self.drain_wall_s += time.perf_counter() - t0
-            return
         self._inflight.append(token)
-        while len(self._inflight) > self.depth:
-            t0 = time.perf_counter()
-            jax.block_until_ready(self._inflight.popleft())
-            self.drain_wall_s += time.perf_counter() - t0
+        if len(self._inflight) > self.depth:
+            self.trace.count("backpressure_waits")
+            with self.trace.span("drain"):
+                while len(self._inflight) > self.depth:
+                    jax.block_until_ready(self._inflight.popleft())
 
     def drain(self) -> None:
         """Block until every in-flight chunk has executed.  Called at every
         read-back boundary (eval / snapshot / scenario event / end of run):
         after a drain the resident buffers are round-consistent and host
         reads charge no device time to the wrong phase."""
-        t0 = time.perf_counter()
-        while self._inflight:
-            jax.block_until_ready(self._inflight.popleft())
-        self.drain_wall_s += time.perf_counter() - t0
+        if not self._inflight:
+            return
+        with self.trace.span("drain"):
+            while self._inflight:
+                jax.block_until_ready(self._inflight.popleft())

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import pathlib
-import time
 import warnings
 from typing import Dict, List, Optional
 
@@ -30,7 +29,8 @@ from repro.core.aggregation import (apply_mixing, mixing_rows,
 from repro.core.planner import (HorizonPlanner, PlannedRound, bucket_key,
                                 chunk_spans, mix_is_train)
 from repro.core.scenarios import resolve_scenario
-from repro.dfl.pipeline import DispatchPipeline
+from repro.core.trace import Trace
+from repro.dfl.pipeline import DispatchPipeline, count_dispatch
 from repro.core.protocol import Mechanism
 from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import (ClassificationData, make_classification,
@@ -262,20 +262,23 @@ class History:
     round durations — the paper's x-axis); ``comm_gb`` cumulative transfer
     volume in GB (Eq. 10 accounting at ``model_bytes_scale`` pricing);
     ``staleness_avg``/``staleness_max`` are in ROUNDS since last activation
-    (Eq. 6); ``wall_s``/``eval_wall_s``/``setup_wall_s`` are REAL host
-    seconds (benchmark accounting, not simulation state).
+    (Eq. 6).
 
-    Per-phase breakdown (real host seconds, benchmark accounting):
-    ``plan_wall_s`` is time in ``planner.plan_round`` (recorded at every
-    pipeline depth); ``pack_wall_s`` (chunk splitting + control-tensor
-    packing), ``stage_wall_s`` (H2D ``device_put`` staging) and
-    ``drain_wall_s`` (host blocked on device completion — back-pressure +
-    boundary drains) are recorded by the pipelined dispatch path
-    (``pipeline_depth >= 1``; the depth-0 oracle keeps its original
-    interleaved code and leaves them 0).  wall_s - eval_wall_s -
-    setup_wall_s - plan_wall_s is the dispatch-plane cost the pipelining
-    benchmark rows report, and drain_wall_s approximates the device-execute
-    share of it.
+    Host time (real seconds of this call, benchmark accounting, not
+    simulation state; ``core.trace.Trace`` writes them): ``wall_s`` is the
+    whole call, and each ``<span>_wall_s`` the self time of the host span of
+    that name — ``setup`` (data, partition, init, resume), ``plan``
+    (``planner.plan_round`` + the bucket key), ``pack`` (chunk splitting +
+    control-tensor packing), ``stage`` (the H2D ``device_put``),
+    ``enqueue`` (the jitted step call, entry to return), ``drain`` (host
+    blocked on the device: pipeline back-pressure, boundary drains and the
+    blocks before an eval or a snapshot reads the buffer), ``eval`` and
+    ``snapshot``.  These partition the call up to the loop's own
+    bookkeeping.  ``drain`` is waiting, not the device's execute time: that
+    overlaps the other spans, and only the profiler's device trace gives
+    it.  ``counts`` holds the trace's counters (dispatches, rows,
+    H2D bytes, back-pressure waits, compilations by span); unlike the
+    times, it is restored on resume, so it describes the whole trajectory.
     """
     rounds: List[int] = dataclasses.field(default_factory=list)
     sim_time: List[float] = dataclasses.field(default_factory=list)
@@ -299,7 +302,10 @@ class History:
     plan_wall_s: float = 0.0      # host wall in planner.plan_round
     pack_wall_s: float = 0.0      # chunk split + control-tensor packing
     stage_wall_s: float = 0.0     # H2D device_put staging
-    drain_wall_s: float = 0.0     # host blocked on device completion
+    enqueue_wall_s: float = 0.0   # jitted step calls, entry to return
+    drain_wall_s: float = 0.0     # host blocked on the device
+    snapshot_wall_s: float = 0.0  # save_snapshot, less its drain
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -324,159 +330,173 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         raise ValueError("resume_from cannot record a bound log: the "
                          "pre-kill rounds' active/W history is not "
                          "checkpointed")
-    rng = np.random.default_rng(cfg.seed)
-    t_wall = time.time()
-
-    # --- data ---
-    if data is None:
-        full = make_classification(cfg.n_samples, cfg.dim, seed=cfg.seed)
-        data, test_split = train_test_split(full, 0.2, seed=cfg.seed)
-        test = test or test_split
-    assert test is not None, "pass `test` when supplying `data`"
-    parts, class_counts = dirichlet_partition(data, cfg.n_workers, cfg.phi,
-                                              seed=cfg.seed)
-    data_sizes = np.array([len(p) for p in parts], np.float64)
-    alpha = jnp.asarray(data_sizes / data_sizes.sum(), jnp.float32)
-
-    # --- environment ---
-    net = EdgeNetwork(NetworkConfig(n_workers=cfg.n_workers), rng)
-    in_range = net.in_range()
-    h_i = heterogeneous_compute_times(cfg.n_workers, cfg.base_compute_s, rng,
-                                      sigma=cfg.compute_sigma)
-
-    # --- models ---
-    key = jax.random.PRNGKey(cfg.seed)
-    stacked = WK.init_stacked(key, cfg.n_workers, cfg.dim, cfg.hidden,
-                              data.n_classes)
-    model_bytes = WK.param_bytes(jax.tree.map(lambda l: l[0], stacked)) \
-        * cfg.model_bytes_scale
-    exp_link_time = net.expected_link_time(model_bytes)
-
-    # batch sampling draws from a dedicated stream so the control-plane rng
-    # trajectory (mechanism decisions, channels, failures) is identical
-    # between the fused engine (jax.random on device) and the legacy path
-    # (numpy on host) — histories stay comparable metric-for-metric
-    batch_rng = np.random.default_rng(cfg.seed + 0x5EED)
-    batch_key = jax.random.PRNGKey(cfg.seed + 0x5EED)
-    shd = None
-    if cfg.mesh_shards > 1:
-        if not cfg.fused_engine:
-            raise ValueError(
-                "mesh_shards > 1 requires the fused engine "
-                "(fused_engine=True): the legacy per-leaf path has no "
-                "resident buffer to shard")
-        from repro.sharding.rules import FleetSharding
-        shd = FleetSharding.create(cfg.mesh_shards)
-    if cfg.fused_engine:
-        buf, flat_spec = FS.flatten_stacked(stacked)
-        stacked = None                     # the flat buffer IS the storage
-        data_x = jnp.asarray(data.x)       # device-resident dataset
-        data_y = jnp.asarray(data.y)
-        max_part = max(len(p) for p in parts)
-        part_idx = np.zeros((cfg.n_workers, max_part), np.int32)
-        for i, p in enumerate(parts):
-            part_idx[i, :len(p)] = p       # padding never sampled (uniform
-        part_sizes = data_sizes.astype(np.int32)  # draws < the true size
-        if shd is not None:
-            # pad the worker axis to a shard multiple (jax NamedShardings
-            # need even splits); padding rows are permanently idle — never
-            # activated, mixed, or evaluated — so zeros are fine.  The
-            # resident dataset partitions row-wise across the mesh too
-            # (sample padding is never indexed: part_idx holds real ids only)
-            row_pad = shd.pad(cfg.n_workers)
-            if row_pad:
-                part_idx = np.pad(part_idx, ((0, row_pad), (0, 0)))
-                part_sizes = np.pad(part_sizes, (0, row_pad),
-                                    constant_values=1)
-            buf = shd.put_rows_padded(buf)
-            data_x = shd.put_rows_padded(data_x)
-            data_y = shd.put_rows_padded(data_y)
-            part_idx = shd.put_rows(jnp.asarray(part_idx))
-            part_sizes = shd.put_rows(jnp.asarray(part_sizes))
-            batch_key = shd.put(batch_key)
-        else:
-            part_idx = jnp.asarray(part_idx)
-            part_sizes = jnp.asarray(part_sizes)
-
-    # --- control plane: the horizon planner owns all mutable control state
-    # (staleness, pull counts, readiness clocks, failure mask, sim clock) and
-    # replays Alg. 1 bookkeeping round-by-round — model-value-independent, so
-    # it can run arbitrarily far ahead of the device dispatches
-    scen = resolve_scenario(cfg.scenario, cfg.n_workers, cfg.n_rounds,
-                            dist=net.dist, comm_range_m=net.cfg.comm_range_m)
-    planner = HorizonPlanner(
-        mechanism, h_i=h_i, in_range=in_range, exp_link_time=exp_link_time,
-        model_bytes=model_bytes, class_counts=class_counts,
-        data_sizes=data_sizes, net=net, rng=rng, tau_bound=cfg.tau_bound,
-        bandwidth_budget=cfg.bandwidth_budget,
-        link_timeout_s=cfg.link_timeout_s,
-        sync_link_timeout_s=cfg.sync_link_timeout_s,
-        failure_prob=cfg.failure_prob, failure_persist=cfg.failure_persist,
-        mesh_shards=cfg.mesh_shards, scenario=scen)
-    x_test = jnp.asarray(test.x)
-    y_test = jnp.asarray(test.y)
-
     hist = History()
-    bound_log = {"active": [], "W": []} if record_history_for_bound else None
+    tr = Trace(hist)
+    with tr.span("setup"):
+        rng = np.random.default_rng(cfg.seed)
 
-    # --- crash-safe resume: overwrite the deterministic setup's mutable
-    # state with the snapshot.  Setup above consumed the exact same rng
-    # draws as the original run's setup, so only the planner state, the
-    # model rows, the (legacy) batch stream, and the history need restoring.
-    if resume_from is not None:
-        ck = pathlib.Path(resume_from)
-        if ck.is_dir():
-            found = CIO.latest_checkpoint(ck)
-            if found is None:
-                raise FileNotFoundError(
-                    f"resume_from={ck} is a directory with no "
-                    f"ckpt_round*.npz snapshot in it")
-            ck = found
-        arr_tmpl = {k: np.zeros_like(v)
-                    for k, v in planner.state_dict()["arrays"].items()}
-        if cfg.fused_engine:
-            n_params = int(buf.shape[1])
-            model_tmpl = {"buf": np.zeros((cfg.n_workers, n_params),
-                                          np.float32)}
-            model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl,
-                                                       arr_tmpl)
-        else:
-            model, arrays, extra = CIO.load_checkpoint(ck, stacked, arr_tmpl)
-        saved_cfg = extra.get("config", {})
-        for k in ("plane", "n_workers", "seed", "fused_engine",
-                  "mesh_shards", "scenario"):
-            want = {"plane": "sim",
-                    "scenario": scen.schedule.name if scen else None
-                    }.get(k, getattr(cfg, k, None))
-            if k in saved_cfg and saved_cfg[k] != want:
+        # --- data ---
+        if data is None:
+            full = make_classification(cfg.n_samples, cfg.dim,
+                                       seed=cfg.seed)
+            data, test_split = train_test_split(full, 0.2, seed=cfg.seed)
+            test = test or test_split
+        assert test is not None, "pass `test` when supplying `data`"
+        parts, class_counts = dirichlet_partition(data, cfg.n_workers,
+                                                  cfg.phi, seed=cfg.seed)
+        data_sizes = np.array([len(p) for p in parts], np.float64)
+        alpha = jnp.asarray(data_sizes / data_sizes.sum(), jnp.float32)
+
+        # --- environment ---
+        net = EdgeNetwork(NetworkConfig(n_workers=cfg.n_workers), rng)
+        in_range = net.in_range()
+        h_i = heterogeneous_compute_times(cfg.n_workers, cfg.base_compute_s,
+                                          rng, sigma=cfg.compute_sigma)
+
+        # --- models ---
+        key = jax.random.PRNGKey(cfg.seed)
+        stacked = WK.init_stacked(key, cfg.n_workers, cfg.dim, cfg.hidden,
+                                  data.n_classes)
+        model_bytes = WK.param_bytes(
+            jax.tree.map(lambda l: l[0], stacked)) * cfg.model_bytes_scale
+        exp_link_time = net.expected_link_time(model_bytes)
+
+        # batch sampling draws from a dedicated stream so the control-plane
+        # rng trajectory (mechanism decisions, channels, failures) is
+        # identical between the fused engine (jax.random on device) and the
+        # legacy path (numpy on host) — histories stay comparable
+        # metric-for-metric
+        batch_rng = np.random.default_rng(cfg.seed + 0x5EED)
+        batch_key = jax.random.PRNGKey(cfg.seed + 0x5EED)
+        shd = None
+        if cfg.mesh_shards > 1:
+            if not cfg.fused_engine:
                 raise ValueError(
-                    f"resume config mismatch: snapshot {ck.name} was written "
-                    f"with {k}={saved_cfg[k]!r} but this run has {k}={want!r}"
-                    f" — resuming must use the identical configuration")
-        planner.load_state({"arrays": arrays,
-                            "scalars": extra["planner_scalars"],
-                            "rng_state": extra["planner_rng"]})
+                    "mesh_shards > 1 requires the fused engine "
+                    "(fused_engine=True): the legacy per-leaf path has no "
+                    "resident buffer to shard")
+            from repro.sharding.rules import FleetSharding
+            shd = FleetSharding.create(cfg.mesh_shards)
         if cfg.fused_engine:
-            restored = jnp.asarray(model["buf"])
-            # rebuild the padded+sharded residency exactly as first init did
-            buf = (shd.put_rows_padded(restored) if shd is not None
-                   else restored)
-        else:
-            stacked = model
-            batch_rng.bit_generator.state = extra["batch_rng"]
-        for k, v in extra["history"].items():
-            if hasattr(hist, k):
-                setattr(hist, k, v)
-    horizon = max(1, cfg.scan_horizon) if cfg.fused_engine else 1
-    # the fused SGD lowering hand-differentiates the sim-plane MLP; any other
-    # architecture plugged into the flat buffer falls back to the AD scan
-    fused_sgd = (cfg.fused_engine and cfg.fused_local_sgd
-                 and WK.fused_sgd_supported(flat_spec))
-    # async dispatch pipeline (ROADMAP item 5): depth >= 1 overlaps host
-    # plan/pack/stage with device execution, bounded at `depth` chunks in
-    # flight; depth 0 keeps the original lockstep flush() verbatim (oracle)
-    pipelined = cfg.fused_engine and cfg.pipeline_depth > 0
-    pipe = DispatchPipeline(cfg.pipeline_depth)
+            buf, flat_spec = FS.flatten_stacked(stacked)
+            stacked = None                  # the flat buffer IS the storage
+            data_x = jnp.asarray(data.x)    # device-resident dataset
+            data_y = jnp.asarray(data.y)
+            max_part = max(len(p) for p in parts)
+            part_idx = np.zeros((cfg.n_workers, max_part), np.int32)
+            for i, p in enumerate(parts):
+                part_idx[i, :len(p)] = p    # padding never sampled
+            # (uniform draws < the true size)
+            part_sizes = data_sizes.astype(np.int32)
+            if shd is not None:
+                # pad the worker axis to a shard multiple (jax
+                # NamedShardings need even splits); padding rows are
+                # permanently idle — never activated, mixed, or evaluated —
+                # so zeros are fine.  The resident dataset partitions
+                # row-wise across the mesh too (sample padding is never
+                # indexed: part_idx holds real ids only)
+                row_pad = shd.pad(cfg.n_workers)
+                if row_pad:
+                    part_idx = np.pad(part_idx, ((0, row_pad), (0, 0)))
+                    part_sizes = np.pad(part_sizes, (0, row_pad),
+                                        constant_values=1)
+                buf = shd.put_rows_padded(buf)
+                data_x = shd.put_rows_padded(data_x)
+                data_y = shd.put_rows_padded(data_y)
+                part_idx = shd.put_rows(jnp.asarray(part_idx))
+                part_sizes = shd.put_rows(jnp.asarray(part_sizes))
+                batch_key = shd.put(batch_key)
+            else:
+                part_idx = jnp.asarray(part_idx)
+                part_sizes = jnp.asarray(part_sizes)
+
+        # --- control plane: the horizon planner owns all mutable control
+        # state (staleness, pull counts, readiness clocks, failure mask, sim
+        # clock) and replays Alg. 1 bookkeeping round-by-round —
+        # model-value-independent, so it can run arbitrarily far ahead of
+        # the device dispatches
+        scen = resolve_scenario(cfg.scenario, cfg.n_workers, cfg.n_rounds,
+                                dist=net.dist,
+                                comm_range_m=net.cfg.comm_range_m)
+        planner = HorizonPlanner(
+            mechanism, h_i=h_i, in_range=in_range,
+            exp_link_time=exp_link_time, model_bytes=model_bytes,
+            class_counts=class_counts, data_sizes=data_sizes, net=net,
+            rng=rng, tau_bound=cfg.tau_bound,
+            bandwidth_budget=cfg.bandwidth_budget,
+            link_timeout_s=cfg.link_timeout_s,
+            sync_link_timeout_s=cfg.sync_link_timeout_s,
+            failure_prob=cfg.failure_prob,
+            failure_persist=cfg.failure_persist,
+            mesh_shards=cfg.mesh_shards, scenario=scen)
+        x_test = jnp.asarray(test.x)
+        y_test = jnp.asarray(test.y)
+
+        bound_log = ({"active": [], "W": []} if record_history_for_bound
+                     else None)
+
+        # --- crash-safe resume: overwrite the deterministic setup's mutable
+        # state with the snapshot.  Setup above consumed the exact same rng
+        # draws as the original run's setup, so only the planner state, the
+        # model rows, the (legacy) batch stream, and the history need
+        # restoring.  The history's host times stay this call's own.
+        if resume_from is not None:
+            ck = pathlib.Path(resume_from)
+            if ck.is_dir():
+                found = CIO.latest_checkpoint(ck)
+                if found is None:
+                    raise FileNotFoundError(
+                        f"resume_from={ck} is a directory with no "
+                        f"ckpt_round*.npz snapshot in it")
+                ck = found
+            arr_tmpl = {k: np.zeros_like(v)
+                        for k, v in planner.state_dict()["arrays"].items()}
+            if cfg.fused_engine:
+                n_params = int(buf.shape[1])
+                model_tmpl = {"buf": np.zeros((cfg.n_workers, n_params),
+                                              np.float32)}
+                model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl,
+                                                           arr_tmpl)
+            else:
+                model, arrays, extra = CIO.load_checkpoint(ck, stacked,
+                                                           arr_tmpl)
+            saved_cfg = extra.get("config", {})
+            for k in ("plane", "n_workers", "seed", "fused_engine",
+                      "mesh_shards", "scenario"):
+                want = {"plane": "sim",
+                        "scenario": scen.schedule.name if scen else None
+                        }.get(k, getattr(cfg, k, None))
+                if k in saved_cfg and saved_cfg[k] != want:
+                    raise ValueError(
+                        f"resume config mismatch: snapshot {ck.name} was "
+                        f"written with {k}={saved_cfg[k]!r} but this run has "
+                        f"{k}={want!r} — resuming must use the identical "
+                        f"configuration")
+            planner.load_state({"arrays": arrays,
+                                "scalars": extra["planner_scalars"],
+                                "rng_state": extra["planner_rng"]})
+            if cfg.fused_engine:
+                restored = jnp.asarray(model["buf"])
+                # rebuild the padded+sharded residency exactly as first init
+                buf = (shd.put_rows_padded(restored) if shd is not None
+                       else restored)
+            else:
+                stacked = model
+                batch_rng.bit_generator.state = extra["batch_rng"]
+            for k, v in extra["history"].items():
+                if hasattr(hist, k) and not k.endswith("wall_s"):
+                    setattr(hist, k, v)
+        horizon = max(1, cfg.scan_horizon) if cfg.fused_engine else 1
+        # the fused SGD lowering hand-differentiates the sim-plane MLP; any
+        # other architecture plugged into the flat buffer falls back to the
+        # AD scan
+        fused_sgd = (cfg.fused_engine and cfg.fused_local_sgd
+                     and WK.fused_sgd_supported(flat_spec))
+        # async dispatch pipeline (ROADMAP item 5): depth >= 1 overlaps host
+        # plan/pack/stage with device execution, bounded at `depth` chunks in
+        # flight; depth 0 keeps the original lockstep flush() (the oracle)
+        pipelined = cfg.fused_engine and cfg.pipeline_depth > 0
+        pipe = DispatchPipeline(cfg.pipeline_depth, tr)
 
     def use_cols(key):
         """Column-sparse contraction for a chunk with these shape buckets?
@@ -501,67 +521,87 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         if cfg.fused_engine:
             put = shd.put if shd is not None else jnp.asarray
             n_rows = cfg.n_workers + (shd.pad(cfg.n_workers) if shd else 0)
-            for lo, hi, key in chunk_spans(plans, cfg.n_workers,
-                                           col_sparse=cfg.col_sparse_mix,
-                                           min_bucket=cfg.min_bucket,
-                                           mesh_shards=cfg.mesh_shards):
+            with tr.span("pack"):
+                spans = list(chunk_spans(plans, cfg.n_workers,
+                                         col_sparse=cfg.col_sparse_mix,
+                                         min_bucket=cfg.min_bucket,
+                                         mesh_shards=cfg.mesh_shards))
+            for lo, hi, key in spans:
                 chunk = plans[lo:hi]
                 col = use_cols(key)
                 if len(chunk) > 1:
-                    w_rows_h, ctrl_h, ts = WK.pack_horizon(
-                        chunk, min_bucket=cfg.min_bucket, col_sparse=col,
-                        shards=cfg.mesh_shards)
-                    if not col:
-                        w_rows_h = WK.pad_w_cols(w_rows_h, n_rows)
-                    buf, _ = WK.mega_round_step(
-                        buf, put(w_rows_h), put(ctrl_h),
-                        put(ts), data_x, data_y, part_idx,
-                        part_sizes, batch_key, spec=flat_spec, lr=cfg.lr,
-                        local_steps=cfg.local_steps,
-                        batch_size=cfg.batch_size, kernels=cfg.kernels,
-                        col_sparse=col, fused_sgd=fused_sgd,
-                        with_losses=False,
-                        mix_is_train=(fused_sgd
-                                      and all(mix_is_train(p)
-                                              for p in chunk)),
-                        shd=shd)
+                    with tr.span("pack"):
+                        w_rows_h, ctrl_h, ts = WK.pack_horizon(
+                            chunk, min_bucket=cfg.min_bucket, col_sparse=col,
+                            shards=cfg.mesh_shards)
+                        if not col:
+                            w_rows_h = WK.pad_w_cols(w_rows_h, n_rows)
+                    with tr.span("stage"):
+                        w_j, c_j, ts_j = put(w_rows_h), put(ctrl_h), put(ts)
+                    with tr.span("enqueue"):
+                        buf, _ = WK.mega_round_step(
+                            buf, w_j, c_j, ts_j, data_x, data_y, part_idx,
+                            part_sizes, batch_key, spec=flat_spec, lr=cfg.lr,
+                            local_steps=cfg.local_steps,
+                            batch_size=cfg.batch_size, kernels=cfg.kernels,
+                            col_sparse=col, fused_sgd=fused_sgd,
+                            with_losses=False,
+                            mix_is_train=(fused_sgd
+                                          and all(mix_is_train(p)
+                                                  for p in chunk)),
+                            shd=shd)
+                    count_dispatch(tr, len(chunk), key[0], key[1],
+                                   w_rows_h.nbytes + ctrl_h.nbytes
+                                   + ts.nbytes)
                     continue
                 # single-round path: one donated round_step dispatch; with
                 # col_sparse_mix/fused_local_sgd off this is bit-for-bit the
                 # pre-horizon PR 1 engine (the correctness oracle)
                 p = chunk[0]
-                if col:
-                    w_rows, mix_ids, col_ids = mixing_rows_cols(
-                        p.W, p.active, p.links, cols_mask=p.mix_cols,
-                        min_bucket=cfg.min_bucket, shards=cfg.mesh_shards)
-                else:
-                    w_rows, mix_ids = mixing_rows(p.W, p.active, p.links,
-                                                  min_bucket=cfg.min_bucket,
-                                                  shards=cfg.mesh_shards)
-                    w_rows = WK.pad_w_cols(w_rows, n_rows)
-                    col_ids = None
-                train_ids, train_mask = padded_rows(p.active,
-                                                    min_bucket=cfg.min_bucket,
-                                                    shards=cfg.mesh_shards)
-                ctrl = WK.pack_round_ctrl(mix_ids, train_ids, train_mask,
-                                          col_ids=col_ids)
-                buf, _ = WK.round_step(
-                    buf, put(w_rows), put(ctrl),
-                    data_x, data_y, part_idx, part_sizes, batch_key,
-                    np.int32(p.t), spec=flat_spec, lr=cfg.lr,
-                    local_steps=cfg.local_steps, batch_size=cfg.batch_size,
-                    kernels=cfg.kernels,
-                    col_sparse=col, fused_sgd=fused_sgd, with_losses=False,
-                    mix_is_train=fused_sgd and mix_is_train(p), shd=shd)
+                with tr.span("pack"):
+                    if col:
+                        w_rows, mix_ids, col_ids = mixing_rows_cols(
+                            p.W, p.active, p.links, cols_mask=p.mix_cols,
+                            min_bucket=cfg.min_bucket,
+                            shards=cfg.mesh_shards)
+                    else:
+                        w_rows, mix_ids = mixing_rows(
+                            p.W, p.active, p.links,
+                            min_bucket=cfg.min_bucket,
+                            shards=cfg.mesh_shards)
+                        w_rows = WK.pad_w_cols(w_rows, n_rows)
+                        col_ids = None
+                    train_ids, train_mask = padded_rows(
+                        p.active, min_bucket=cfg.min_bucket,
+                        shards=cfg.mesh_shards)
+                    ctrl = WK.pack_round_ctrl(mix_ids, train_ids, train_mask,
+                                              col_ids=col_ids)
+                with tr.span("stage"):
+                    w_j, c_j = put(w_rows), put(ctrl)
+                with tr.span("enqueue"):
+                    buf, _ = WK.round_step(
+                        buf, w_j, c_j,
+                        data_x, data_y, part_idx, part_sizes, batch_key,
+                        np.int32(p.t), spec=flat_spec, lr=cfg.lr,
+                        local_steps=cfg.local_steps,
+                        batch_size=cfg.batch_size, kernels=cfg.kernels,
+                        col_sparse=col, fused_sgd=fused_sgd,
+                        with_losses=False,
+                        mix_is_train=fused_sgd and mix_is_train(p), shd=shd)
+                count_dispatch(tr, 1, key[0], key[1],
+                               w_rows.nbytes + ctrl.nbytes)
         else:
             for p in plans:
-                stacked = apply_mixing(jnp.asarray(p.W), stacked,
-                                       kernels=cfg.kernels)
-                xb, yb = _sample_batches(parts, data, cfg, batch_rng)
-                stacked, _ = WK.local_train(stacked, xb, yb,
-                                            jnp.asarray(p.active),
-                                            lr=cfg.lr,
-                                            local_steps=cfg.local_steps)
+                with tr.span("pack"):
+                    xb, yb = _sample_batches(parts, data, cfg, batch_rng)
+                with tr.span("enqueue"):
+                    stacked = apply_mixing(jnp.asarray(p.W), stacked,
+                                           kernels=cfg.kernels)
+                    stacked, _ = WK.local_train(stacked, xb, yb,
+                                                jnp.asarray(p.active),
+                                                lr=cfg.lr,
+                                                local_steps=cfg.local_steps)
+                count_dispatch(tr, 1, cfg.n_workers, cfg.n_workers)
 
     def flush_pipelined(plans):
         """The depth >= 1 twin of ``flush``: identical dispatches (same
@@ -573,73 +613,71 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         ONE fused non-blocking ``jax.device_put`` per chunk instead of three
         ``jnp.asarray`` round-trips, and no implicit block — ``pipe.submit``
         bounds the in-flight chunks and the drive loop drains only at
-        read-back boundaries.  Per-phase walls land in the History."""
+        read-back boundaries."""
         nonlocal buf
         put = shd.put if shd is not None else None
         n_rows = cfg.n_workers + (shd.pad(cfg.n_workers) if shd else 0)
-        t0 = time.perf_counter()
-        spans = list(chunk_spans(plans, cfg.n_workers,
-                                 col_sparse=cfg.col_sparse_mix,
-                                 min_bucket=cfg.min_bucket,
-                                 mesh_shards=cfg.mesh_shards))
-        hist.pack_wall_s += time.perf_counter() - t0
+        with tr.span("pack"):
+            spans = list(chunk_spans(plans, cfg.n_workers,
+                                     col_sparse=cfg.col_sparse_mix,
+                                     min_bucket=cfg.min_bucket,
+                                     mesh_shards=cfg.mesh_shards))
         for lo, hi, key in spans:
             chunk = plans[lo:hi]
             col = use_cols(key)
-            t0 = time.perf_counter()
             if len(chunk) > 1:
-                w_rows_h, ctrl_h, ts = WK.pack_chunk(
-                    chunk, key, min_bucket=cfg.min_bucket, col_sparse=col,
-                    shards=cfg.mesh_shards)
-                if not col:
-                    w_rows_h = WK.pad_w_cols(w_rows_h, n_rows)
-                mit = fused_sgd and all(mix_is_train(p) for p in chunk)
-                t1 = time.perf_counter()
-                hist.pack_wall_s += t1 - t0
-                if put is not None:
-                    w_j, c_j, ts_j = put(w_rows_h), put(ctrl_h), put(ts)
-                else:
-                    w_j, c_j, ts_j = jax.device_put((w_rows_h, ctrl_h, ts))
-                hist.stage_wall_s += time.perf_counter() - t1
-                buf, done = WK.mega_round_step(
-                    buf, w_j, c_j, ts_j, data_x, data_y, part_idx,
-                    part_sizes, batch_key, spec=flat_spec, lr=cfg.lr,
-                    local_steps=cfg.local_steps, batch_size=cfg.batch_size,
-                    kernels=cfg.kernels, col_sparse=col,
-                    fused_sgd=fused_sgd, with_losses=False,
-                    mix_is_train=mit, shd=shd)
+                with tr.span("pack"):
+                    host = WK.pack_chunk(
+                        chunk, key, min_bucket=cfg.min_bucket,
+                        col_sparse=col, shards=cfg.mesh_shards)
+                    if not col:
+                        host = (WK.pad_w_cols(host[0], n_rows),) + host[1:]
+                    mit = fused_sgd and all(mix_is_train(p) for p in chunk)
+                with tr.span("stage"):
+                    w_j, c_j, ts_j = (tuple(map(put, host)) if put is not None
+                                      else jax.device_put(host))
+                with tr.span("enqueue"):
+                    buf, done = WK.mega_round_step(
+                        buf, w_j, c_j, ts_j, data_x, data_y, part_idx,
+                        part_sizes, batch_key, spec=flat_spec, lr=cfg.lr,
+                        local_steps=cfg.local_steps,
+                        batch_size=cfg.batch_size, kernels=cfg.kernels,
+                        col_sparse=col, fused_sgd=fused_sgd,
+                        with_losses=False, mix_is_train=mit, shd=shd)
             else:
                 p = chunk[0]
-                if col:
-                    w_rows, mix_ids, col_ids = mixing_rows_cols(
-                        p.W, p.active, p.links, cols_mask=p.mix_cols,
-                        min_bucket=cfg.min_bucket, shards=cfg.mesh_shards)
-                else:
-                    w_rows, mix_ids = mixing_rows(p.W, p.active, p.links,
-                                                  min_bucket=cfg.min_bucket,
-                                                  shards=cfg.mesh_shards)
-                    w_rows = WK.pad_w_cols(w_rows, n_rows)
-                    col_ids = None
-                train_ids, train_mask = padded_rows(
-                    p.active, min_bucket=cfg.min_bucket,
-                    shards=cfg.mesh_shards)
-                ctrl = WK.pack_round_ctrl(mix_ids, train_ids, train_mask,
-                                          col_ids=col_ids)
-                mit = fused_sgd and mix_is_train(p)
-                t1 = time.perf_counter()
-                hist.pack_wall_s += t1 - t0
-                if put is not None:
-                    w_j, c_j = put(w_rows), put(ctrl)
-                else:
-                    w_j, c_j = jax.device_put((w_rows, ctrl))
-                hist.stage_wall_s += time.perf_counter() - t1
-                buf, done = WK.round_step(
-                    buf, w_j, c_j, data_x, data_y, part_idx, part_sizes,
-                    batch_key, np.int32(p.t), spec=flat_spec, lr=cfg.lr,
-                    local_steps=cfg.local_steps, batch_size=cfg.batch_size,
-                    kernels=cfg.kernels, col_sparse=col,
-                    fused_sgd=fused_sgd, with_losses=False,
-                    mix_is_train=mit, shd=shd)
+                with tr.span("pack"):
+                    if col:
+                        w_rows, mix_ids, col_ids = mixing_rows_cols(
+                            p.W, p.active, p.links, cols_mask=p.mix_cols,
+                            min_bucket=cfg.min_bucket,
+                            shards=cfg.mesh_shards)
+                    else:
+                        w_rows, mix_ids = mixing_rows(
+                            p.W, p.active, p.links,
+                            min_bucket=cfg.min_bucket,
+                            shards=cfg.mesh_shards)
+                        w_rows = WK.pad_w_cols(w_rows, n_rows)
+                        col_ids = None
+                    train_ids, train_mask = padded_rows(
+                        p.active, min_bucket=cfg.min_bucket,
+                        shards=cfg.mesh_shards)
+                    host = (w_rows, WK.pack_round_ctrl(
+                        mix_ids, train_ids, train_mask, col_ids=col_ids))
+                    mit = fused_sgd and mix_is_train(p)
+                with tr.span("stage"):
+                    w_j, c_j = (tuple(map(put, host)) if put is not None
+                                else jax.device_put(host))
+                with tr.span("enqueue"):
+                    buf, done = WK.round_step(
+                        buf, w_j, c_j, data_x, data_y, part_idx, part_sizes,
+                        batch_key, np.int32(p.t), spec=flat_spec, lr=cfg.lr,
+                        local_steps=cfg.local_steps,
+                        batch_size=cfg.batch_size, kernels=cfg.kernels,
+                        col_sparse=col, fused_sgd=fused_sgd,
+                        with_losses=False, mix_is_train=mit, shd=shd)
+            count_dispatch(tr, len(chunk), key[0], key[1],
+                           sum(a.nbytes for a in host))
             # track the NON-donated output: the buffer itself is donated
             # into the next chunk's dispatch, so it cannot be the in-flight
             # token; the loss output of the SAME executable materializes
@@ -654,7 +692,9 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         if cfg.fused_engine:
             view = buf if buf.shape[0] == cfg.n_workers \
                 else buf[:cfg.n_workers]
-            model = {"buf": np.asarray(jax.block_until_ready(view))}
+            with tr.span("drain"):
+                jax.block_until_ready(view)
+            model = {"buf": np.asarray(view)}
         else:
             model = stacked
         extra = {
@@ -673,21 +713,20 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                             model, opt_state=snap["arrays"], extra=extra)
         CIO.prune_checkpoints(cfg.checkpoint_dir, cfg.checkpoint_keep)
 
-    hist.setup_wall_s = time.time() - t_wall
     pending: list[PlannedRound] = []
     stop = False
     while planner.t < cfg.n_rounds and not stop:
-        t0p = time.perf_counter()
-        p = planner.plan_round()
-        if cfg.fused_engine:
-            # resolve the round's shape-bucket key at plan time (memoized on
-            # the plan, every depth): dispatch-path chunk_spans then only
-            # does lookups — bucketing is control-plane work and belongs
-            # with the planner, not on the dispatch critical path
-            bucket_key(p, cfg.n_workers, col_sparse=cfg.col_sparse_mix,
-                       min_bucket=cfg.min_bucket,
-                       mesh_shards=cfg.mesh_shards)
-        hist.plan_wall_s += time.perf_counter() - t0p
+        with tr.span("plan"):
+            p = planner.plan_round()
+            if cfg.fused_engine:
+                # resolve the round's shape-bucket key at plan time
+                # (memoized on the plan, every depth): dispatch-path
+                # chunk_spans then only does lookups — bucketing is
+                # control-plane work and belongs with the planner, not on
+                # the dispatch critical path
+                bucket_key(p, cfg.n_workers, col_sparse=cfg.col_sparse_mix,
+                           min_bucket=cfg.min_bucket,
+                           mesh_shards=cfg.mesh_shards)
         t = p.t
         sim_clock = planner.sim_clock
         hist.round_durations.append(p.duration)
@@ -731,45 +770,45 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         if do_eval:
             # drain queued round dispatches first so their device time is
             # charged to the rounds, not to the eval
-            jax.block_until_ready(buf if cfg.fused_engine else stacked)
-            t_eval = time.time()
-            if cfg.fused_engine:
-                # flat-native eval: Eq. 11 global model is one alpha @ buf
-                # matvec; no stacked pytree is materialized.  A padded
-                # sharded buffer evals its first N rows only (padding rows
-                # are idle replicas of w_0 and must not enter the means)
-                view = buf if buf.shape[0] == cfg.n_workers \
-                    else buf[:cfg.n_workers]
-                accg, lossg = WK.evaluate_global_flat(view, alpha, x_test,
-                                                      y_test, spec=flat_spec)
-                accl, _ = WK.evaluate_stacked_flat(view, x_test, y_test,
-                                                   spec=flat_spec)
-            else:
-                accg, lossg = WK.evaluate_global(stacked, alpha, x_test,
-                                                 y_test)
-                accl, _ = WK.evaluate_stacked(stacked, x_test, y_test)
-            hist.rounds.append(t)
-            hist.sim_time.append(sim_clock)
-            hist.comm_gb.append(planner.comm_bytes / 1e9)
-            hist.acc_global.append(float(accg))
-            hist.acc_local.append(float(accl))
-            hist.loss_global.append(float(lossg))
-            hist.staleness_avg.append(float(planner.st.tau.mean()))
-            hist.staleness_max.append(int(planner.st.tau.max()))
-            if (cfg.target_accuracy is not None
-                    and hist.completion_time is None
-                    and float(accg) >= cfg.target_accuracy):
-                hist.completion_time = sim_clock
-                hist.completion_comm_gb = planner.comm_bytes / 1e9
-            hist.eval_wall_s += time.time() - t_eval
+            with tr.span("drain"):
+                jax.block_until_ready(buf if cfg.fused_engine else stacked)
+            with tr.span("eval"):
+                if cfg.fused_engine:
+                    # flat-native eval: Eq. 11 global model is one alpha @ buf
+                    # matvec; no stacked pytree is materialized.  A padded
+                    # sharded buffer evals its first N rows only (padding rows
+                    # are idle replicas of w_0 and must not enter the means)
+                    view = buf if buf.shape[0] == cfg.n_workers \
+                        else buf[:cfg.n_workers]
+                    accg, lossg = WK.evaluate_global_flat(
+                        view, alpha, x_test, y_test, spec=flat_spec)
+                    accl, _ = WK.evaluate_stacked_flat(view, x_test, y_test,
+                                                       spec=flat_spec)
+                else:
+                    accg, lossg = WK.evaluate_global(stacked, alpha, x_test,
+                                                     y_test)
+                    accl, _ = WK.evaluate_stacked(stacked, x_test, y_test)
+                hist.rounds.append(t)
+                hist.sim_time.append(sim_clock)
+                hist.comm_gb.append(planner.comm_bytes / 1e9)
+                hist.acc_global.append(float(accg))
+                hist.acc_local.append(float(accl))
+                hist.loss_global.append(float(lossg))
+                hist.staleness_avg.append(float(planner.st.tau.mean()))
+                hist.staleness_max.append(int(planner.st.tau.max()))
+                if (cfg.target_accuracy is not None
+                        and hist.completion_time is None
+                        and float(accg) >= cfg.target_accuracy):
+                    hist.completion_time = sim_clock
+                    hist.completion_comm_gb = planner.comm_bytes / 1e9
         if do_ckpt:
             # after the eval so a snapshot at an eval round carries that
             # round's history point — the resumed run never re-evals it
-            save_snapshot(t)
+            with tr.span("snapshot"):
+                save_snapshot(t)
 
     pipe.drain()
-    hist.drain_wall_s += pipe.drain_wall_s
-    hist.wall_s = time.time() - t_wall
+    tr.finish()
     if bound_log is not None:
         hist.bound_log = bound_log  # type: ignore[attr-defined]
     return hist

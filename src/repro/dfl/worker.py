@@ -141,11 +141,12 @@ def evaluate_global_flat(buf: jnp.ndarray, alpha: jnp.ndarray,
 
     The global model is one ``alpha @ buf`` matvec + a static unravel — no
     stacked pytree is materialized, so horizon-boundary evals stay cheap."""
-    gm = FS.unravel_row(FS.weighted_row(buf, alpha), spec)
-    logits = mlp_logits(gm, x)
-    acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
-    logp = jax.nn.log_softmax(logits, -1)
-    loss = -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
+    with jax.named_scope("eval"):
+        gm = FS.unravel_row(FS.weighted_row(buf, alpha), spec)
+        logits = mlp_logits(gm, x)
+        acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+        logp = jax.nn.log_softmax(logits, -1)
+        loss = -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
     return acc, loss
 
 
@@ -161,8 +162,9 @@ def evaluate_stacked_flat(buf: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray,
         loss = -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
         return acc, loss
 
-    accs, losses = jax.vmap(one)(buf)
-    return accs.mean(), losses.mean()
+    with jax.named_scope("eval"):
+        accs, losses = jax.vmap(one)(buf)
+        return accs.mean(), losses.mean()
 
 
 # --------------------------------------------------------------------------- #
@@ -492,23 +494,30 @@ def _mix_train_body(buf: jnp.ndarray, w_rows: jnp.ndarray,
         return _pin(new_sub, sub_shd), sub_loss
 
     if fused_sgd and mix_is_train and k_train > 0 and w_rows.shape[0] > 0:
-        sub = _mix_rows(buf, w_rows, col_ids, kernels, shd)
-        new_sub, sub_loss = train_rows(sub)
-        buf = _pin_rows(buf.at[train_row_ids].set(new_sub), shd)
+        with jax.named_scope("mix"):
+            sub = _mix_rows(buf, w_rows, col_ids, kernels, shd)
+        with jax.named_scope("sgd"):
+            new_sub, sub_loss = train_rows(sub)
+        with jax.named_scope("write_back"):
+            buf = _pin_rows(buf.at[train_row_ids].set(new_sub), shd)
         losses = jnp.zeros((n,), jnp.float32)
         if with_losses:
             losses = losses.at[train_row_ids].set(sub_loss * train_mask)
         return buf, _pin_repl(losses, shd)
-    if col_ids is not None:
-        buf = mix_flat_cols(buf, w_rows, mix_row_ids, col_ids,
-                            kernels=kernels, shd=shd)
-    else:
-        buf = mix_flat(buf, w_rows, mix_row_ids, kernels=kernels, shd=shd)
+    with jax.named_scope("mix"):
+        if col_ids is not None:
+            buf = mix_flat_cols(buf, w_rows, mix_row_ids, col_ids,
+                                kernels=kernels, shd=shd)
+        else:
+            buf = mix_flat(buf, w_rows, mix_row_ids, kernels=kernels,
+                           shd=shd)
     losses = jnp.zeros((n,), jnp.float32)
     if k_train == 0:
         return buf, losses
-    new_sub, sub_loss = train_rows(FS.take_rows(buf, train_row_ids, shd))
-    buf = _pin_rows(buf.at[train_row_ids].set(new_sub), shd)
+    with jax.named_scope("sgd"):
+        new_sub, sub_loss = train_rows(FS.take_rows(buf, train_row_ids, shd))
+    with jax.named_scope("write_back"):
+        buf = _pin_rows(buf.at[train_row_ids].set(new_sub), shd)
     if with_losses:
         losses = losses.at[train_row_ids].set(sub_loss * train_mask)
     return buf, _pin_repl(losses, shd)
@@ -553,11 +562,12 @@ def round_step(buf: jnp.ndarray, w_rows: jnp.ndarray, ctrl: jnp.ndarray,
     k_train = train_row_ids.shape[0]
     xb = yb = None
     if k_train:
-        key = jax.random.fold_in(key, t)           # per-round stream, in-jit
-        xb, yb = sample_batches_device(key, train_row_ids, data_x, data_y,
-                                       part_idx[train_row_ids],
-                                       part_sizes[train_row_ids],
-                                       local_steps, batch_size)
+        with jax.named_scope("sample"):
+            key = jax.random.fold_in(key, t)       # per-round stream, in-jit
+            xb, yb = sample_batches_device(key, train_row_ids, data_x,
+                                           data_y, part_idx[train_row_ids],
+                                           part_sizes[train_row_ids],
+                                           local_steps, batch_size)
     return _mix_train_body(buf, w_rows, mix_row_ids, col_ids, train_row_ids,
                            train_mask, xb, yb, spec, lr, kernels,
                            fused_sgd, with_losses, mix_is_train, shd)
@@ -771,28 +781,32 @@ def mega_round_step(buf: jnp.ndarray, w_rows: jnp.ndarray, ctrl: jnp.ndarray,
     mix_ids, col_ids, train_ids, masks = split_ctrl(ctrl, k_mix, u)
     k_train = train_ids.shape[1]                   # (H, k) segments per round
     if k_train:
-        keys = jax.vmap(jax.random.fold_in, (None, 0))(key, ts)
-        xb, yb = jax.vmap(
-            lambda k, ids: sample_batches_device(
-                k, ids, data_x, data_y, part_idx[ids], part_sizes[ids],
-                local_steps, batch_size))(keys, train_ids)
+        with jax.named_scope("sample"):
+            keys = jax.vmap(jax.random.fold_in, (None, 0))(key, ts)
+            xb, yb = jax.vmap(
+                lambda k, ids: sample_batches_device(
+                    k, ids, data_x, data_y, part_idx[ids], part_sizes[ids],
+                    local_steps, batch_size))(keys, train_ids)
     else:
         xb = yb = jnp.zeros((ts.shape[0],), jnp.float32)        # scan filler
 
+    # the scan step's ops carry one stable scope name, ``mega_round``
     if col_ids is not None:
         def body(b, xs):
             w, mids, cids, tids, mask, x, y = xs
-            return _mix_train_body(b, w, mids, cids, tids, mask, x, y, spec,
-                                   lr, kernels, fused_sgd, with_losses,
-                                   mix_is_train, shd)
+            with jax.named_scope("mega_round"):
+                return _mix_train_body(b, w, mids, cids, tids, mask, x, y,
+                                       spec, lr, kernels, fused_sgd,
+                                       with_losses, mix_is_train, shd)
 
         return jax.lax.scan(body, buf, (w_rows, mix_ids, col_ids, train_ids,
                                         masks, xb, yb))
 
     def body(b, xs):
         w, mids, tids, mask, x, y = xs
-        return _mix_train_body(b, w, mids, None, tids, mask, x, y, spec, lr,
-                               kernels, fused_sgd, with_losses,
-                               mix_is_train, shd)
+        with jax.named_scope("mega_round"):
+            return _mix_train_body(b, w, mids, None, tids, mask, x, y, spec,
+                                   lr, kernels, fused_sgd, with_losses,
+                                   mix_is_train, shd)
 
     return jax.lax.scan(body, buf, (w_rows, mix_ids, train_ids, masks, xb, yb))

@@ -97,3 +97,31 @@ def test_save_is_atomic_no_tmp_left_behind(tmp_path):
     assert [p.name for p in tmp_path.iterdir()] == ["x.npz"]
     loaded, _, _ = load_checkpoint(path, {"a": np.ones(3)})
     np.testing.assert_array_equal(loaded["a"], np.zeros(3))
+
+
+def test_trace_counts_survive_snapshot_and_resume(tmp_path):
+    """``History.counts`` rides in the snapshot's JSON ``extra``: a run
+    resumed from a mid-run snapshot ends with the counters of the
+    uninterrupted run (compilations aside, which belong to each process's
+    caches), while its host times are its own call's."""
+    from repro.checkpoint import io as CIO
+    from repro.core.protocol import DySTop
+    from repro.dfl.simulator import SimConfig, run_simulation
+
+    cfg = SimConfig(n_workers=16, n_rounds=20, hidden=16, n_samples=1200,
+                    dim=8, eval_every=5, checkpoint_every=5,
+                    checkpoint_dir=str(tmp_path))
+    mech = DySTop(V=10.0, t_thre=8, max_neighbors=4)
+    full = run_simulation(mech, cfg)
+    mid = CIO.list_checkpoints(tmp_path)[1]
+    _, _, extra = load_checkpoint(mid, {"buf": np.zeros((16, 1))})
+    assert len(extra["history"]["round_active"]) == extra["round"]
+    assert extra["history"]["counts"]["dispatches"] > 0
+    resumed = run_simulation(mech, cfg, resume_from=str(mid))
+
+    def trajectory(h):
+        return {k: v for k, v in h.counts.items() if "/" not in k}
+
+    assert trajectory(resumed) == trajectory(full)
+    assert len(resumed.round_active) == 20
+    assert resumed.plan_wall_s < full.plan_wall_s

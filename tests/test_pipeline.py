@@ -28,9 +28,11 @@ from repro.core.aggregation import (col_union_mask, mixing_matrix,
                                     mixing_matrix_rows)
 from repro.core.planner import PlannedRound, bucket_key, chunk_spans
 from repro.core.protocol import DySTop
+from repro.core.trace import Trace
 from repro.dfl import lm_worker as LW
 from repro.dfl import worker as WK
 from repro.dfl.pipeline import DispatchPipeline
+from repro.dfl.simulator import History as SimHistory
 from repro.dfl.simulator import SimConfig, run_simulation
 from repro.models import registry as R
 
@@ -58,7 +60,7 @@ def test_pipeline_depth0_blocks_inline(monkeypatch):
     waited = []
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda tok: waited.append(tok))
-    pipe = DispatchPipeline(0)
+    pipe = DispatchPipeline(0, Trace(SimHistory()))
     a, b = _Token(), _Token()
     pipe.submit(a)
     assert waited == [a]          # lockstep: every submit waits immediately
@@ -72,7 +74,8 @@ def test_pipeline_bounds_in_flight_and_drains_fifo(monkeypatch):
     waited = []
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda tok: waited.append(tok))
-    pipe = DispatchPipeline(2)
+    hist = SimHistory()
+    pipe = DispatchPipeline(2, Trace(hist))
     toks = [_Token() for _ in range(4)]
     pipe.submit(toks[0])
     pipe.submit(toks[1])
@@ -85,7 +88,8 @@ def test_pipeline_bounds_in_flight_and_drains_fifo(monkeypatch):
     assert waited == toks         # FIFO, all retired
     pipe.drain()
     assert waited == toks         # idempotent
-    assert pipe.drain_wall_s >= 0.0
+    assert hist.drain_wall_s >= 0.0
+    assert hist.counts["backpressure_waits"] == 2
 
 
 # --------------------------------------------------------------------------- #
