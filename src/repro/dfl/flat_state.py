@@ -83,6 +83,18 @@ def unravel_row(vec: jnp.ndarray, spec: FlatSpec) -> Any:
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
+def unravel_row_at(buf: jnp.ndarray, r, spec: FlatSpec) -> Any:
+    """Row ``r`` (traced) of an (N, P) buffer -> its single-model pytree,
+    each leaf sliced from the buffer itself: no (P,) row is gathered first.
+    """
+    leaves = [
+        jax.lax.dynamic_slice(buf, (r, o), (1, s)).reshape(shape).astype(dtype)
+        for o, s, shape, dtype in zip(spec.offsets, spec.sizes, spec.shapes,
+                                      spec.dtypes)
+    ]
+    return jax.tree.unflatten(spec.treedef, leaves)
+
+
 def take_rows(buf: jnp.ndarray, ids: jnp.ndarray, shd=None) -> jnp.ndarray:
     """``buf[ids]`` for a wide (N, P) buffer, as a loop of row slices.
 

@@ -326,10 +326,11 @@ class LMEngine:
     non-identity rows (row- or column-sparse exactly like the simulation
     plane, via ``worker.mix_flat`` / ``mix_flat_cols``), then the activated
     rows of BOTH buffers run one AD train step each through the generic
-    ``Optimizer.update`` and are written back — inactive rows are never
-    touched, so model-plane work is O(k), not O(N).  Under the ``mix_is_train``
-    fusion (mix rows == train rows, every DySTop round) the mixed sub-buffer
-    feeds the train step directly, skipping the intermediate scatter.
+    ``Optimizer.update`` and are written back — inactive rows, bucket
+    padding included, are never touched, so model-plane work is O(k), not
+    O(N).  Under the ``mix_is_train`` fusion (mix rows == train rows, every
+    DySTop round) the mixed sub-buffer feeds the train step directly,
+    skipping the intermediate scatter.
 
     Jits are cached per (col_sparse, fuse, pregather) variant; shapes bucket
     through ``pack_horizon``, so the compile count stays O(log N) per
@@ -345,8 +346,8 @@ class LMEngine:
     rows on HOST before the H2D transfer — batches ship (H, k, B, S) instead
     of (H, N, B, S), an ~N/k transfer cut that matters precisely in the
     large-N sharded regime (the train ids still ride in ``ctrl`` for the
-    scatter; gather by padded ids is value-exact, padding rows are masked
-    no-ops).
+    scatter; gather by padded ids is value-exact, padding rows skip their
+    train step).
     """
 
     def __init__(self, cfg: ModelConfig, optimizer: Optimizer,
@@ -356,9 +357,10 @@ class LMEngine:
         self.shd = shd
         self._mega_cache: dict = {}
 
-    def _train_one(self, pvec, ovec, m, t, l):
-        """One worker's AD train step on its flat rows; a padding row
-        (``m == 0``) comes back bit-identical."""
+    def _train_one(self, pvec, state, t, l):
+        """One worker's AD train step on its flat params row and its
+        optimizer state; returns the new params and optimizer state raveled
+        into (P,) / (S,) rows, and the loss."""
         cfg, opt, spec = self.cfg, self.opt, self.spec
         with jax.named_scope("fwd_bwd"):
             params = FS.unravel_row(pvec, spec.params)
@@ -367,13 +369,10 @@ class LMEngine:
             (loss, _), grads = jax.value_and_grad(
                 lambda p: R.compute_loss(cfg, p, batch), has_aux=True)(params)
         with jax.named_scope(opt.name):
-            state = FS.unravel_row(ovec, spec.opt)
             new_p, new_s = opt.update(grads, state, params)
         with jax.named_scope("write_back"):
-            keep = m > 0
-            return (jnp.where(keep, FS.ravel_row(new_p, spec.params), pvec),
-                    jnp.where(keep, FS.ravel_row(new_s, spec.opt), ovec),
-                    loss * m)
+            return (FS.ravel_row(new_p, spec.params),
+                    FS.ravel_row(new_s, spec.opt), loss)
 
     def _train_rows(self, pbuf, obuf, sub, tids, mask, tok, lab):
         """Train the k gathered rows one after another, each written back in
@@ -386,12 +385,17 @@ class LMEngine:
         widths does not fit one 16 GB chip; in sequence one worker's are live
         at a time.  Numerics: every row runs the same program whatever the
         bucket size k, so ``min_bucket`` and the per-call-flatten oracle
-        (which maps over workers the same way) round alike.  Padding ids
-        repeat an idle row whose write-back is its own value, so the sequence
-        equals a batched scatter.
+        (which maps over workers the same way) round alike.
+
+        A padding row (``mask[i] == 0``: an idle row, ``padded_rows``) skips
+        its whole step by a ``lax.cond``: it is neither read, trained nor
+        written, so its rows and its zero loss slot come back as they went
+        in.  The untaken branch passes the carry through, so the buffers
+        stay in place.
 
         With ``shd`` each shard runs the loop over all k ids and trains the
-        rows it holds, so ``pbuf``/``obuf`` rows never leave their shard; the
+        real rows it holds (the same predicate, narrowed to the shard's
+        block), so ``pbuf``/``obuf`` rows never leave their shard; the
         per-row losses (zero elsewhere) meet in one psum.
         """
         n = pbuf.shape[0]
@@ -406,18 +410,20 @@ class LMEngine:
                     with jax.named_scope("gather"):
                         pvec = (sub[i] if sub is not None else
                                 jax.lax.dynamic_index_in_dim(pb, r, 0, False))
-                        ovec = jax.lax.dynamic_index_in_dim(ob, r, 0, False)
-                    new_p, new_o, loss = self._train_one(pvec, ovec, mask[i],
-                                                         tok[i], lab[i])
+                        # leaf by leaf: a gathered (S,) row is copied once
+                        # more on the TPU, a GB of temporaries at 135M
+                        state = FS.unravel_row_at(ob, r, self.spec.opt)
+                    new_p, new_o, loss = self._train_one(pvec, state, tok[i],
+                                                         lab[i])
                     with jax.named_scope("write_back"):
                         put = jax.lax.dynamic_update_index_in_dim
                         return (put(pb, new_p, r, 0), put(ob, new_o, r, 0),
                                 ls.at[tids[i]].set(loss))
 
-                if blk is None:
-                    return train(carry)
-                return jax.lax.cond((r >= 0) & (r < blk), train,
-                                    lambda c: c, carry)
+                real = mask[i] > 0
+                if blk is not None:
+                    real = real & (r >= 0) & (r < blk)
+                return jax.lax.cond(real, train, lambda c: c, carry)
 
             return jax.lax.fori_loop(0, tids.shape[0], body, (pb, ob, losses))
 

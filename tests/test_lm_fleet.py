@@ -15,13 +15,16 @@ import numpy as np
 import pytest
 
 from repro.core.aggregation import apply_mixing, mixing_matrix
+from repro.core.planner import PlannedRound
 from repro.core.protocol import DySTop, RoundContext
 from repro.core.staleness import StalenessState
+from repro.core.trace import Trace
 from repro.data.synthetic import make_token_stream
 from repro.dfl import flat_state as FS
 from repro.dfl import lm_worker as LW
 from repro.dfl.network import (EdgeNetwork, NetworkConfig,
                                heterogeneous_compute_times)
+from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 
 
@@ -184,6 +187,67 @@ def test_lm_scan_horizon_invariance():
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(f1.obuf), np.asarray(f8.obuf),
                                rtol=1e-5, atol=1e-6)
+
+
+def _one_active_chunk(n):
+    """Three hand-built DySTop-style rounds on ``n`` workers, one activated
+    worker each (2, 3, 2) pulling half its model from one neighbour.  Rows
+    0 and 1 are never activated; with ``min_bucket`` 2 the one padding row
+    of every round is row 0 (the first idle row)."""
+    rounds = []
+    for t, (a, nb) in enumerate([(2, 3), (3, 1), (2, 1)], start=1):
+        active = np.zeros(n, bool)
+        active[a] = True
+        links = np.zeros((n, n), bool)
+        links[a, nb] = True
+        W = np.eye(n, dtype=np.float32)
+        W[a, a] = W[a, nb] = 0.5
+        rounds.append(PlannedRound(t=t, active=active, links=links,
+                                   synchronous=False, W=W, duration=1.0,
+                                   n_transfers=1))
+    return rounds
+
+
+@pytest.mark.parametrize("fuse", [True, False])
+def test_padding_rows_skip_train_step(fuse):
+    """Bucket padding skips the train step: a chunk dispatched at
+    ``min_bucket`` 2 (one padding row a round) leaves the padding row's
+    params, optimizer state and loss slots exactly as they went in, and its
+    activated rows equal the same chunk dispatched without padding."""
+    cfg = R.get_smoke_config("smollm-135m")
+    n, b, s = 4, 2, 16
+    fleet = LW.init_fleet(cfg, n, lr=1e-3)
+    rng = np.random.default_rng(0)
+    # distinct rows, so the Eq. 4 mix moves the activated ones
+    p0 = np.asarray(fleet.pbuf) + rng.normal(
+        0.0, 1e-2, (n, fleet.pbuf.shape[1])).astype(np.float32)
+    o0 = np.asarray(fleet.obuf).copy()
+    chunk = _one_active_chunk(n)
+    tokens = rng.integers(0, cfg.vocab_size, (len(chunk), n, b, s),
+                          dtype=np.int32)
+    labels = rng.integers(0, cfg.vocab_size, (len(chunk), n, b, s),
+                          dtype=np.int32)
+    engine = LW.get_lm_engine(cfg, fleet.optimizer, fleet.spec,
+                              KernelConfig(), None)
+
+    def dispatch(min_bucket):
+        out = engine.dispatch_chunk(
+            jnp.asarray(p0), jnp.asarray(o0), chunk, tokens, labels,
+            col_sparse=False, fuse=fuse, min_bucket=min_bucket,
+            trace=Trace(LW.LMHistory()))
+        return [np.asarray(x) for x in out]
+
+    pb, ob, losses = dispatch(2)
+    np.testing.assert_array_equal(pb[0], p0[0])       # the padding row
+    np.testing.assert_array_equal(ob[0], o0[0])
+    np.testing.assert_array_equal(losses[:, 0], 0.0)
+    assert (losses[:, 2:] != 0).sum() == len(chunk)   # one loss a round
+    assert not np.array_equal(pb[2], p0[2])           # the real rows trained
+
+    pb1, ob1, losses1 = dispatch(1)                   # no padding at all
+    np.testing.assert_array_equal(pb, pb1)
+    np.testing.assert_array_equal(ob, ob1)
+    np.testing.assert_array_equal(losses, losses1)
 
 
 def test_planner_driven_control_matches_hand_rolled_loop():

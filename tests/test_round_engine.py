@@ -54,6 +54,10 @@ def test_unravel_row_matches_leaf_slices():
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b[3]),
                  row3, tree)
     np.testing.assert_array_equal(FS.ravel_row(row3, spec), buf[3])
+    # the same leaves sliced from the buffer at a traced row index
+    at3 = jax.jit(lambda b, r: FS.unravel_row_at(b, r, spec))(buf, 3)
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b),
+                 at3, row3)
 
 
 @pytest.mark.parametrize("ids", [[3, 0, 11], [5, 5, 2, 5], [7]])

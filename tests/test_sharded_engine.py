@@ -340,16 +340,19 @@ def _lm_mech():
 
 
 @needs_devices(2)
-@pytest.mark.parametrize("n_workers,shards", [(64, 8), (6, 4)])
-def test_lm_sharded_matches_oracle(n_workers, shards):
-    """N=64 LM fleet at mesh_shards=8 (the acceptance geometry) plus a small
-    ragged case: control bit-exact, resident buffers to f32 tolerance."""
+@pytest.mark.parametrize("n_workers,shards,min_bucket",
+                         [(64, 8, 2), (6, 4, 2), (8, 4, 4)])
+def test_lm_sharded_matches_oracle(n_workers, shards, min_bucket):
+    """N=64 LM fleet at mesh_shards=8 (the acceptance geometry), a small
+    ragged case, and a coarse bucket whose padding rows (skipped train
+    steps) lie in several shards: control bit-exact, resident buffers to
+    f32 tolerance."""
     if N_DEV < shards:
         pytest.skip(f"{shards} shards need {shards} devices")
     cfg = R.get_smoke_config("smollm-135m")
-    kw = _lm_kw(n_workers=n_workers)
+    kw = _lm_kw(n_workers=n_workers, min_bucket=min_bucket)
     f1, h1 = _cached(
-        f"lm{n_workers}",
+        f"lm{n_workers}-b{min_bucket}",
         lambda: LW.run_lm_federation(_lm_mech(), cfg,
                                      LW.LMRunConfig(mesh_shards=1, **kw)))
     fs, hs = LW.run_lm_federation(_lm_mech(), cfg,

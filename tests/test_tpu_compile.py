@@ -1,8 +1,9 @@
-"""v5e compile rehearsals: every Pallas kernel, at the shapes the chip runs.
+"""v5e compile rehearsals: every Pallas kernel, at the shapes the chip runs,
+and the LM mega-round's skip of padding rows.
 
-Each test compiles one kernel for a described (not attached) TPU v5e with
-the TPU compiler installed next to JAX, and checks that the compiled program
-holds the Mosaic kernel (``tpu_custom_call``).  Interpret mode, which the
+Each kernel test compiles one kernel for a described (not attached) TPU v5e
+with the TPU compiler installed next to JAX, and checks that the compiled
+program holds the Mosaic kernel (``tpu_custom_call``).  Interpret mode, which the
 rest of the suite runs on the CPU, accepts tilings and slices that Mosaic
 refuses; these compiles catch that without a chip.  Nothing runs, so they
 say nothing about values or times.
@@ -11,7 +12,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports this file.  The fixture skips where the topology cannot be described.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,13 +22,16 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.dfl import flat_state as FS
+from repro.dfl import lm_worker as LW
 from repro.dfl.worker import init_mlp
 from repro.kernels import aggregate as AGG
 from repro.kernels import flash_attention as FA
 from repro.kernels import fused_sgd as FSGD
 from repro.kernels import moe_router as MR
 from repro.kernels import ssd_chunk as SC
+from repro.kernels.config import KernelConfig
 from repro.launch.mesh import FLEET_AXIS
+from repro.models import registry as R
 from repro.sharding.rules import FleetSharding
 
 SIM_P = 6922            # default sim MLP: dim 32, hidden 64, 10 classes
@@ -134,3 +140,36 @@ def test_moe_router_compiles(one_chip):
         lambda l: MR.moe_router(l, 8, interpret=False),
         _sds((512, 384), one_chip))                     # kimi-k2 router
     assert "tpu_custom_call" in txt
+
+
+def test_lm_mega_round_skips_padding_in_place(one_chip):
+    """The LM mega-round (smoke widths, 4 workers, fused mix and train,
+    a padded bucket of 2) skips a padding row by a conditional, and the
+    fleet buffers stay in place through it: no instruction copies a whole
+    (N, P) or (N, S) buffer."""
+    kern = KernelConfig(backend="pallas", interpret=False)
+    cfg = dataclasses.replace(R.get_smoke_config("smollm-135m"),
+                              kernels=kern)
+    n, h, k, b, s = 4, 4, 2, 2, 256
+    opt = LW._cached_optimizer("adam", 1e-3)
+    params = jax.eval_shape(
+        lambda: R.init_params(cfg, jax.random.PRNGKey(0))[0])
+
+    def stacked(tree):
+        return FS.spec_of(jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct((n,) + l.shape, l.dtype), tree))
+
+    spec = FS.FleetSpec(params=stacked(params),
+                        opt=stacked(jax.eval_shape(opt.init, params)))
+    p, o = spec.params.n_params, spec.opt.n_params
+    mega = LW.LMEngine(cfg, opt, spec, kernels=kern)._mega(
+        col_sparse=False, fuse=True, pregather=True)
+    txt = mega.lower(
+        _sds((n, p), one_chip), _sds((n, o), one_chip),
+        _sds((h, k, n), one_chip), _sds((h, 3 * k), one_chip, jnp.int32),
+        _sds((h, k, b, s), one_chip, jnp.int32),
+        _sds((h, k, b, s), one_chip, jnp.int32)).compile().as_text()
+    assert " conditional(" in txt
+    whole = re.compile(rf"= f32\[{n},({p}|{o})\]\S* (\S+?)\(")
+    ops = {m.group(2) for m in map(whole.search, txt.splitlines()) if m}
+    assert ops and not any(op.startswith("copy") for op in ops), ops
