@@ -69,6 +69,9 @@ class ModelConfig:
                                        # moe_router) with reference backward
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
+    scale_embeddings: bool = True      # token embeddings times sqrt(d_model)
+                                       # (gemma-style); False feeds the table
+                                       # rows in as they are (Mamba-2)
     post_norm: bool = False            # gemma2-style extra post-block norms
     dtype: str = "bfloat16"
 

@@ -1,7 +1,9 @@
 """Mamba-2 2.7B [arXiv:2405.21060] — SSD (state-space duality), attention-free.
 
 64L d_model=2560 (d_inner=5120, head_dim=64 -> 80 heads) ssm_state=128
-vocab=50280; no FFN (pure stack of SSD blocks).
+vocab 50,277 tokens in 50,288 rows (padded to a multiple of 16); no FFN
+(pure stack of SSD blocks).  As published (state-spaces/mamba2-2.7b): RMS
+norms with eps 1e-5, tied embeddings, and token embeddings fed in unscaled.
 """
 from repro.configs.base import ModelConfig, SSMConfig
 
@@ -15,9 +17,11 @@ def get_config() -> ModelConfig:
         n_heads=1,           # unused (attention-free)
         n_kv_heads=1,
         d_ff=0,
-        vocab_size=50280,
+        vocab_size=50288,
         ssm=SSMConfig(d_state=128, head_dim=64, expand=2, chunk_size=256),
+        norm_eps=1e-5,
         tie_embeddings=True,
+        scale_embeddings=False,
     )
 
 
@@ -32,4 +36,6 @@ def get_smoke_config() -> ModelConfig:
         d_ff=0,
         vocab_size=1024,
         ssm=SSMConfig(d_state=32, head_dim=32, expand=2, chunk_size=32),
+        norm_eps=1e-5,
+        scale_embeddings=False,
     )

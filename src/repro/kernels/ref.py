@@ -52,6 +52,8 @@ def ssd_chunk_ref(Bc, Cc, cum_la, xbar):
     decay = cum_la[:, :, :, None] - cum_la[:, :, None, :]      # (G,H,Q,Q)
     q = scores.shape[-1]
     causal = jnp.tril(jnp.ones((q, q), bool))
-    l_mat = jnp.where(causal[None, None], jnp.exp(decay), 0.0)
+    # mask before the exp: above the diagonal the decay is positive and can
+    # overflow f32, and inf times the masked cotangent's 0 is a NaN
+    l_mat = jnp.exp(jnp.where(causal[None, None], decay, -jnp.inf))
     return jnp.einsum("gqk,ghqk,ghkp->ghqp", scores, l_mat,
                       xbar.astype(jnp.float32))

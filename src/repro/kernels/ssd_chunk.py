@@ -46,7 +46,7 @@ def _ssd_chunk_kernel(cb_ref, cc_ref, la_col_ref, la_row_ref, x_ref, o_ref):
     q = scores.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    l_mat = jnp.where(cols <= rows, jnp.exp(decay), 0.0)
+    l_mat = jnp.exp(jnp.where(cols <= rows, decay, -jnp.inf))
     o_ref[0, 0, :, :] = jnp.dot(scores * l_mat, x,
                                 preferred_element_type=jnp.float32
                                 ).astype(o_ref.dtype)
@@ -70,7 +70,7 @@ def ssd_chunk(Bc: jnp.ndarray, Cc: jnp.ndarray, cum_la: jnp.ndarray,
     return pl.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
-        name="ssd_chunk",
+        name="dystop_ssd_chunk",
         in_specs=[
             pl.BlockSpec((1, Q, N), lambda g, h: (g, 0, 0)),
             pl.BlockSpec((1, Q, N), lambda g, h: (g, 0, 0)),

@@ -75,7 +75,8 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
     x = params["embed"]["table"][tokens].astype(jnp.dtype(cfg.dtype))
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     spec = L.AttnSpec(causal=True)
 
     def layer_fn(xc, lp):
@@ -134,7 +135,8 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
     """token (B, 1) -> (logits, new cache). Cross K/V must be pre-filled."""
     pos = cache["pos"]
     x = params["embed"]["table"][token].astype(jnp.dtype(cfg.dtype))
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
 
     def layer_fn(x_in, scanned):
         lp, sk, sv, skp, ck, cv = scanned

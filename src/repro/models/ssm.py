@@ -120,40 +120,49 @@ def ssm_forward(cfg: ModelConfig, p: Params, x_res: jnp.ndarray) -> jnp.ndarray:
     xc = xbar.reshape(Bsz, nc, Q, H, P_)
 
     # ---- intra-chunk (quadratic dual form) ----
-    if cfg.kernels.use_pallas:
-        # Pallas ssd_chunk kernel (reference backward).  Kernel layout is
-        # head-major (G, H, Q, ·) with G = batch * n_chunks.
-        G = Bsz * nc
-        y_k = K.ssd_chunk_diff(
-            Bc.reshape(G, Q, s.d_state), Cc.reshape(G, Q, s.d_state),
-            jnp.transpose(cum.reshape(G, Q, H), (0, 2, 1)),
-            jnp.transpose(xc.reshape(G, Q, H, P_), (0, 2, 1, 3)),
-            cfg.kernels)
-        y_intra = jnp.transpose(y_k, (0, 2, 1, 3)).reshape(Bsz, nc, Q, H, P_)
-    else:
-        cb = jnp.einsum("bcqn,bckn->bcqk", Cc, Bc)            # (B,nc,Q,Q)
-        decay = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
-        causal = jnp.tril(jnp.ones((Q, Q), bool))
-        L = jnp.where(causal[None, None, :, :, None], jnp.exp(decay), 0.0)
-        y_intra = jnp.einsum("bcqk,bcqkh,bckhp->bcqhp", cb, L, xc)
+    # named scopes ``ssd_intra`` / ``ssd_scan`` let a device trace charge
+    # the two halves of SSD separately (chipbench/scopes.py)
+    with jax.named_scope("ssd_intra"):
+        if cfg.kernels.use_pallas:
+            # Pallas ssd_chunk kernel (reference backward).  Kernel layout
+            # is head-major (G, H, Q, ·) with G = batch * n_chunks.
+            G = Bsz * nc
+            y_k = K.ssd_chunk_diff(
+                Bc.reshape(G, Q, s.d_state), Cc.reshape(G, Q, s.d_state),
+                jnp.transpose(cum.reshape(G, Q, H), (0, 2, 1)),
+                jnp.transpose(xc.reshape(G, Q, H, P_), (0, 2, 1, 3)),
+                cfg.kernels)
+            y_intra = jnp.transpose(y_k, (0, 2, 1, 3)).reshape(
+                Bsz, nc, Q, H, P_)
+        else:
+            cb = jnp.einsum("bcqn,bckn->bcqk", Cc, Bc)        # (B,nc,Q,Q)
+            decay = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,Q,H)
+            causal = jnp.tril(jnp.ones((Q, Q), bool))
+            # masked before the exp (kernels/ref.py::ssd_chunk_ref says why)
+            L = jnp.exp(jnp.where(causal[None, None, :, :, None], decay,
+                                  -jnp.inf))
+            y_intra = jnp.einsum("bcqk,bcqkh,bckhp->bcqhp", cb, L, xc)
 
     # ---- chunk boundary states + inter-chunk scan ----
-    decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)           # (B,nc,Q,H)
-    chunk_state = jnp.einsum("bckn,bckh,bckhp->bchpn", Bc, decay_to_end, xc)
-    chunk_decay = jnp.exp(cum[:, :, -1, :])                   # (B,nc,H)
+    with jax.named_scope("ssd_scan"):
+        decay_to_end = jnp.exp(cum[:, :, -1:, :] - cum)       # (B,nc,Q,H)
+        chunk_state = jnp.einsum("bckn,bckh,bckhp->bchpn", Bc, decay_to_end,
+                                 xc)
+        chunk_decay = jnp.exp(cum[:, :, -1, :])               # (B,nc,H)
 
-    def scan_fn(carry, inp):
-        cs, cd = inp                                          # (B,H,P,N), (B,H)
-        new = carry * cd[:, :, None, None] + cs
-        return new, carry                                     # emit state BEFORE this chunk
+        def scan_fn(carry, inp):
+            cs, cd = inp                                      # (B,H,P,N), (B,H)
+            new = carry * cd[:, :, None, None] + cs
+            return new, carry                                 # emit state BEFORE this chunk
 
-    init = jnp.zeros((Bsz, H, P_, s.d_state), jnp.float32)
-    _, prev_states = jax.lax.scan(
-        scan_fn, init,
-        (jnp.moveaxis(chunk_state, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
-    prev_states = jnp.moveaxis(prev_states, 0, 1)             # (B,nc,H,P,N)
+        init = jnp.zeros((Bsz, H, P_, s.d_state), jnp.float32)
+        _, prev_states = jax.lax.scan(
+            scan_fn, init,
+            (jnp.moveaxis(chunk_state, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+        prev_states = jnp.moveaxis(prev_states, 0, 1)         # (B,nc,H,P,N)
 
-    y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp", Cc, jnp.exp(cum), prev_states)
+        y_inter = jnp.einsum("bcqn,bcqh,bchpn->bcqhp", Cc, jnp.exp(cum),
+                             prev_states)
     y = (y_intra + y_inter).reshape(Bsz, S, H, P_) + p["D"][None, None, :, None] * xh
     y = y.reshape(Bsz, S, d_in)
     y = _gated_norm(y, z, p["norm"], cfg.norm_eps)

@@ -292,7 +292,8 @@ def forward(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
     n_pre, n_grp, n_coda = structure(cfg)
     per = pattern(cfg)
     x = params["embed"]["table"][tokens].astype(jnp.dtype(cfg.dtype))
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     prefix_len = 0
     if prefix_embeds is not None:
         prefix_len = prefix_embeds.shape[1]
@@ -362,7 +363,8 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
     per = pattern(cfg)
     pos = cache["pos"]
     x = params["embed"]["table"][token].astype(jnp.dtype(cfg.dtype))
-    x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     new_cache: Params = {"pos": pos + 1, "prelude": [], "coda": []}
 
     for i, lp in enumerate(params["prelude"]):

@@ -173,3 +173,50 @@ def test_lm_mega_round_skips_padding_in_place(one_chip):
     whole = re.compile(rf"= f32\[{n},({p}|{o})\]\S* (\S+?)\(")
     ops = {m.group(2) for m in map(whole.search, txt.splitlines()) if m}
     assert ops and not any(op.startswith("copy") for op in ops), ops
+
+
+def test_mamba2_mega_round_fits_one_chip(one_chip, tmp_path):
+    """The LM mega-round of the ``lm-mamba2l4-n2`` cell, at Mamba-2 2.7B's
+    published widths (4 layers, an eighth of the vocabulary): 2 workers
+    with Adam, a fused mix-and-train bucket of 2, batch 1 x seq 4096 (16
+    SSD chunks), 4 rounds a dispatch.  It compiles for a v5e with the SSD
+    forward in the ``dystop_ssd_chunk`` kernel, and the compiler's memory
+    report (its own account of the program's HBM, the one it refuses a
+    program by) fits the v5e's 15.75 GiB: 14.74 GiB, of it 3.96 GiB the
+    resident fleet.  ``memory_analysis()`` reads more (4.25e9 B of
+    arguments and 12.95e9 B of temporaries), and the chip's measured peak
+    less (PERF.md)."""
+    from repro.configs.base import ModelConfig, SSMConfig
+    kern = KernelConfig(backend="pallas", interpret=False)
+    cfg = ModelConfig(
+        arch_id="mamba2-2.7b-l4", family="ssm", n_layers=4, d_model=2560,
+        n_heads=1, n_kv_heads=1, d_ff=0, vocab_size=6286,
+        ssm=SSMConfig(d_state=128, head_dim=64, expand=2, chunk_size=256,
+                      conv_width=4),
+        norm_eps=1e-5, tie_embeddings=True, scale_embeddings=False,
+        kernels=kern)
+    n, h, k, b, s = 2, 4, 2, 1, 4096
+    opt = LW._cached_optimizer("adam", 1e-3)
+    params = jax.eval_shape(
+        lambda: R.init_params(cfg, jax.random.PRNGKey(0))[0])
+
+    def stacked(tree):
+        return FS.spec_of(jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct((n,) + l.shape, l.dtype), tree))
+
+    spec = FS.FleetSpec(params=stacked(params),
+                        opt=stacked(jax.eval_shape(opt.init, params)))
+    p, o = spec.params.n_params, spec.opt.n_params
+    mega = LW.LMEngine(cfg, opt, spec, kernels=kern)._mega(
+        col_sparse=False, fuse=True, pregather=True)
+    compiled = mega.lower(
+        _sds((n, p), one_chip), _sds((n, o), one_chip),
+        _sds((h, k, n), one_chip), _sds((h, 3 * k), one_chip, jnp.int32),
+        _sds((h, k, b, s), one_chip, jnp.int32),
+        _sds((h, k, b, s), one_chip, jnp.int32)).compile(
+            compiler_options={"xla_dump_to": str(tmp_path)})
+    assert "dystop_ssd_chunk" in compiled.as_text()
+    report, = tmp_path.glob("*jit_mega*memory-usage-report.txt")
+    used = int(re.search(r"Total bytes used: (\d+)",
+                         report.read_text()).group(1))
+    assert 4 * (p + o) * n <= used <= 15.75 * 2**30, used
