@@ -327,10 +327,10 @@ def apply_mixing(W: jnp.ndarray, stacked_models: Any, kernels: Any = None,
             "kernels=KernelConfig(backend='pallas') instead",
             DeprecationWarning, stacklevel=2)
         pallas = bool(use_kernel)
-        p_blk = 512
+        p_blk = None
     else:
         pallas = kernels is not None and kernels.use_pallas
-        p_blk = kernels.agg_p_blk if kernels is not None else 512
+        p_blk = kernels.agg_p_blk if kernels is not None else None
     if pallas:
         from repro.kernels import ops as K
 

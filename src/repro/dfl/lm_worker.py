@@ -581,7 +581,8 @@ class LMEngine:
             out = self._mega(col_sparse, fuse, pregather and bool(k_train))(
                 pbuf, obuf, w_j, c_j, tk_j, lb_j)
         count_dispatch(trace, len(chunk), k_mix, k_train,
-                       w.nbytes + c.nbytes + tokens.nbytes + labels.nbytes)
+                       w.nbytes + c.nbytes + tokens.nbytes + labels.nbytes,
+                       WK.narrow_mix(self.kernels, w.shape, bool(u), shards))
         return out
 
     @functools.cached_property
@@ -957,7 +958,8 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
                                            kernels=run.kernels)
                     sp, so, losses = step(sp, so, batch,
                                           jnp.asarray(p.active))
-                count_dispatch(tr, 1, n, n)
+                count_dispatch(tr, 1, n, n, narrow_mix=WK.narrow_mix(
+                    run.kernels, p.W.shape, False))
                 loss_rows.append((losses, p.active))
         pending.clear()
 

@@ -43,14 +43,16 @@ import jax
 
 
 def count_dispatch(trace, rounds: int, k_mix: int, k_train: int,
-                   h2d_bytes: int = 0) -> None:
+                   h2d_bytes: int = 0, narrow_mix: bool = False) -> None:
     """The counters of one dispatched chunk of ``rounds`` rounds, into the
     call's ``core.trace.Trace``: its mix and train rows as dispatched
-    (``k_mix`` / ``k_train`` a round, bucket padding included) and the bytes
-    staged on the device for it."""
+    (``k_mix`` / ``k_train`` a round, bucket padding included), its rounds
+    whose Eq. 4 mix ran the panel kernel's VPU body (``narrow_mix``, from
+    ``worker.narrow_mix``) and the bytes staged on the device for it."""
     trace.count("dispatches")
     trace.count("scan_dispatches", rounds > 1)
     trace.count("mix_rows", rounds * k_mix)
+    trace.count("mix_narrow_rounds", rounds if narrow_mix else 0)
     trace.count("train_rows", rounds * k_train)
     trace.count("h2d_bytes", h2d_bytes)
 

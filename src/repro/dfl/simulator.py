@@ -552,7 +552,9 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                             shd=shd)
                     count_dispatch(tr, len(chunk), key[0], key[1],
                                    w_rows_h.nbytes + ctrl_h.nbytes
-                                   + ts.nbytes)
+                                   + ts.nbytes,
+                                   WK.narrow_mix(cfg.kernels, w_rows_h.shape,
+                                                 col, cfg.mesh_shards))
                     continue
                 # single-round path: one donated round_step dispatch; with
                 # col_sparse_mix/fused_local_sgd off this is bit-for-bit the
@@ -589,7 +591,9 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                         with_losses=False,
                         mix_is_train=fused_sgd and mix_is_train(p), shd=shd)
                 count_dispatch(tr, 1, key[0], key[1],
-                               w_rows.nbytes + ctrl.nbytes)
+                               w_rows.nbytes + ctrl.nbytes,
+                               WK.narrow_mix(cfg.kernels, w_rows.shape, col,
+                                             cfg.mesh_shards))
         else:
             for p in plans:
                 with tr.span("pack"):
@@ -601,7 +605,9 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                                                 jnp.asarray(p.active),
                                                 lr=cfg.lr,
                                                 local_steps=cfg.local_steps)
-                count_dispatch(tr, 1, cfg.n_workers, cfg.n_workers)
+                count_dispatch(tr, 1, cfg.n_workers, cfg.n_workers,
+                               narrow_mix=WK.narrow_mix(
+                                   cfg.kernels, p.W.shape, False))
 
     def flush_pipelined(plans):
         """The depth >= 1 twin of ``flush``: identical dispatches (same
@@ -677,7 +683,9 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                         col_sparse=col, fused_sgd=fused_sgd,
                         with_losses=False, mix_is_train=mit, shd=shd)
             count_dispatch(tr, len(chunk), key[0], key[1],
-                           sum(a.nbytes for a in host))
+                           sum(a.nbytes for a in host),
+                           WK.narrow_mix(cfg.kernels, host[0].shape, col,
+                                         cfg.mesh_shards))
             # track the NON-donated output: the buffer itself is donated
             # into the next chunk's dispatch, so it cannot be the in-flight
             # token; the loss output of the SAME executable materializes

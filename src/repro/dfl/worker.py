@@ -239,6 +239,19 @@ def _mix_rows(buf: jnp.ndarray, w_rows: jnp.ndarray, col_ids,
     return w_rows.astype(jnp.float32) @ buf
 
 
+def narrow_mix(kernels, w_shape, col_sparse: bool, shards: int = 1) -> bool:
+    """Whether ``_mix_rows`` over mixing rows of this (..., k, width) shape
+    runs the Pallas panel's VPU body (``kernels.aggregate.vpu_body``): the
+    Pallas backend, k > 0 rows, and a contraction narrower than a sublane
+    tile, per shard for a row-sparse mix of a sharded buffer (each shard
+    contracts its N/S block).  Behind the trace's ``mix_narrow_rounds``."""
+    k, width = w_shape[-2:]
+    if not (kernels is not None and kernels.use_pallas and k):
+        return False
+    from repro.kernels.aggregate import vpu_body
+    return vpu_body(width if col_sparse else width // shards)
+
+
 def mix_flat(buf: jnp.ndarray, w_rows: jnp.ndarray, row_ids: jnp.ndarray,
              kernels=None, shd=None) -> jnp.ndarray:
     """Sparse Eq. 4 over the flat buffer: mix the k non-identity rows only.

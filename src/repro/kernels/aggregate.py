@@ -6,9 +6,18 @@ worker models as (N_workers, P) flat parameters — P is tens of millions while
 N is ~100, so this is a skinny matmul that XLA handles poorly when fused into
 the surrounding pytree traffic.
 
-TPU-native tiling: W is tiny and lives in VMEM whole; X/Y stream through VMEM
-in (N, p_blk) column panels with p_blk a multiple of 128 lanes so the MXU sees
-aligned (N x N) @ (N x p_blk) tiles.
+TPU-native tiling: W is tiny and stays resident whole (VMEM for the MXU
+body, SMEM for the VPU body); X/Y stream through VMEM in (·, p_blk) column
+panels.  The schedule takes its width and its body from the operand shapes
+(``_panel_matmul``): the panel is as wide as a fixed VMEM budget for the
+double-buffered X and Y blocks allows (``panel_width``), so a skinny
+contraction over P ~ 1e8 parameters is a few thousand grid steps, not
+hundreds of thousands of fixed per-step costs; and a contraction narrower
+than one f32 sublane tile (u < 8, the LM fleets' buckets) runs on the VPU
+as k·u scalar-times-row multiply-adds, W's scalars in SMEM (``vpu_body``),
+instead of an MXU dot that would use at most u/128 of the systolic array's
+depth.  The VPU body is f32 throughout; the MXU body's f32 ``jnp.dot`` runs
+at the default precision.
 
 Sparse variant: rows of W are identity for workers that neither activated nor
 received a push this round (MATCHA's sparse-mixing insight), so the dense
@@ -29,25 +38,69 @@ column ids contribute exactly 0.
 from __future__ import annotations
 
 import functools
-from typing import Union
+import math
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
 from repro.dfl.flat_state import take_rows
 from repro.kernels.config import resolve_interpret
 from repro.sharding.rules import shard_map
 
+LANES = 128                   # f32 vreg lanes: the panel width's unit
+SUBLANES = 8                  # f32 vreg sublanes: a block's rows pad to this
+PANEL_VMEM_BYTES = 8 << 20    # double-buffered X + Y blocks; v5e scopes 16 MiB
+VPU_SLICE = 2048              # lanes per step of the VPU body's loop
 
-def _aggregate_kernel(w_ref, x_ref, o_ref):
+
+def vpu_body(u: int) -> bool:
+    """Whether a contraction of width ``u`` runs the VPU body: narrower than
+    one f32 sublane tile, where the MXU dot would idle nearly all of its
+    depth."""
+    return u < SUBLANES
+
+
+def panel_width(k: int, u: int, p: int) -> int:
+    """The derived panel width of a (k, u) @ (u, p) contraction: the widest
+    multiple of 128 lanes whose double-buffered (u, width) X and (k, width)
+    Y blocks, rows rounded up to 8 sublanes, fit ``PANEL_VMEM_BYTES``, and
+    no wider than p rounded up to 128."""
+    rows = -(-u // SUBLANES) * SUBLANES + -(-k // SUBLANES) * SUBLANES
+    fit = PANEL_VMEM_BYTES // (rows * 4 * 2) // LANES * LANES
+    return max(LANES, min(fit, -(-p // LANES) * LANES))
+
+
+def _mxu_kernel(w_ref, x_ref, o_ref):
     o_ref[...] = jnp.dot(w_ref[...], x_ref[...],
                          preferred_element_type=jnp.float32)
 
 
+def _vpu_kernel(w_ref, x_ref, o_ref):
+    """Y[i] = sum_j W[i, j] X[j] as scalar-times-row multiply-adds in f32,
+    W's scalars read from SMEM, one lane slice at a time so the body stays
+    small however wide the panel."""
+    k, u = w_ref.shape
+    width = o_ref.shape[1]
+    sl = math.gcd(width, VPU_SLICE)
+
+    def step(s, carry):
+        cols = pl.ds(pl.multiple_of(s * sl, sl), sl)
+        for i in range(k):
+            acc = w_ref[i, 0] * x_ref[0:1, cols]
+            for j in range(1, u):
+                acc = acc + w_ref[i, j] * x_ref[j:j + 1, cols]
+            o_ref[i:i + 1, cols] = acc
+        return carry
+
+    jax.lax.fori_loop(0, width // sl, step, 0)
+
+
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
-def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
+def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: Optional[int] = None,
               interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Y = W @ X.  W: (N, N) f32; X: (N, P) f32 -> (N, P) f32."""
     n, p = X.shape
@@ -56,7 +109,8 @@ def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
 
 
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
-def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
+def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray,
+                   p_blk: Optional[int] = None,
                    interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Active-row sparse path: Y_rows = W_rows @ X.
 
@@ -72,7 +126,7 @@ def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512,
 
 @functools.partial(jax.jit, static_argnames=("p_blk", "interpret"))
 def aggregate_rows_cols(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
-                        X: jnp.ndarray, p_blk: int = 512,
+                        X: jnp.ndarray, p_blk: Optional[int] = None,
                         interpret: Union[str, bool] = "auto") -> jnp.ndarray:
     """Column-sparse Eq. 4: Y_rows = W_sub @ X[col_ids].
 
@@ -137,7 +191,7 @@ def aggregate_rows_cols_sharded(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
 
 
 def aggregate_rows_sharded_kernel(W_rows: jnp.ndarray, X: jnp.ndarray,
-                                  shd, p_blk: int = 512,
+                                  shd, p_blk: Optional[int] = None,
                                   interpret: Union[str, bool] = "auto"
                                   ) -> jnp.ndarray:
     """shard_map Pallas twin of ``aggregate_rows_sharded``.
@@ -167,7 +221,7 @@ def aggregate_rows_sharded_kernel(W_rows: jnp.ndarray, X: jnp.ndarray,
 
 def aggregate_rows_cols_sharded_kernel(W_sub: jnp.ndarray,
                                        col_ids: jnp.ndarray, X: jnp.ndarray,
-                                       shd, p_blk: int = 512,
+                                       shd, p_blk: Optional[int] = None,
                                        interpret: Union[str, bool] = "auto"
                                        ) -> jnp.ndarray:
     """shard_map Pallas twin of ``aggregate_rows_cols_sharded``.
@@ -194,21 +248,29 @@ def aggregate_rows_cols_sharded_kernel(W_sub: jnp.ndarray,
     return jax.lax.with_sharding_constraint(y, shd.for_rows(k))
 
 
-def _panel_matmul(W: jnp.ndarray, X: jnp.ndarray, p_blk: int,
+def _panel_matmul(W: jnp.ndarray, X: jnp.ndarray, p_blk: Optional[int],
                   interpret: bool) -> jnp.ndarray:
-    """(k, N) @ (N, P) with W VMEM-resident and X/Y in (·, p_blk) panels.
+    """(k, N) @ (N, P) with W resident and X/Y in (·, p_blk) VMEM panels.
 
-    The grid covers P with ``cdiv`` panels and no padding copy: output
-    columns depend only on their own X column, so the out-of-bounds lanes of
-    a ragged last panel never reach a stored column."""
+    The schedule follows the static shapes: ``p_blk=None`` takes the width
+    from ``panel_width`` (an int pins it), and ``vpu_body(N)`` picks the VPU
+    body over the MXU dot.  The grid covers P with ``cdiv`` panels and no
+    padding copy: output columns depend only on their own X column, so the
+    out-of-bounds lanes of a ragged last panel never reach a stored column."""
     k, n = W.shape
     p = X.shape[1]
+    if p_blk is None:
+        p_blk = panel_width(k, n, p)
+    if vpu_body(n):
+        kernel, w_spec = _vpu_kernel, pl.BlockSpec(memory_space=pltpu.SMEM)
+    else:
+        kernel, w_spec = _mxu_kernel, pl.BlockSpec((k, n), lambda i: (0, 0))
     return pl.pallas_call(
-        _aggregate_kernel,
+        kernel,
         grid=(pl.cdiv(p, p_blk),),
         name="dystop_aggregate_panel",
         in_specs=[
-            pl.BlockSpec((k, n), lambda i: (0, 0)),          # W resident
+            w_spec,                                          # W resident
             pl.BlockSpec((n, p_blk), lambda i: (0, i)),      # X panel
         ],
         out_specs=pl.BlockSpec((k, p_blk), lambda i: (0, i)),

@@ -15,7 +15,7 @@ cycles (nothing here touches models, engines, or the kernel modules).
 from __future__ import annotations
 
 import dataclasses
-from typing import Union
+from typing import Optional, Union
 
 BACKENDS = ("reference", "pallas")
 
@@ -48,12 +48,14 @@ class KernelConfig:
     block sizes
         Per-op tile shapes, validated against TPU tiling at construction:
         ``agg_p_blk`` — the (·, p_blk) parameter-axis panel of the aggregate
-        kernels; ``attn_blk_q``/``attn_blk_k`` — flash-attention query/key
-        tiles; ``moe_blk_t`` — router token-panel rows.
+        kernels, ``None`` (default) = derived from the operand shapes and a
+        VMEM budget (``kernels.aggregate.panel_width``), an int pins it;
+        ``attn_blk_q``/``attn_blk_k`` — flash-attention query/key tiles;
+        ``moe_blk_t`` — router token-panel rows.
     """
     backend: str = "reference"
     interpret: Union[str, bool] = "auto"
-    agg_p_blk: int = 512
+    agg_p_blk: Optional[int] = None
     attn_blk_q: int = 128
     attn_blk_k: int = 128
     moe_blk_t: int = 256
@@ -75,6 +77,8 @@ class KernelConfig:
                                  ("attn_blk_k", 128, "lane"),
                                  ("moe_blk_t", 8, "sublane")):
             v = getattr(self, name)
+            if name == "agg_p_blk" and v is None:
+                continue
             if not (isinstance(v, int) and not isinstance(v, bool)
                     and v > 0 and v % mult == 0):
                 raise ValueError(

@@ -30,33 +30,34 @@ from repro.kernels import ref as _ref
 from repro.kernels import ssd_chunk as _sc
 from repro.kernels.config import KernelConfig
 
-def aggregate(W: jnp.ndarray, X: jnp.ndarray, p_blk: int = 512) -> jnp.ndarray:
+def aggregate(W: jnp.ndarray, X: jnp.ndarray,
+              p_blk: Optional[int] = None) -> jnp.ndarray:
     """Y = W @ X (mixing-matrix model aggregation, paper Eq. 4)."""
     return _agg.aggregate(W, X, p_blk=p_blk)
 
 
 def aggregate_rows(W_rows: jnp.ndarray, X: jnp.ndarray,
-                   p_blk: int = 512) -> jnp.ndarray:
+                   p_blk: Optional[int] = None) -> jnp.ndarray:
     """Sparse Eq. 4: the k gathered non-identity rows of W times the buffer."""
     return _agg.aggregate_rows(W_rows, X, p_blk=p_blk)
 
 
 def aggregate_rows_cols(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
-                        X: jnp.ndarray, p_blk: int = 512) -> jnp.ndarray:
+                        X: jnp.ndarray, p_blk: Optional[int] = None) -> jnp.ndarray:
     """Column-sparse Eq. 4: gather the u-column union slab once, then
     contract ``(k, u) @ (u, P)`` (see ``kernels.aggregate``)."""
     return _agg.aggregate_rows_cols(W_sub, col_ids, X, p_blk=p_blk)
 
 
 def aggregate_rows_sharded(W_rows: jnp.ndarray, X: jnp.ndarray, shd,
-                           p_blk: int = 512) -> jnp.ndarray:
+                           p_blk: Optional[int] = None) -> jnp.ndarray:
     """Per-shard ``shard_map`` panel schedule over a row-sharded buffer."""
     return _agg.aggregate_rows_sharded_kernel(W_rows, X, shd, p_blk=p_blk)
 
 
 def aggregate_rows_cols_sharded(W_sub: jnp.ndarray, col_ids: jnp.ndarray,
                                 X: jnp.ndarray, shd,
-                                p_blk: int = 512) -> jnp.ndarray:
+                                p_blk: Optional[int] = None) -> jnp.ndarray:
     """Column-sparse shard_map twin (masked union gather + psum slab)."""
     return _agg.aggregate_rows_cols_sharded_kernel(W_sub, col_ids, X, shd,
                                                    p_blk=p_blk)

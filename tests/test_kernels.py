@@ -68,6 +68,68 @@ def test_aggregate_identity_rows():
     np.testing.assert_allclose(out[0], X.mean(0), rtol=1e-5, atol=1e-6)
 
 
+def _panel_grid(fn, *args):
+    """The grid of the one ``pallas_call`` in ``fn``'s jaxpr."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["grid_mapping"].grid
+            for v in eqn.params.values():
+                sub = v if hasattr(v, "eqns") else getattr(v, "jaxpr", None)
+                if sub is not None and (g := find(sub)) is not None:
+                    return g
+        return None
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("p", [1, 127, 129, 70_001])
+@pytest.mark.parametrize("k,u", [(1, 1), (2, 2), (2, 4), (4, 4), (8, 8),
+                                 (16, 64)])
+def test_panel_schedule_matches_ref(k, u, p):
+    """The derived-width schedule, both bodies (VPU for u < 8, MXU
+    otherwise) and a ragged last panel: column-sparse Eq. 4 against the
+    oracle, and an identity row comes back bit for bit."""
+    n = u + 3
+    kw, kx, kc = jax.random.split(jax.random.PRNGKey(k * 100 + u), 3)
+    W = np.array(jax.nn.softmax(jax.random.normal(kw, (k, u)), axis=-1))
+    W[0] = np.eye(u, dtype=np.float32)[u - 1]          # an identity row
+    X = jax.random.normal(kx, (n, p))
+    cids = jax.random.permutation(kc, n)[:u].astype(jnp.int32)
+    assert AGG.vpu_body(u) == (u < 8)
+    out = np.asarray(K.aggregate_rows_cols(jnp.asarray(W), cids, X))
+    ref = np.asarray(REF.aggregate_rows_cols_ref(jnp.asarray(W), cids, X))
+    np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(out[0], np.asarray(X)[int(cids[u - 1])])
+
+
+@pytest.mark.parametrize("k,u,p,steps", [
+    (2, 4, 134_515_008, 2_053),     # smollm-135m fleet of 4: u 4, k 2
+    (2, 2, 177_252_800, 2_705),     # mamba2-2.7b (4 layers) fleet of 2
+    (8, 64, 6_922, 1),              # the sim MLP at its bucket floor
+    (100, 100, 6_922, 2),           # the sim's dense N = 100 mix
+    (1, 1, 1, 1),
+    (16, 64, 70_001, 6),
+])
+def test_panel_width_rule(k, u, p, steps):
+    """The derived width: a multiple of 128 lanes, its double-buffered X and
+    Y blocks (rows rounded up to 8 sublanes) within the VMEM budget, the
+    widest such, no wider than P rounded up to 128; an explicit width still
+    pins the grid."""
+    w = AGG.panel_width(k, u, p)
+    per_lane = (-(-u // 8) * 8 + -(-k // 8) * 8) * 4 * 2
+    lanes = -(-p // 128) * 128
+    assert w % 128 == 0 and 128 <= w <= lanes
+    assert per_lane * w <= AGG.PANEL_VMEM_BYTES
+    assert w == lanes or per_lane * (w + 128) > AGG.PANEL_VMEM_BYTES
+    assert -(-p // w) == steps
+    W, X = jnp.ones((k, u)), jnp.ones((u, min(p, 70_001)))
+    cids = jnp.arange(u, dtype=jnp.int32)
+    for p_blk, grid in ((None, -(-X.shape[1] // AGG.panel_width(
+            k, u, X.shape[1]))), (256, -(-X.shape[1] // 256))):
+        assert _panel_grid(lambda w_, c_, x_: AGG.aggregate_rows_cols(
+            w_, c_, x_, p_blk=p_blk), W, cids, X) == (grid,)
+
+
 # --------------------------------------------------------------------------- #
 # flash attention
 # --------------------------------------------------------------------------- #

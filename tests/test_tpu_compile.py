@@ -36,6 +36,7 @@ from repro.sharding.rules import FleetSharding
 
 SIM_P = 6922            # default sim MLP: dim 32, hidden 64, 10 classes
 SMOLLM_P = 134_515_008  # smollm-135m, one worker's flat row
+MAMBA_P = 177_252_800   # mamba2-2.7b at 4 layers, an eighth of the vocab
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +79,8 @@ def _sds(shape, sharding, dtype=jnp.float32):
 @pytest.mark.parametrize("n,k,u,p", [
     (100, 8, 64, SIM_P),      # sim plane: N=100 fleet, 64-column union
     (4, 4, 4, SMOLLM_P),      # LM plane: 4 smollm-135m workers, all mixed
+    (4, 2, 4, SMOLLM_P),      # the smollm cell's buckets: the VPU body
+    (2, 2, 2, MAMBA_P),       # the Mamba-2 cell (4 layers, 2 workers)
 ])
 def test_aggregate_rows_cols_compiles(one_chip, n, k, u, p):
     txt = _compiled_text(

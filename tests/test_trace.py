@@ -15,8 +15,10 @@ import pytest
 from repro.core import trace as T
 from repro.core.protocol import DySTop
 from repro.dfl import lm_worker as LW
+from repro.dfl import simulator as SIM
 from repro.dfl import worker as WK
 from repro.dfl.simulator import History, SimConfig, run_simulation
+from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 
 SPANS = ("setup", "plan", "pack", "stage", "enqueue", "drain", "eval",
@@ -172,6 +174,37 @@ def test_counters_match_the_history(plane, sim_runs, lm_runs):
     assert c["mix_rows"] >= sum(h.round_active)
     assert 0 < c["scan_dispatches"] <= c["dispatches"] <= len(h.round_active)
     assert c["h2d_bytes"] > 0
+
+
+@pytest.mark.parametrize("plane", ["sim", "lm"])
+def test_mix_narrow_rounds_counts_the_vpu_body(plane, monkeypatch):
+    """``mix_narrow_rounds``: every mixing round of an N = 4 LM fleet on the
+    Pallas backend runs the panel kernel's VPU body (u <= 4 < 8); no round
+    of a sim at ``min_bucket`` 8 does, its buckets keep the MXU body."""
+    mod = LW if plane == "lm" else SIM
+    real = mod.count_dispatch
+    seen = []
+
+    def spy(trace, rounds, k_mix, *args, **kw):
+        seen.append((rounds, k_mix))
+        return real(trace, rounds, k_mix, *args, **kw)
+
+    monkeypatch.setattr(mod, "count_dispatch", spy)
+    pallas = KernelConfig(backend="pallas")
+    if plane == "lm":
+        _, h = LW.run_lm_federation(
+            DySTop(V=3.0, t_thre=3, max_neighbors=3),
+            R.get_smoke_config("smollm-135m"),
+            LW.LMRunConfig(n_workers=4, n_rounds=8, batch=2, seq=8,
+                           eval_every=4, seed=1, kernels=pallas))
+    else:
+        h = run_simulation(DySTop(V=10.0, t_thre=8, max_neighbors=4),
+                           SimConfig(n_workers=16, n_rounds=12, hidden=16,
+                                     n_samples=1200, dim=8, eval_every=6,
+                                     min_bucket=8, kernels=pallas))
+    mixing = sum(rounds for rounds, k_mix in seen if k_mix)
+    assert mixing > 0 and h.counts["mix_rows"] > 0
+    assert h.counts["mix_narrow_rounds"] == (mixing if plane == "lm" else 0)
 
 
 @pytest.mark.parametrize("depth", [0, 1])
