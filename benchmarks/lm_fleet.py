@@ -1,22 +1,19 @@
-"""LM fleet engine: persistent-flat planner-driven rounds vs the
-per-call-flatten baseline, at smoke geometry (N=8 real zoo workers).
+"""LM fleet engine: planner-driven resident rounds at smoke geometry (N=8
+real zoo workers).
 
-Three comparisons on the IDENTICAL control-plane + batch trajectory (the
-driver draws one token batch per planned round on either path, and the
-``HorizonPlanner`` rng stream is shared):
+On one control-plane + batch trajectory (the driver draws one token batch
+per planned round, and the ``HorizonPlanner`` rng stream is fixed by the
+seed):
 
-* resident vs re-flatten — the PR 4 tentpole: resident flat ``(N, P)`` /
-  ``(N, S)`` buffers + gathered-active-row training + ``lax.scan``
-  mega-rounds, against the pre-resident architecture (stacked pytrees,
-  flatten-per-call ``fleet_mix_stacked``, masked train-ALL-N step).  The
-  win stacks three effects: O(k) instead of O(N) train compute, no
-  pytree<->buffer churn per round, and one dispatch per horizon instead of
-  per round.  Acceptance: ≥1.3x rounds/sec on the CI box.
-* scan vs per-round dispatch — the same resident engine at
-  ``scan_horizon=1`` isolates what mega-round batching buys the LM plane.
-* optimizer spread — resident rounds under sgd vs adam vs adafactor: the
-  gathered-row step is generic over ``Optimizer.update``, so the resident
-  engine prices optimizer choice directly.
+* the resident engine — flat ``(N, P)`` / ``(N, S)`` buffers +
+  gathered-active-row training + ``lax.scan`` mega-rounds;
+* scan vs per-round dispatch — the same engine at ``scan_horizon=1``
+  isolates what mega-round batching buys the LM plane;
+* optimizer spread — rounds under sgd vs adam vs adafactor: the gathered-row
+  step is generic over ``Optimizer.update``, so the engine prices optimizer
+  choice directly;
+* the dispatch pipeline's host cost and phase breakdown;
+* per-arch reference vs Pallas zoo-kernel forward.
 
     PYTHONPATH=src python -m benchmarks.lm_fleet
     PYTHONPATH=src python -m benchmarks.run --only lm_fleet --quick
@@ -50,39 +47,24 @@ def _us_per_round(cfg, rounds: int, reps: int = 2, **kw) -> float:
     return min(one() for _ in range(reps))
 
 
-def _us_pipeline_pair(cfg, rounds: int, reps: int = 3, **kw) -> tuple:
-    """As ``_us_per_round`` at ``pipeline_depth`` 0 and 1, additionally
-    excluding host planner time (identical in both depths — the quantity
-    the depth knob changes is pack + stage + dispatch + device wait).
-    Reps are interleaved across depths so load spikes hit both paths
-    alike; best-of is then a fair floor for each.  Returns (lockstep
-    us/round, pipelined us/round, lockstep host pack+stage us/round,
-    pipelined host pack+stage us/round, best pipelined LMHistory)."""
-    def run_cfg(depth: int):
-        return LW.LMRunConfig(n_rounds=rounds, batch=2, seq=32,
-                              eval_every=rounds, pipeline_depth=depth, **kw)
+def _us_pipelined(cfg, rounds: int, reps: int = 3, **kw) -> tuple:
+    """As ``_us_per_round``, additionally excluding host planner time (the
+    quantity left is pack + stage + dispatch + device wait).  Returns (best
+    us/round, best host pack+stage us/round, the best run's LMHistory)."""
+    run = LW.LMRunConfig(n_rounds=rounds, batch=2, seq=32, eval_every=rounds,
+                         **kw)
 
-    def one(depth: int):
-        _, h = LW.run_lm_federation(_mech(rounds), cfg, run_cfg(depth))
+    def one():
+        _, h = LW.run_lm_federation(_mech(rounds), cfg, run)
         return ((h.wall_s - h.eval_wall_s - h.setup_wall_s
                  - h.plan_wall_s) / rounds * 1e6, h)
 
-    for depth in (0, 1):                            # compile warmup
-        LW.run_lm_federation(_mech(rounds), cfg, run_cfg(depth))
-    best = {0: float("inf"), 1: float("inf")}
-    host = {0: float("inf"), 1: float("inf")}
-    h1 = None
-    for _ in range(reps):
-        for depth in (0, 1):
-            us, h = one(depth)
-            if us < best[depth]:
-                best[depth] = us
-                if depth == 1:
-                    h1 = h
-            host[depth] = min(
-                host[depth],
-                (h.pack_wall_s + h.stage_wall_s) / rounds * 1e6)
-    return best[0], best[1], host[0], host[1], h1
+    LW.run_lm_federation(_mech(rounds), cfg, run)   # compile warmup
+    runs = [one() for _ in range(reps)]
+    us, h1 = min(runs, key=lambda r: r[0])
+    host = min((h.pack_wall_s + h.stage_wall_s) / rounds * 1e6
+               for _, h in runs)
+    return us, host, h1
 
 
 def main(rounds: int = 24, workers: int = 8,
@@ -90,19 +72,12 @@ def main(rounds: int = 24, workers: int = 8,
     cfg = R.get_smoke_config(arch)
     kw = dict(n_workers=workers)
 
-    resident = _us_per_round(cfg, rounds, resident_fleet=True, **kw)
-    reflatten = _us_per_round(cfg, rounds, resident_fleet=False, **kw)
+    resident = _us_per_round(cfg, rounds, **kw)
     emit(f"lm_fleet/resident_{workers}w", resident,
          f"persistent-flat planner-driven fleet ({arch} smoke), "
          f"gathered-active-row train + scan mega-rounds")
-    emit(f"lm_fleet/reflatten_{workers}w", reflatten,
-         "per-call-flatten baseline: stacked pytrees + masked all-N step")
-    emit(f"lm_fleet/resident_speedup_{workers}w", reflatten / resident,
-         f"resident fleet is {reflatten / resident:.2f}x rounds/sec vs the "
-         f"re-flatten path (same control + batch trajectory)")
 
-    scan1 = _us_per_round(cfg, rounds, resident_fleet=True, scan_horizon=1,
-                          **kw)
+    scan1 = _us_per_round(cfg, rounds, scan_horizon=1, **kw)
     emit(f"lm_fleet/resident_scan1_{workers}w", scan1,
          "resident engine, per-round dispatch (scan_horizon=1)")
     emit(f"lm_fleet/scan_speedup_{workers}w", scan1 / resident,
@@ -110,38 +85,23 @@ def main(rounds: int = 24, workers: int = 8,
          f"dispatch on the LM plane")
 
     for opt in ("sgd", "adafactor"):
-        us = _us_per_round(cfg, rounds, resident_fleet=True, optimizer=opt,
-                           **kw)
+        us = _us_per_round(cfg, rounds, optimizer=opt, **kw)
         emit(f"lm_fleet/resident_{opt}_{workers}w", us,
              f"resident rounds under {opt} (generic Optimizer.update in the "
              f"gathered-row step)")
 
-    # async dispatch pipeline row pair (ROADMAP item 5): the SAME resident
-    # trajectory driven lockstep (depth 0 oracle) vs double-buffered (the
-    # default), host planning excluded from both (identical and overlapped
-    # by the pipelined loop on multi-core hosts).  The smoke LM round is
-    # model-compute-bound (XLA CPU executes the mega-chunk synchronously on
-    # this 1-core runner), so the end-to-end pair is context; the pinned
-    # LM-plane delta is the HOST dispatch-path cost — pack + stage per
-    # round, the exact quantity the depth knob rewires (fast uniform-bucket
-    # packer + one fused non-blocking device_put vs pack_horizon + four
-    # jnp.asarray calls).
-    lock, pipe, host0, host1, h1 = _us_pipeline_pair(cfg, rounds, **kw)
-    emit(f"lm_fleet/lockstep_{workers}w", lock,
-         "resident fleet, pipeline_depth=0 (lockstep oracle drive loop); "
-         "model-compute-bound at smoke scale")
+    # the dispatch pipeline, host planning excluded (overlapped by the
+    # pipelined loop on multi-core hosts).  The smoke LM round is
+    # model-compute-bound, so the end-to-end row is context; the host
+    # dispatch-path cost — pack + stage per round — is what the pipeline
+    # shapes (fast uniform-bucket packer + one fused non-blocking
+    # device_put)
+    pipe, host1, h1 = _us_pipelined(cfg, rounds, **kw)
     emit(f"lm_fleet/pipelined_{workers}w", pipe,
-         "same trajectory, pipeline_depth=1: fast packer + fused device_put "
-         "staging + per-chunk loss drain, bounded in-flight chunks")
-    emit(f"lm_fleet/pipeline_host_lockstep_{workers}w", host0,
-         "depth-0 host dispatch-path cost per round (pack + stage walls)")
+         "pipeline_depth=1: fast packer + fused device_put staging + "
+         "per-chunk loss drain, bounded in-flight chunks")
     emit(f"lm_fleet/pipeline_host_pipelined_{workers}w", host1,
          "depth-1 host dispatch-path cost per round (pack + stage walls)")
-    emit(f"lm_fleet/pipeline_speedup_{workers}w", host0 / host1,
-         f"pipelined LM host dispatch path is {host0 / host1:.2f}x faster "
-         f"than lockstep (bit-identical trajectories; end-to-end smoke "
-         f"rounds are model-compute-bound so the wall pair above is ~flat "
-         f"on 1 core)")
     for phase, val in (("plan", h1.plan_wall_s), ("pack", h1.pack_wall_s),
                        ("stage", h1.stage_wall_s),
                        ("drain", h1.drain_wall_s)):
@@ -150,7 +110,7 @@ def main(rounds: int = 24, workers: int = 8,
              f"depth-1 {phase} host wall per round (LMHistory phase "
              f"breakdown; drain ~= device execute)")
 
-    # kernel plane pair (ROADMAP item 4): the same resident trajectory per
+    # kernel plane pair: the same resident trajectory per
     # zoo family with the forward pass routed through the Pallas kernels
     # (flash_attention / ssd_chunk / moe_router) vs the reference einsum
     # forward.  On CPU the kernels run in interpret mode, so the kernel
@@ -161,8 +121,8 @@ def main(rounds: int = 24, workers: int = 8,
     for karch in ("smollm-135m", "mamba2-2.7b", "kimi-k2-1t-a32b"):
         kcfg = R.get_smoke_config(karch)
         tag = karch.split("-")[0]
-        ref_us = _us_per_round(kcfg, kr, reps=1, resident_fleet=True, **kkw)
-        pal_us = _us_per_round(kcfg, kr, reps=1, resident_fleet=True,
+        ref_us = _us_per_round(kcfg, kr, reps=1, **kkw)
+        pal_us = _us_per_round(kcfg, kr, reps=1,
                                kernels=KernelConfig(backend="pallas"), **kkw)
         emit(f"lm_fleet/forward_ref_{tag}_4w", ref_us,
              f"{karch} smoke fleet, reference einsum forward (XLA CPU)")

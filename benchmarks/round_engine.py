@@ -1,22 +1,19 @@
-"""Fused round engine: legacy vs single-round dispatch vs scan mega-rounds
-vs the PR 3 column-sparse + fused-SGD engine.
+"""Fused round engine: per-round dispatch vs scan mega-rounds, the mix and
+SGD lowerings in isolation, and the dispatch pipeline's host phases.
 
 Four layers are measured at N=100 workers, steady partial activation
 (DySTop — the regime the mechanism targets):
 
-* legacy vs fused (``scan_horizon=1``) — PR 1's comparison: per-leaf mixing
-  dispatches + host batch loop vs ONE donated ``round_step`` jit per round.
-* fused vs scan (``scan_horizon=8``) — end-to-end simulations at the default
-  model scale; here the model plane (16 workers x 2 SGD steps) dominates, so
-  amortizing dispatch buys a bounded win.  The PR 2 engine (row-sparse mix +
-  per-step AD SGD, ``col_sparse_mix=False, fused_local_sgd=False``) is kept
-  as a row so the new-engine speedup is tracked end to end.
+* per-round vs scan — end-to-end simulations at ``scan_horizon=1`` and 8 at
+  the default model scale; here the model plane (16 workers x 2 SGD steps)
+  dominates, so amortizing dispatch buys a bounded win.
 * mix plane — ``mix_flat`` (row-sparse (k, N) @ (N, P)) vs ``mix_flat_cols``
   (gather-union (k, u) @ (u, P)) on a real steady-regime W at the edge-proxy
   model scale, buffers donated exactly like the engine's round dispatch.
-  Column sparsity wins where the mix is memory-bound on small models; at the
-  default model scale with a near-full union the simulator falls back to the
-  row-sparse path host-side (u = N never pays the slab gather).
+  Both are engine paths: ``aggregation.prefer_cols`` picks one per chunk,
+  and its gather-cost constant is read off these rows.
+* SGD plane — the fused unrolled lowering and the Pallas fused-SGD kernel
+  on the gathered active rows.
 * dispatch plane — the horizon scheduler's actual target: the same steady
   control trajectory executed with per-round ``round_step`` dispatches vs
   ``mega_round_step`` scans over a paper-testbed-scale edge model proxy
@@ -24,8 +21,7 @@ Four layers are measured at N=100 workers, steady partial activation
   regimes are tiny per-worker models, where per-round dispatch IS the
   cost).  Host planning is identical in both paths and excluded; this is
   rounds/sec of the engine itself.  The ``max_workers=8`` mix-dominated
-  variant additionally runs the PR 3 engine (column-sparse + fused SGD) on
-  the SAME plans — the ≥1.5x engine-speedup acceptance row.
+  variant runs the column-sparse + fused-SGD mega path on the SAME plans.
 
     PYTHONPATH=src python -m benchmarks.round_engine
     PYTHONPATH=src python -m benchmarks.run --only round_engine --quick
@@ -56,40 +52,32 @@ from repro.kernels.config import KernelConfig
 from benchmarks.common import emit
 
 
-def _cfg(rounds: int, workers: int, fused: bool,
+def _cfg(rounds: int, workers: int,
          kernels: Optional[KernelConfig] = None,
-         scan_horizon: int = 1, col_sparse_mix: bool = True,
-         fused_local_sgd: bool = True) -> SimConfig:
+         scan_horizon: int = 1) -> SimConfig:
     return SimConfig(n_workers=workers, n_rounds=rounds, phi=0.5, lr=0.1,
-                     eval_every=rounds, seed=0, fused_engine=fused,
-                     kernels=kernels, scan_horizon=scan_horizon,
-                     col_sparse_mix=col_sparse_mix,
-                     fused_local_sgd=fused_local_sgd)
+                     eval_every=rounds, seed=0, kernels=kernels,
+                     scan_horizon=scan_horizon)
 
 
 def _mech(max_workers: Optional[int]) -> DySTop:
     return DySTop(V=10.0, t_thre=20, max_neighbors=7, max_workers=max_workers)
 
 
-def _us_per_round(rounds: int, workers: int, fused: bool,
-                  max_workers: Optional[int],
+def _us_per_round(rounds: int, workers: int, max_workers: Optional[int],
                   kernels: Optional[KernelConfig] = None,
-                  scan_horizon: int = 1, reps: int = 3,
-                  col_sparse_mix: bool = True,
-                  fused_local_sgd: bool = True) -> float:
+                  scan_horizon: int = 1, reps: int = 3) -> float:
     # warmup run (full length, so both PTCA phases and every active-row shape
     # bucket get compiled), then per-round cost from `wall_s - eval_wall_s -
     # setup_wall_s` (the simulator separates eval passes and one-time setup
     # from round work, syncing queued dispatches before evals so device time
     # is charged to the rounds).  Best of `reps` runs: the floor is robust to
     # scheduler noise on small boxes.
-    kw = dict(kernels=kernels, scan_horizon=scan_horizon,
-              col_sparse_mix=col_sparse_mix, fused_local_sgd=fused_local_sgd)
-    run_simulation(_mech(max_workers), _cfg(rounds, workers, fused, **kw))
+    kw = dict(kernels=kernels, scan_horizon=scan_horizon)
+    run_simulation(_mech(max_workers), _cfg(rounds, workers, **kw))
 
     def one() -> float:
-        h = run_simulation(_mech(max_workers),
-                           _cfg(rounds, workers, fused, **kw))
+        h = run_simulation(_mech(max_workers), _cfg(rounds, workers, **kw))
         return (h.wall_s - h.eval_wall_s - h.setup_wall_s) / rounds * 1e6
 
     return min(one() for _ in range(reps))
@@ -153,9 +141,7 @@ def _mix_plane(workers: int, dim: int = 8, hidden: int = 8,
     efficient and the jnp lowering pays the union gather as a separate slab
     copy — measured parity-to-modest-win at N=100.  The TPU Pallas kernel
     (``aggregate_rows_cols``) is where the cut shows up as HBM traffic: the
-    (u, P) slab streams through VMEM panels instead of all N rows.  The
-    simulator's host-side u = N fallback guarantees the column path is never
-    a pessimization.
+    (u, P) slab streams through VMEM panels instead of all N rows.
     """
     import functools
 
@@ -191,14 +177,13 @@ def _mix_plane(workers: int, dim: int = 8, hidden: int = 8,
 
 def _sgd_plane(k: int = 16, dim: int = 32, hidden: int = 64, ncls: int = 10,
                steps: int = 2, batch: int = 32, reps: int = 60) -> tuple:
-    """Per-step AD scan vs the fused unrolled lowering, default model scale.
+    """The fused unrolled SGD lowering at the default model scale.
 
     Times ONLY the local-SGD jit over the gathered active rows (k workers x
-    ``local_steps`` — the simulator's default shapes), isolating the
-    tentpole's second half from host planning and dispatch noise.  Returns
-    (us AD oracle, us fused, us Pallas fused-SGD kernel).  The kernel row
-    runs interpret mode on CPU — a correctness/cost floor on record, not a
-    perf claim (docs/BENCHMARKS.md).
+    ``local_steps`` — the simulator's default shapes), isolated from host
+    planning and dispatch noise.  Returns (us fused, us Pallas fused-SGD
+    kernel).  The kernel row runs interpret mode on CPU — a
+    correctness/cost floor on record, not a perf claim (docs/BENCHMARKS.md).
     """
     stacked = WK.init_stacked(jax.random.PRNGKey(0), k, dim, hidden, ncls,
                               same_init=False)
@@ -208,8 +193,6 @@ def _sgd_plane(k: int = 16, dim: int = 32, hidden: int = 64, ncls: int = 10,
     yb = jax.random.randint(ky, (k, steps, batch), 0, ncls)
     act = jnp.ones((k,), jnp.float32)
     fns = {
-        "ad": jax.jit(lambda b: WK.local_sgd_flat(b, xb, yb, act, spec,
-                                                  0.05)[0]),
         "fused": jax.jit(lambda b: WK.local_sgd_flat_fused(
             b, xb, yb, act, spec, 0.05, with_losses=False)[0]),
         "kernel": jax.jit(lambda b: FSGD.fused_sgd(
@@ -223,7 +206,7 @@ def _sgd_plane(k: int = 16, dim: int = 32, hidden: int = 64, ncls: int = 10,
             t0 = time.perf_counter()
             jax.block_until_ready(fn(buf))
             best[name] = min(best[name], time.perf_counter() - t0)
-    return best["ad"] * 1e6, best["fused"] * 1e6, best["kernel"] * 1e6
+    return best["fused"] * 1e6, best["kernel"] * 1e6
 
 
 def _dispatch_plane(workers: int, horizon: int = 8, n_plan: int = 48,
@@ -237,8 +220,8 @@ def _dispatch_plane(workers: int, horizon: int = 8, n_plan: int = 48,
     plane: per-round ``round_step`` dispatches vs ``mega_round_step`` scans
     of ``horizon`` rounds, over an edge-proxy model (P ~ ``dim*hidden`` —
     the paper-testbed / large-N regime where dispatch dominates).  With
-    ``sparse_variant`` the PR 3 engine (column-sparse mix + fused SGD) runs
-    the SAME plans as a third contender.  Returns a dict of us/round.
+    ``sparse_variant`` the column-sparse + fused-SGD mega path runs the
+    plans instead.  Returns a dict of us/round.
     """
     plans, buf, spec, data_x, data_y, part_idx, part_sizes = _steady_env(
         workers, dim, hidden, max_workers, n_plan,
@@ -267,8 +250,8 @@ def _dispatch_plane(workers: int, horizon: int = 8, n_plan: int = 48,
         return b
 
     def mega_sparse_all(b):
-        # the full PR 3 dispatch exactly as run_simulation issues it:
-        # column-sparse mix + fused SGD + loss skip + mix-rows==train-rows
+        # a column-sparse chunk as run_simulation issues it: column-sparse
+        # mix + fused SGD + loss skip + mix-rows==train-rows
         for i in range(0, len(plans), horizon):
             chunk = plans[i:i + horizon]
             mit = all(not (p.links.any(axis=1) & ~p.active).any()
@@ -282,9 +265,8 @@ def _dispatch_plane(workers: int, horizon: int = 8, n_plan: int = 48,
                                       **kw)
         return b
 
-    variants = [("single", single_all), ("mega", mega_all)]
-    if sparse_variant:
-        variants.append(("mega_sparse", mega_sparse_all))
+    variants = ([("mega_sparse", mega_sparse_all)] if sparse_variant
+                else [("single", single_all), ("mega", mega_all)])
     state = {name: jnp.array(buf) for name, _ in variants}
     best = {name: float("inf") for name, _ in variants}
     for name, fn in variants:
@@ -303,57 +285,32 @@ def _dispatch_plane(workers: int, horizon: int = 8, n_plan: int = 48,
 
 def pipeline_main(rounds: int = 320, workers: int = 100,
                   reps: int = 5) -> None:
-    """Dispatch-plane row pair for the async pipeline (ROADMAP item 5):
-    the SAME steady trajectory driven lockstep (depth 0 oracle) vs
-    double-buffered (depth 1, the default), plus the depth-1 per-phase
-    breakdown rows.
+    """Dispatch-plane rows for the async pipeline: the drive loop's host
+    cost per round and its per-phase breakdown.
 
-    Steady DySTop control (max_workers=8 — stable (8, 8) shape buckets,
-    row-sparse mix so the column-union bucket never splits chunks) at the
+    Steady DySTop control (max_workers=8 — stable shape buckets) at the
     edge-proxy model scale with ``scan_horizon=16`` — the dispatch-bound
-    regime the pipeline targets; the whole run is full-horizon mega-chunks.
-    Per-round cost excludes eval, setup AND host planning (identical in both
-    paths, warmed at plan time either way, and overlapped by the pipelined
-    loop on multi-core hosts): what is left is pack + stage + dispatch +
-    device wait, the part the depth knob actually changes.  Reps are
-    interleaved across depths so load spikes hit both paths alike; best-of
-    is then a fair floor for each.
+    regime the pipeline targets.  Per-round cost excludes eval, setup AND
+    host planning (overlapped by the pipelined loop on multi-core hosts):
+    what is left is pack + stage + dispatch + device wait.  Best of
+    ``reps`` runs.
     """
-    def cfg(depth: int) -> SimConfig:
-        return SimConfig(n_workers=workers, n_rounds=rounds, phi=0.5, lr=0.1,
-                         dim=8, hidden=8, batch_size=8, local_steps=1,
-                         n_samples=4000, scan_horizon=16,
-                         col_sparse_mix=False, eval_every=rounds, seed=0,
-                         pipeline_depth=depth)
+    cfg = SimConfig(n_workers=workers, n_rounds=rounds, phi=0.5, lr=0.1,
+                    dim=8, hidden=8, batch_size=8, local_steps=1,
+                    n_samples=4000, scan_horizon=16, eval_every=rounds,
+                    seed=0)
 
-    def one(depth: int):
-        h = run_simulation(_mech(8), cfg(depth))
+    def one():
+        h = run_simulation(_mech(8), cfg)
         return ((h.wall_s - h.eval_wall_s - h.setup_wall_s
                  - h.plan_wall_s) / rounds * 1e6, h)
 
-    for depth in (0, 1):                            # compile warmup
-        run_simulation(_mech(8), cfg(depth))
-    best = {0: float("inf"), 1: float("inf")}
-    h1 = None
-    for _ in range(reps):
-        for depth in (0, 1):
-            us, h = one(depth)
-            if us < best[depth]:
-                best[depth] = us
-                if depth == 1:
-                    h1 = h
-    lock, pipe = best[0], best[1]
-    emit(f"round_engine/dispatch_lockstep_{workers}w", lock,
-         "steady scan16 row-sparse drive loop, pipeline_depth=0 "
-         "(lockstep oracle)")
+    run_simulation(_mech(8), cfg)                   # compile warmup
+    pipe, h1 = min((one() for _ in range(reps)), key=lambda r: r[0])
     emit(f"round_engine/dispatch_pipelined_{workers}w", pipe,
-         "same trajectory, pipeline_depth=1: fast uniform-bucket packer + "
-         "one fused non-blocking device_put + bounded in-flight chunks")
-    emit(f"round_engine/pipeline_speedup_{workers}w", lock / pipe,
-         f"pipelined drive loop is {lock / pipe:.2f}x rounds/sec vs the "
-         f"lockstep oracle (bit-identical trajectories; on this 1-core "
-         f"runner the win is the host-work cut — plan/device overlap adds "
-         f"on multi-core hosts)")
+         "steady scan16 drive loop, pipeline_depth=1: fast uniform-bucket "
+         "packer + one fused non-blocking device_put + bounded in-flight "
+         "chunks")
     for phase, val in (("plan", h1.plan_wall_s), ("pack", h1.pack_wall_s),
                        ("stage", h1.stage_wall_s),
                        ("drain", h1.drain_wall_s)):
@@ -470,43 +427,20 @@ def sharded_main(quick: bool = False, workers: int = 100,
 
 def main(rounds: int = 80, workers: int = 100) -> None:
     # headline: steady partial activation (max_workers=16), default model
-    legacy = _us_per_round(rounds, workers, fused=False, max_workers=16)
-    fused = _us_per_round(rounds, workers, fused=True, max_workers=16)
-    emit(f"round_engine/legacy_{workers}w", legacy,
-         "per-leaf mix + host batch loop + all-workers train jit")
+    fused = _us_per_round(rounds, workers, max_workers=16)
     emit(f"round_engine/fused_{workers}w", fused,
          "one donated dispatch per round (scan_horizon=1)")
-    emit(f"round_engine/speedup_{workers}w", legacy / fused,
-         f"fused is {legacy / fused:.2f}x faster per simulated round")
-    scan = _us_per_round(rounds, workers, fused=True, max_workers=16,
-                         scan_horizon=8)
+    scan = _us_per_round(rounds, workers, max_workers=16, scan_horizon=8)
     emit(f"round_engine/fused_scan8_{workers}w", scan,
-         "mega-rounds + column-sparse mix + fused SGD (the default engine)")
+         "mega-rounds + per-chunk mix contraction + fused SGD (the engine)")
     emit(f"round_engine/scan_speedup_{workers}w", fused / scan,
          f"end-to-end {fused / scan:.2f}x vs per-round dispatch (model plane "
          f"dominates at default scale)")
-    # PR 2 engine (row-sparse mix + per-step AD SGD) on the same trajectory:
-    # the end-to-end baseline the new default engine is tracked against
-    scan_pr2 = _us_per_round(rounds, workers, fused=True, max_workers=16,
-                             scan_horizon=8, col_sparse_mix=False,
-                             fused_local_sgd=False)
-    emit(f"round_engine/fused_scan8_pr2_{workers}w", scan_pr2,
-         "PR 2 engine: mega-rounds with row-sparse mix + AD-scan SGD")
-    emit(f"round_engine/engine_speedup_{workers}w", scan_pr2 / scan,
-         f"new engine is {scan_pr2 / scan:.2f}x end-to-end at the default "
-         f"model scale (SGD-bound; fused SGD is the lever here).  NB: the "
-         f"flags-off baseline shares PR 3's faster planner — vs the actual "
-         f"PR 2 commit the gap is wider")
-    # SGD plane: the fused unrolled lowering vs the per-step AD scan at the
-    # simulator's default shapes (k=16 x 2 steps x batch 32)
-    sgd_ad, sgd_fused, sgd_kernel = _sgd_plane()
-    emit(f"round_engine/sgd_ad_{workers}w", sgd_ad,
-         "per-step AD lax.scan local SGD (PR 2 lowering), k=16 x 2 steps")
+    # SGD plane: the fused unrolled lowering at the simulator's default
+    # shapes (k=16 x 2 steps x batch 32)
+    sgd_fused, sgd_kernel = _sgd_plane()
     emit(f"round_engine/sgd_fused_{workers}w", sgd_fused,
-         "fused unrolled manual-backward SGD (the default lowering)")
-    emit(f"round_engine/sgd_lowering_speedup_{workers}w", sgd_ad / sgd_fused,
-         f"fused local-steps SGD is {sgd_ad / sgd_fused:.2f}x the AD scan "
-         f"on the gathered active rows")
+         "fused unrolled manual-backward SGD (the sim-plane MLP lowering)")
     emit(f"round_engine/sgd_fused_kernel_{workers}w", sgd_kernel,
          "Pallas VMEM-resident fused-SGD kernel, same shapes (interpret "
          "mode on CPU — cost-on-record, the perf claim is TPU-only)")
@@ -534,36 +468,27 @@ def main(rounds: int = 80, workers: int = 100) -> None:
          f"mega-rounds are {d16['single'] / d16['mega']:.2f}x rounds/sec at "
          f"the dispatch plane (edge-proxy model, N={workers} steady, "
          f"horizon 16)")
-    # mix-dominated dispatch plane (max_workers=8 ⇒ union u=64 < N): the PR 3
-    # engine (column-sparse + fused SGD) vs the PR 2 mega path on SAME plans
+    # mix-dominated dispatch plane (max_workers=8 ⇒ union u=64 < N): the
+    # column-sparse + fused-SGD mega path
     d8 = _dispatch_plane(workers, horizon=16, n_plan=96, max_workers=8,
                          sparse_variant=True)
-    emit(f"round_engine/dispatch_scan16_pr2mix_{workers}w", d8["mega"],
-         "PR 2 mega-rounds (row-sparse mix + AD SGD), mix-dominated regime")
     emit(f"round_engine/dispatch_scan16_sparse_{workers}w", d8["mega_sparse"],
-         "PR 3 mega-rounds (column-sparse mix + fused SGD), same plans")
-    emit(f"round_engine/engine_scan_speedup_{workers}w",
-         d8["mega"] / d8["mega_sparse"],
-         f"new engine mega-rounds vs the PR 2 mega path on the same plans: "
-         f"{d8['mega'] / d8['mega_sparse']:.2f}x (N={workers} steady, "
-         f"edge-proxy model — dispatch-overhead-bound, so the lowering wins "
-         f"show up at the default model scale instead)")
-    fused_k = _us_per_round(rounds, workers, fused=True, max_workers=16,
+         "mega-rounds with column-sparse mix + fused SGD, mix-dominated "
+         "regime")
+    fused_k = _us_per_round(rounds, workers, max_workers=16,
                             kernels=KernelConfig(backend="pallas"))
     emit(f"round_engine/fused_kernel_{workers}w", fused_k,
          "fused + KernelConfig(backend='pallas'): panel mix AND fused-SGD "
          "kernel (interpret mode on CPU; compiles on TPU)")
-    # secondary: uncapped bursty activation (all-N flush rounds bound the win;
-    # bucket changes every round, so scan chunks degrade to single dispatches)
-    legacy_b = _us_per_round(rounds, workers, fused=False, max_workers=None)
-    fused_b = _us_per_round(rounds, workers, fused=True, max_workers=None,
+    # secondary: uncapped bursty activation (all-N flush rounds; bucket
+    # changes every round, so scan chunks degrade to single dispatches)
+    fused_b = _us_per_round(rounds, workers, max_workers=None,
                             scan_horizon=8)
-    emit(f"round_engine/legacy_{workers}w_burst", legacy_b,
-         "uncapped V=10 activation (1-active / all-active flush cycles)")
     emit(f"round_engine/fused_{workers}w_burst", fused_b,
-         f"fused is {legacy_b / fused_b:.2f}x in the bursty regime")
-    # async dispatch pipeline row pair (ROADMAP item 5); longer run so the
-    # scan32 chunks amortize warmup-independent noise
+         "uncapped V=10 activation (1-active / all-active flush cycles), "
+         "scan_horizon=8")
+    # async dispatch pipeline rows; longer run so the scan16 chunks amortize
+    # warmup-independent noise
     pipeline_main(rounds=rounds * 4, workers=workers)
 
 

@@ -47,12 +47,12 @@ SUITES = {
     # kernel microbenchmarks (the sharded-panel row emits only with >= 2
     # devices — CI's multi-device lane runs this suite on 8 emulated devices)
     "kernels": lambda q: kernels_bench.main(quick=q),
-    # fused device-resident round engine vs legacy per-leaf path
+    # fused device-resident round engine: dispatch, mix and SGD planes
     "round_engine": lambda q: round_engine.main(rounds=40 if q else 80),
     # mesh-sharded dispatch plumbing proof (emits only with >= 2 devices;
     # CI's multi-device lane forces 8 emulated host devices)
     "round_engine_sharded": lambda q: round_engine.sharded_main(quick=q),
-    # persistent-flat planner-driven LM fleet vs per-call-flatten baseline
+    # persistent-flat planner-driven LM fleet
     "lm_fleet": lambda q: lm_fleet.main(rounds=12 if q else 24),
     # scenario/fault-plane degradation curves: presets vs the
     # no-staleness-control ablation (ROADMAP item 2)

@@ -5,10 +5,6 @@ and optimizer state live in resident flat (N, P) / (N, S) buffers for the
 whole run.
 
     PYTHONPATH=src python examples/dfl_lm.py --arch gemma2-2b --rounds 25
-
-``--oracle`` runs the pre-resident architecture (per-call-flatten mixing +
-masked train-all-N step) on the identical control plane — useful for eyeball
-A/Bs; `benchmarks/lm_fleet.py` times the two properly.
 """
 import argparse
 
@@ -30,8 +26,6 @@ def main():
                     choices=("adam", "sgd", "adafactor"))
     ap.add_argument("--horizon", type=int, default=8,
                     help="rounds per lax.scan mega-dispatch")
-    ap.add_argument("--oracle", action="store_true",
-                    help="per-call-flatten baseline (resident_fleet=False)")
     args = ap.parse_args()
 
     cfg = R.get_smoke_config(args.arch)
@@ -40,12 +34,11 @@ def main():
     run = LW.LMRunConfig(
         n_workers=args.workers, n_rounds=args.rounds, batch=args.batch,
         seq=args.seq, optimizer=args.optimizer, scan_horizon=args.horizon,
-        resident_fleet=not args.oracle, eval_every=5)
+        eval_every=5)
     mech = DySTop(V=3.0, t_thre=args.rounds // 3, max_neighbors=3)
 
     print(f"federating {args.workers} x {cfg.arch_id} "
-          f"({'oracle' if args.oracle else 'resident'} engine, "
-          f"horizon {args.horizon})")
+          f"(horizon {args.horizon})")
     fleet, hist = LW.run_lm_federation(mech, cfg, run)
     print(f"{fleet.model_bytes / 1e6:.1f} MB params + "
           f"{fleet.opt_bytes / 1e6:.1f} MB {args.optimizer} state per replica")

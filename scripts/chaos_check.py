@@ -46,13 +46,11 @@ SIM_MODEL_FIELDS = ("acc_global", "acc_local", "loss_global")
 LM_MODEL_FIELDS = ("loss_global", "round_loss")
 
 # small enough for CI smoke, large enough that the child is mid-run when the
-# first snapshot (round 5) appears.  pipeline_depth=1 pinned explicitly: the
-# SIGKILL lands while the async dispatch pipeline has a chunk in flight, so
-# this doubles as the kill-mid-pipeline half of tests/test_pipeline.py
+# first snapshot (round 5) appears
 SIM_KW = dict(n_workers=16, n_rounds=60, n_samples=2000, dim=16,
-              eval_every=10, seed=7, scenario="churn20", pipeline_depth=1)
+              eval_every=10, seed=7, scenario="churn20")
 LM_KW = dict(n_workers=6, n_rounds=20, batch=2, seq=16, eval_every=5,
-             seed=7, scenario="blackout", scan_horizon=4, pipeline_depth=1)
+             seed=7, scenario="blackout", scan_horizon=4)
 CKPT_EVERY = 5
 
 

@@ -26,7 +26,7 @@ matching chunk-split key for ``lax.scan`` horizons.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -313,24 +313,16 @@ def mixing_rows_cols(W: np.ndarray, active: np.ndarray, links: np.ndarray,
     return W_sub, row_ids, col_ids
 
 
-def apply_mixing(W: jnp.ndarray, stacked_models: Any, kernels: Any = None,
-                 use_kernel: Optional[bool] = None) -> Any:
+def apply_mixing(W: jnp.ndarray, stacked_models: Any,
+                 kernels: Any = None) -> Any:
     """new_models = W @ models, per leaf.  Leaves: (N, ...).
 
-    ``kernels`` is a ``repro.kernels.config.KernelConfig`` (None = reference
-    einsum).  ``use_kernel`` is the DEPRECATED boolean it replaced.
+    The dense pytree form of Eq. 4, the reference the tests hold the
+    resident engines to.  ``kernels`` is a
+    ``repro.kernels.config.KernelConfig`` (None = reference einsum).
     """
-    if use_kernel is not None:
-        import warnings
-        warnings.warn(
-            "apply_mixing(use_kernel=...) is deprecated; pass "
-            "kernels=KernelConfig(backend='pallas') instead",
-            DeprecationWarning, stacklevel=2)
-        pallas = bool(use_kernel)
-        p_blk = None
-    else:
-        pallas = kernels is not None and kernels.use_pallas
-        p_blk = kernels.agg_p_blk if kernels is not None else None
+    pallas = kernels is not None and kernels.use_pallas
+    p_blk = kernels.agg_p_blk if kernels is not None else None
     if pallas:
         from repro.kernels import ops as K
 

@@ -70,43 +70,38 @@ class PlannedRound:
                                                   compare=False)
 
 
-def bucket_key(plan: "PlannedRound", n_workers: int,
-               col_sparse: bool = False,
-               min_bucket: int = 8, mesh_shards: int = 1) -> Tuple[int, ...]:
+def bucket_key(plan: "PlannedRound", n_workers: int, min_bucket: int = 8,
+               mesh_shards: int = 1) -> Tuple[int, int, int]:
     """Power-of-two shape buckets of one planned round.
 
-    ``(k_mix, k_train)`` — plus the bucket of the nonzero-column union when
-    the consumer contracts column-sparse — is everything a model plane needs
+    ``(k_mix, k_train, u)`` — the mixed and trained row buckets and the
+    bucket of the nonzero-column union — is everything a model plane needs
     to know to batch rounds into one ``lax.scan`` dispatch: every round of a
-    chunk must share one contraction shape.  Model-value-independent, so it
-    lives with the planner and serves BOTH planes (the MLP simulation engine
-    and the LM fleet engine) rather than being re-derived per worker module.
-    ``mesh_shards`` only feeds the ``col_union_mask`` fallback for plans
-    whose union the planner did not resolve (a sharded planner stores the
-    shard-aware union in ``mix_cols`` already).
+    chunk must share one contraction shape, whichever contraction
+    (``aggregation.prefer_cols``) the chunk then takes.
+    Model-value-independent, so it lives with the planner and serves BOTH
+    planes (the MLP simulation engine and the LM fleet engine) rather than
+    being re-derived per worker module.  ``mesh_shards`` only feeds the
+    ``col_union_mask`` fallback for plans whose union the planner did not
+    resolve (a sharded planner stores the shard-aware union in ``mix_cols``
+    already).
 
-    Memoized per (col_sparse, min_bucket, mesh_shards) on the plan itself:
-    ``chunk_spans``, the horizon packer, and the dispatch pipeline all key on
-    the same buckets, and with dispatch pipelined the key is consulted once
-    per consumer rather than recomputed — the memo fills lazily at first use
-    so its cost stays in the dispatch phase, not the (benchmark-excluded)
-    planning phase.  Plans are duck-typed throughout the packers, so a plan
-    without the memo slot simply recomputes.
+    Memoized per (min_bucket, mesh_shards) on the plan itself: the drive
+    loops warm it at plan time, so ``chunk_spans`` and the packers only do
+    lookups.  Plans are duck-typed throughout the packers, so a plan without
+    the memo slot simply recomputes.
     """
     memo = getattr(plan, "_key_memo", None)
-    mk = (col_sparse, min_bucket, mesh_shards)
+    mk = (min_bucket, mesh_shards)
     if memo is not None:
         key = memo.get(mk)
         if key is not None:
             return key
-    base = plan_buckets(plan.active, plan.links, min_bucket)
-    if col_sparse:
-        cols = (getattr(plan, "mix_cols", None))
-        if cols is None:
-            cols = col_union_mask(plan.active, plan.links, mesh_shards)
-        key = base + (bucket_size(int(cols.sum()), n_workers, min_bucket),)
-    else:
-        key = base
+    cols = getattr(plan, "mix_cols", None)
+    if cols is None:
+        cols = col_union_mask(plan.active, plan.links, mesh_shards)
+    key = (plan_buckets(plan.active, plan.links, min_bucket)
+           + (bucket_size(int(cols.sum()), n_workers, min_bucket),))
     if memo is not None:
         memo[mk] = key
     return key
@@ -139,8 +134,7 @@ def mix_is_train(plan: "PlannedRound") -> bool:
     activated workers build links).  Lets a fused model plane feed the Eq. 4
     output straight into Eq. 5 without scattering and re-gathering the same
     rows; push-style baselines (SA-ADFL) set links on passive receivers and
-    return False here.  Memoized on the plan (lazily, at first use) — both
-    the lockstep and pipelined dispatchers consult it per chunk.
+    return False here.  Memoized on the plan (lazily, at first use).
     """
     memo = getattr(plan, "_mit_memo", None)
     if memo is None:
@@ -151,26 +145,23 @@ def mix_is_train(plan: "PlannedRound") -> bool:
 
 
 def chunk_spans(plans: List["PlannedRound"], n_workers: int,
-                col_sparse: bool = False, min_bucket: int = 8,
-                mesh_shards: int = 1
-                ) -> Iterator[Tuple[int, int, Tuple[int, ...]]]:
+                min_bucket: int = 8, mesh_shards: int = 1
+                ) -> Iterator[Tuple[int, int, Tuple[int, int, int]]]:
     """Split a pending plan list into maximal bucket-uniform ``[lo, hi)``
     runs — the chunks a model plane ships as single ``lax.scan``
     mega-dispatches — yielding ``(lo, hi, key)`` with the run's shared
-    ``bucket_key`` so dispatchers never re-derive it (one source for the
-    (col_sparse, min_bucket) arguments).  Splitting (rather than padding to
-    the horizon max) means no round ever pays a larger shape bucket than its
-    own single-dispatch bucket; in the steady regime keys rarely change, so
-    chunks stay horizon-length.
+    ``bucket_key`` so dispatchers never re-derive it.  Splitting (rather
+    than padding to the horizon max) means no round ever pays a larger shape
+    bucket than its own single-dispatch bucket; in the steady regime keys
+    rarely change, so chunks stay horizon-length.
     """
     lo = 0
     while lo < len(plans):
-        key = bucket_key(plans[lo], n_workers, col_sparse, min_bucket,
-                         mesh_shards)
+        key = bucket_key(plans[lo], n_workers, min_bucket, mesh_shards)
         hi = lo + 1
         while (hi < len(plans)
-               and bucket_key(plans[hi], n_workers, col_sparse,
-                              min_bucket, mesh_shards) == key):
+               and bucket_key(plans[hi], n_workers, min_bucket,
+                              mesh_shards) == key):
             hi += 1
         yield lo, hi, key
         lo = hi
@@ -395,9 +386,9 @@ class HorizonPlanner:
     # boundary (checkpoint rounds force a flush + pipeline drain before
     # save_snapshot runs), so at snapshot time ``self.t`` equals the last
     # DISPATCHED round and state_dict() needs no in-flight plan queue —
-    # resuming a pipelined run replays from the exact same stream position as
-    # a lockstep one.  That invariant is what keeps pipeline_depth out of the
-    # checkpoint format (see dfl.pipeline and docs/ARCHITECTURE.md).
+    # resuming replays from the exact same stream position at any depth.
+    # That invariant is what keeps pipeline_depth out of the checkpoint
+    # format (see dfl.pipeline and docs/ARCHITECTURE.md).
 
     _STATE_ARRAYS = ("tau", "queue", "pull_counts", "time_since_act",
                      "budget", "down")

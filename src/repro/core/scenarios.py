@@ -11,9 +11,9 @@ consumes ahead of the device.
 The cardinal invariant: **overlays never touch the rng stream**.  Every event
 is a deterministic function of the round index (and static network geometry),
 applied as a mask/scale on top of the stochastic draws the planner already
-makes — so a scenario replays bit-identically on the fused, legacy, and
-mesh-sharded engines at any ``scan_horizon`` (the rng stream IS the
-trajectory, and the stream never moves).
+makes — so a scenario replays bit-identically at any ``scan_horizon``,
+pipeline depth and shard count (the rng stream IS the trajectory, and the
+stream never moves).
 
 Graceful-degradation semantics ride through the existing machinery:
 

@@ -19,9 +19,8 @@ simulation plane:
   * local training is a GATHERED-ACTIVE-ROW step: only the k activated
     workers' rows are read, one after another, through AD + the generic
     ``Optimizer.update`` (adam/sgd/adafactor — any state pytree), and
-    written back in place.  The pre-PR-4 architecture (per-call-flatten
-    mixing + train-all-N-and-mask step) is kept as the flag-gated
-    correctness oracle (``LMRunConfig.resident_fleet=False``).
+    written back in place.  The tests hold it to a plain per-call-flatten
+    reference (``tests/lm_reference.py``).
 
 CPU-budget note: use smoke-geometry configs (``registry.get_smoke_config``)
 for interactive runs; the code path is identical for full configs on real
@@ -32,7 +31,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import pathlib
-import warnings
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -42,7 +40,7 @@ from jax.sharding import PartitionSpec
 
 from repro.checkpoint import io as CIO
 from repro.configs.base import ModelConfig
-from repro.core.aggregation import mixing_rows, prefer_cols
+from repro.core.aggregation import prefer_cols
 from repro.core.planner import (HorizonPlanner, PlannedRound, bucket_key,
                                 chunk_spans, mix_is_train)
 from repro.core.scenarios import resolve_scenario
@@ -69,9 +67,8 @@ class LMFleet:
     ``spec`` carries the ravel metadata for both.  The ``stacked_params`` /
     ``stacked_opt`` properties materialize (and, on assignment, re-flatten)
     the stacked pytrees — that round-trip is exact (f32 storage holds bf16
-    params and int32 step counters losslessly) and is the per-call cost the
-    legacy oracle path pays on every round, which the resident engine pays
-    never.
+    params and int32 step counters losslessly) and is paid only at those
+    boundaries, never per round.
     """
     cfg: ModelConfig
     pbuf: jnp.ndarray               # (N, P) f32 resident params
@@ -82,7 +79,8 @@ class LMFleet:
 
     @property
     def stacked_params(self) -> Params:
-        """Stacked param pytree (leaves (N, ...)) — checkpoint/oracle view."""
+        """Stacked param pytree (leaves (N, ...)) — checkpoint/reference
+        view."""
         return FS.unflatten(self.pbuf, self.spec.params)
 
     @stacked_params.setter
@@ -190,112 +188,6 @@ def worker_streams(cfg: ModelConfig, n_workers: int, batch: int, seq: int,
 
 
 # --------------------------------------------------------------------------- #
-# per-call-flatten oracle plane (the pre-resident architecture, flag-gated)
-# --------------------------------------------------------------------------- #
-
-
-def fleet_mix_stacked(stacked_params: Params, W: np.ndarray,
-                      active: Optional[np.ndarray] = None,
-                      links: Optional[np.ndarray] = None,
-                      kernels=None) -> Params:
-    """Eq. 4 over a STACKED param pytree, re-flattening per call.
-
-    The pre-PR-4 mixing path, kept as the correctness oracle and the
-    benchmark baseline: flatten the whole fleet, run the same gather ->
-    (k, N) @ (N, P) -> scatter contraction as the resident engine, unflatten
-    back to the pytree the masked train step consumes.
-    """
-    buf, spec = FS.flatten_stacked(stacked_params)
-    use_pallas = kernels is not None and kernels.use_pallas
-    if active is not None and links is not None:
-        w_rows, row_ids = mixing_rows(np.asarray(W, np.float32), active, links)
-        buf = WK.mix_flat(buf, jnp.asarray(w_rows), jnp.asarray(row_ids),
-                          kernels=kernels)
-    elif use_pallas:
-        from repro.kernels import ops as K
-        buf = K.aggregate(jnp.asarray(W, jnp.float32), buf,
-                          p_blk=kernels.agg_p_blk)
-    else:
-        buf = jnp.asarray(W, jnp.float32) @ buf
-    return FS.unflatten(buf, spec)
-
-
-def fleet_mix(fleet: LMFleet, W: np.ndarray,
-              active: Optional[np.ndarray] = None,
-              links: Optional[np.ndarray] = None,
-              kernels=None) -> None:
-    """Eq. 4 over the RESIDENT fleet buffer — no flatten, no pytree.
-
-    When ``active``/``links`` are given, only the k non-identity rows of W
-    are computed — the same gather -> (k, N) @ (N, P) -> scatter path as the
-    simulation plane's fused engine.
-    """
-    use_pallas = kernels is not None and kernels.use_pallas
-    if active is not None and links is not None:
-        w_rows, row_ids = mixing_rows(np.asarray(W, np.float32), active, links)
-        fleet.pbuf = WK.mix_flat(fleet.pbuf, jnp.asarray(w_rows),
-                                 jnp.asarray(row_ids), kernels=kernels)
-    elif use_pallas:
-        from repro.kernels import ops as K
-        fleet.pbuf = K.aggregate(jnp.asarray(W, jnp.float32), fleet.pbuf,
-                                 p_blk=kernels.agg_p_blk)
-    else:
-        fleet.pbuf = jnp.asarray(W, jnp.float32) @ fleet.pbuf
-
-
-def make_fleet_step(fleet: LMFleet):
-    """Masked per-worker train step over STACKED pytrees: trains ALL N
-    workers, one after another as the resident engine does, and masks the
-    inactive updates away.  The pre-PR-4 oracle the gathered-active-row
-    engine is pinned against — O(N) model-plane work per round regardless of
-    how few workers activated."""
-    return _fleet_step(fleet.cfg, fleet.optimizer)
-
-
-@functools.lru_cache(maxsize=None)
-def _fleet_step(cfg: ModelConfig, opt: Optimizer):
-    def one(params, opt_state, batch, active):
-        def loss_fn(p):
-            return R.compute_loss(cfg, p, batch)
-
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-        new_p, new_s = opt.update(grads, opt_state, params)
-        a = active.astype(jnp.float32)
-
-        def mix(n, o):
-            am = a.astype(n.dtype).reshape((1,) * n.ndim)
-            return n * am + o * (1 - am)
-
-        return (jax.tree.map(mix, new_p, params),
-                jax.tree.map(mix, new_s, opt_state), loss)
-
-    return jax.jit(lambda *xs: jax.lax.map(lambda x: one(*x), xs))
-
-
-def fleet_eval_stacked(cfg: ModelConfig, stacked_params: Params,
-                       batch: Dict[str, jnp.ndarray],
-                       alpha: jnp.ndarray) -> float:
-    """Eq. 11 eval through the stacked pytree (per-leaf tensordot) — the
-    eval-by-pytree oracle twin of ``fleet_eval``."""
-    gm = jax.tree.map(lambda l: jnp.tensordot(alpha, l.astype(jnp.float32),
-                                              axes=1).astype(l.dtype),
-                      stacked_params)
-    loss, _ = R.compute_loss(cfg, gm, batch)
-    return float(loss)
-
-
-def fleet_eval(fleet: LMFleet, batch: Dict[str, jnp.ndarray],
-               alpha: jnp.ndarray) -> float:
-    """Loss of the data-size-weighted global model (paper Eq. 11),
-    flat-native: one ``alpha @ pbuf`` matvec (``flat_state.weighted_row``)
-    plus a static unravel — no stacked pytree is materialized."""
-    gm = FS.unravel_row(FS.weighted_row(fleet.pbuf, alpha),
-                        fleet.spec.params)
-    loss, _ = R.compute_loss(fleet.cfg, gm, batch)
-    return float(loss)
-
-
-# --------------------------------------------------------------------------- #
 # the resident engine: gathered-active-row rounds as lax.scan mega-dispatches
 # --------------------------------------------------------------------------- #
 
@@ -332,22 +224,14 @@ class LMEngine:
     DySTop round) the mixed sub-buffer feeds the train step directly,
     skipping the intermediate scatter.
 
-    Jits are cached per (col_sparse, fuse, pregather) variant; shapes bucket
-    through ``pack_horizon``, so the compile count stays O(log N) per
-    variant.
+    Jits are cached per (col_sparse, fuse) variant; shapes bucket through
+    ``pack_chunk``, so the compile count stays O(log N) per variant.
 
     ``shd`` (a ``sharding.rules.FleetSharding``) runs the engine mesh-
     sharded: ``pbuf``/``obuf`` stay row-partitioned over the fleet axis
     across dispatches, the mix lowers to the collective contractions of
     ``kernels.aggregate`` (union all_gather / shard-local slabs + psum), and
     each shard trains the activated rows it holds.
-
-    ``pregather=True`` in ``dispatch_chunk`` gathers the k activated batch
-    rows on HOST before the H2D transfer — batches ship (H, k, B, S) instead
-    of (H, N, B, S), an ~N/k transfer cut that matters precisely in the
-    large-N sharded regime (the train ids still ride in ``ctrl`` for the
-    scatter; gather by padded ids is value-exact, padding rows skip their
-    train step).
     """
 
     def __init__(self, cfg: ModelConfig, optimizer: Optimizer,
@@ -384,7 +268,7 @@ class LMEngine:
         grads, updated f32 rows), which for smollm-135m at its published
         widths does not fit one 16 GB chip; in sequence one worker's are live
         at a time.  Numerics: every row runs the same program whatever the
-        bucket size k, so ``min_bucket`` and the per-call-flatten oracle
+        bucket size k, so ``min_bucket`` and the per-call-flatten reference
         (which maps over workers the same way) round alike.
 
         A padding row (``mask[i] == 0``: an idle row, ``padded_rows``) skips
@@ -450,7 +334,9 @@ class LMEngine:
                          out_specs=(rows, rows, rep), check_vma=False)(*args)
 
     def _round_body(self, pbuf, obuf, w, mids, cids, tids, mask, tok, lab,
-                    fuse: bool, pregather: bool):
+                    fuse: bool):
+        """One round of the scan; ``tok``/``lab`` are the activated rows'
+        batches, (k_train, B, S) in train-row order."""
         shd = self.shd
 
         def pin(pb, ob, ls):
@@ -461,17 +347,12 @@ class LMEngine:
                     jax.lax.with_sharding_constraint(ls, shd.replicated()))
 
         k_mix, k_train = w.shape[0], tids.shape[0]
-        # pregathered batches arrive (k, B, S) in train-row order; otherwise
-        # the activated rows are gathered from the full-N batch on device
-        with jax.named_scope("gather"):
-            tok_k = tok if pregather else (tok[tids] if k_train else tok)
-            lab_k = lab if pregather else (lab[tids] if k_train else lab)
         if fuse and k_mix and k_train:
             # mix rows == train rows: Eq. 4 output feeds Eq. 5 directly
             with jax.named_scope("mix"):
                 sub = WK._mix_rows(pbuf, w, cids, self.kernels, shd)
             return pin(*self._train_rows(pbuf, obuf, sub, tids, mask,
-                                         tok_k, lab_k))
+                                         tok, lab))
         if k_mix:
             with jax.named_scope("mix"):
                 pbuf = (WK.mix_flat_cols(pbuf, w, mids, cids, self.kernels,
@@ -481,11 +362,11 @@ class LMEngine:
                                          shd=shd))
         if k_train:
             return pin(*self._train_rows(pbuf, obuf, None, tids, mask,
-                                         tok_k, lab_k))
+                                         tok, lab))
         return pin(pbuf, obuf, jnp.zeros((pbuf.shape[0],), jnp.float32))
 
-    def _mega(self, col_sparse: bool, fuse: bool, pregather: bool):
-        key = (col_sparse, fuse, pregather)
+    def _mega(self, col_sparse: bool, fuse: bool):
+        key = (col_sparse, fuse)
         if key in self._mega_cache:
             return self._mega_cache[key]
 
@@ -500,8 +381,7 @@ class LMEngine:
                     w, mi, ci, ti, m, tk, lb = xs
                     with jax.named_scope("mega_round"):
                         pb, ob, ls = self._round_body(c[0], c[1], w, mi, ci,
-                                                      ti, m, tk, lb, fuse,
-                                                      pregather)
+                                                      ti, m, tk, lb, fuse)
                     return (pb, ob), ls
                 xs = (w_rows, mix_ids, col_ids, train_ids, masks,
                       tokens, labels)
@@ -510,8 +390,7 @@ class LMEngine:
                     w, mi, ti, m, tk, lb = xs
                     with jax.named_scope("mega_round"):
                         pb, ob, ls = self._round_body(c[0], c[1], w, mi, None,
-                                                      ti, m, tk, lb, fuse,
-                                                      pregather)
+                                                      ti, m, tk, lb, fuse)
                     return (pb, ob), ls
                 xs = (w_rows, mix_ids, train_ids, masks, tokens, labels)
             (pbuf, obuf), losses = jax.lax.scan(body, (pbuf, obuf), xs)
@@ -521,40 +400,30 @@ class LMEngine:
         return mega
 
     def dispatch_chunk(self, pbuf, obuf, chunk: List[PlannedRound],
-                       tokens: np.ndarray, labels: np.ndarray, *,
+                       tokens: np.ndarray, labels: np.ndarray, *, key,
                        col_sparse: bool, fuse: bool, trace: Trace,
-                       min_bucket: int = 8, pregather: bool = False, key=None
+                       min_bucket: int = 8
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """One bucket-uniform chunk -> one donated scan dispatch.
 
         ``tokens``/``labels`` are the full-N per-round batches (H, N, B, S).
-        ``pregather=False``: they ship whole and the activated rows are
-        gathered ON DEVICE by the packed train ids.  ``pregather=True``: the
-        k activated rows are gathered on HOST (by the padded train-id
-        segments already packed into ``ctrl``) and only (H, k, B, S) crosses
-        the H2D boundary — identical values, ~N/k less batch transfer.
-
-        ``key`` (the chunk's ``bucket_key``, pipelined drive loop only)
-        routes packing through the uniform-bucket fast packer
-        (``worker.pack_chunk`` — bit-identical output, much less host work)
-        and stages all four host arrays with ONE fused non-blocking
-        ``jax.device_put``; ``key=None`` keeps the original pack/stage path
-        verbatim (the depth-0 oracle).  ``trace`` (the call's
-        ``core.trace.Trace``) takes the ``pack``, ``stage`` and ``enqueue``
-        spans and the chunk's counters (``pipeline.count_dispatch``).
+        The k activated rows are gathered on HOST (by the padded train-id
+        segments packed into ``ctrl``), so only (H, k, B, S) crosses the
+        H2D boundary; padding rows skip their train step, so gathering by
+        padded ids is value-exact.  ``key`` is the chunk's ``bucket_key``:
+        the uniform-bucket packer (``worker.pack_chunk``) takes its shapes
+        from it, and all four host arrays stage with ONE fused non-blocking
+        ``jax.device_put``.  ``trace`` (the call's ``core.trace.Trace``)
+        takes the ``pack``, ``stage`` and ``enqueue`` spans and the chunk's
+        counters (``pipeline.count_dispatch``).
 
         Returns (new pbuf, new obuf, (H, N) per-round losses — zero rows for
         idle workers).
         """
         shards = self.shd.n_shards if self.shd is not None else 1
         with trace.span("pack"):
-            if key is not None:
-                w, c, _ = WK.pack_chunk(chunk, key, min_bucket=min_bucket,
-                                        col_sparse=col_sparse, shards=shards)
-            else:
-                w, c, _ = WK.pack_horizon(chunk, min_bucket=min_bucket,
-                                          col_sparse=col_sparse,
-                                          shards=shards)
+            w, c, _ = WK.pack_chunk(chunk, key, min_bucket=min_bucket,
+                                    col_sparse=col_sparse, shards=shards)
             if self.shd is not None and not (col_sparse and w.shape[1]):
                 w = WK.pad_w_cols(w, pbuf.shape[0])
             k_mix = w.shape[1]
@@ -562,25 +431,20 @@ class LMEngine:
             # one ctrl-layout definition: the same split the device scan
             # performs
             _, _, tids, _ = WK.split_ctrl(c, k_mix, u)
-            k_train = tids.shape[-1]
-            if pregather and k_train:
-                h_ix = np.arange(len(chunk))[:, None]
-                tokens = tokens[h_ix, tids]                  # (H, k, B, S)
-                labels = labels[h_ix, tids]
+            h_ix = np.arange(len(chunk))[:, None]
+            tokens = tokens[h_ix, tids]                      # (H, k, B, S)
+            labels = labels[h_ix, tids]
         with trace.span("stage"):
             if self.shd is not None:
                 put = self.shd.put
                 w_j, c_j = put(w), put(c)
                 tk_j, lb_j = put(tokens), put(labels)
-            elif key is not None:
-                w_j, c_j, tk_j, lb_j = jax.device_put((w, c, tokens, labels))
             else:
-                w_j, c_j = jnp.asarray(w), jnp.asarray(c)
-                tk_j, lb_j = jnp.asarray(tokens), jnp.asarray(labels)
+                w_j, c_j, tk_j, lb_j = jax.device_put((w, c, tokens, labels))
         with trace.span("enqueue"):
-            out = self._mega(col_sparse, fuse, pregather and bool(k_train))(
-                pbuf, obuf, w_j, c_j, tk_j, lb_j)
-        count_dispatch(trace, len(chunk), k_mix, k_train,
+            out = self._mega(col_sparse, fuse)(pbuf, obuf, w_j, c_j, tk_j,
+                                               lb_j)
+        count_dispatch(trace, len(chunk), k_mix, tids.shape[-1],
                        w.nbytes + c.nbytes + tokens.nbytes + labels.nbytes,
                        WK.narrow_mix(self.kernels, w.shape, bool(u), shards))
         return out
@@ -624,22 +488,16 @@ class LMEngine:
 class LMRunConfig:
     """LM-plane run configuration (the SimConfig of the LM fleet).
 
-    ``resident_fleet`` gates the tentpole: True (default) runs the
-    device-resident gathered-active-row engine with ``scan_horizon``
-    mega-rounds; False runs the per-call-flatten oracle (stacked pytrees,
-    ``fleet_mix_stacked`` + the masked train-all-N step) on the IDENTICAL
-    control plane — trajectories are bit-for-bit equal, model state equal to
-    f32 tolerance (pinned by ``tests/test_lm_fleet.py``).  ``min_bucket=2``:
-    LM fleets are small (8-64 workers), so fine-grained shape buckets keep
-    the gathered row set near the true activation count.
+    One engine: the device-resident gathered-active-row ``LMEngine`` with
+    ``scan_horizon`` mega-rounds behind the async dispatch pipeline
+    (``pipeline_depth`` chunks in flight, >= 1; see ``SimConfig``).
+    ``min_bucket=2``: LM fleets are small (8-64 workers), so fine-grained
+    shape buckets keep the gathered row set near the true activation count.
 
-    ``mesh_shards > 1`` (resident engine only) row-partitions ``pbuf`` /
-    ``obuf`` over the 1-D fleet mesh — N pads to a shard multiple with
-    permanently-idle rows, control trajectories stay bit-identical, model
-    state agrees to f32 reduction-order tolerance.  ``host_batch_gather``
-    gathers the k activated batch rows on host before H2D (value-exact;
-    (H, k, B, S) ships instead of (H, N, B, S) — the ~N/k transfer cut that
-    matters in the large-N sharded regime).
+    ``mesh_shards > 1`` row-partitions ``pbuf`` / ``obuf`` over the 1-D
+    fleet mesh — N pads to a shard multiple with permanently-idle rows,
+    control trajectories stay bit-identical, model state agrees to f32
+    reduction-order tolerance.
     """
     n_workers: int = 8
     n_rounds: int = 30
@@ -649,13 +507,9 @@ class LMRunConfig:
     lr: float = 1e-3
     scan_horizon: int = 8
     pipeline_depth: int = 1           # in-flight chunks behind the staged one
-                                      #   (resident engine): 1 = double
-                                      #   buffering (default), 0 = lockstep
-                                      #   oracle — bit-identical either way
-    resident_fleet: bool = True
-    col_sparse_mix: bool = True
+                                      #   (>= 1; 1 = double buffering) —
+                                      #   bit-identical at any depth
     mesh_shards: int = 1
-    host_batch_gather: bool = True
     min_bucket: int = 2
     eval_every: int = 5
     seed: int = 0
@@ -665,9 +519,6 @@ class LMRunConfig:
     sync_link_timeout_s: float = 30.0
     comm_range_m: float = 80.0
     compute_sigma: float = 0.6
-    use_kernel: bool = False          # DEPRECATED alias: True maps to
-                                      #   kernels=KernelConfig(
-                                      #   backend="pallas") in __post_init__
     kernels: Optional["KernelConfig"] = None  # kernel-plane config (see
                                       #   SimConfig.kernels): backend="pallas"
                                       #   routes Eq. 4 mixing through the
@@ -698,14 +549,11 @@ class LMRunConfig:
             if v <= 0:
                 raise ValueError(f"LMRunConfig.{f} must be > 0, got {v}")
         for f in ("n_workers", "n_rounds", "batch", "seq", "eval_every",
-                  "scan_horizon", "mesh_shards", "min_bucket"):
+                  "scan_horizon", "pipeline_depth", "mesh_shards",
+                  "min_bucket"):
             v = getattr(self, f)
             if v < 1:
                 raise ValueError(f"LMRunConfig.{f} must be >= 1, got {v}")
-        if self.pipeline_depth < 0:
-            raise ValueError(f"LMRunConfig.pipeline_depth must be >= 0 "
-                             f"(0 = lockstep oracle), got "
-                             f"{self.pipeline_depth}")
         if self.checkpoint_every < 0:
             raise ValueError(f"LMRunConfig.checkpoint_every must be >= 0 "
                              f"(0 disables snapshots), got "
@@ -720,19 +568,6 @@ class LMRunConfig:
                 f"LMRunConfig.kernels must be a kernels.config.KernelConfig "
                 f"(or None for the reference default), got "
                 f"{type(self.kernels).__name__}")
-        if self.use_kernel:
-            warnings.warn(
-                "LMRunConfig.use_kernel is deprecated; pass "
-                "kernels=KernelConfig(backend='pallas') instead",
-                DeprecationWarning, stacklevel=2)
-            if self.kernels is None:
-                self.kernels = KernelConfig(backend="pallas")
-            elif not self.kernels.use_pallas:
-                raise ValueError(
-                    "LMRunConfig.use_kernel=True conflicts with "
-                    "kernels=KernelConfig(backend='reference') — drop the "
-                    "deprecated flag and select the backend on KernelConfig "
-                    "alone")
         if self.kernels is None:
             self.kernels = KernelConfig()
         self.kernels.check_executable("LMRunConfig.kernels")
@@ -784,9 +619,8 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
 
     The ``HorizonPlanner`` owns ALL control state exactly as in
     ``run_simulation``; one token-stream draw happens per planned round in
-    plan order on BOTH engine paths, so the batch trajectory — like the
-    control trajectory — is bit-for-bit independent of
-    ``resident_fleet``/``scan_horizon``.
+    plan order, so the batch trajectory — like the control trajectory — is
+    bit-for-bit independent of ``scan_horizon`` and ``pipeline_depth``.
 
     ``resume_from`` (see ``run_simulation``): a snapshot file or checkpoint
     directory from a ``checkpoint_every`` run of the same config; setup
@@ -806,9 +640,6 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
             cfg = dataclasses.replace(cfg, kernels=run.kernels)
         shd = None
         if run.mesh_shards > 1:
-            if not run.resident_fleet:
-                raise ValueError("mesh_shards > 1 requires the resident "
-                                 "engine (resident_fleet=True)")
             from repro.sharding.rules import FleetSharding
             shd = FleetSharding.create(run.mesh_shards)
         rng = np.random.default_rng(run.seed)
@@ -846,8 +677,7 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
         # --- crash-safe resume: overwrite the deterministic setup's mutable
         # state (resident buffers, planner, rng stream, history but its host
         # times) and fast-forward the token stream past the checkpointed
-        # rounds.  Placed BEFORE the engine/oracle setup so the oracle's
-        # stacked pytrees materialize from the restored buffers.
+        # rounds
         if resume_from is not None:
             ck = pathlib.Path(resume_from)
             if ck.is_dir():
@@ -866,7 +696,6 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
                                                        arr_tmpl)
             saved_cfg = extra.get("config", {})
             checks = {"plane": "lm", "n_workers": n, "seed": run.seed,
-                      "resident_fleet": run.resident_fleet,
                       "mesh_shards": run.mesh_shards,
                       "scenario": scen.schedule.name if scen else None}
             for k, want in checks.items():
@@ -890,87 +719,46 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
                 if hasattr(hist, k) and not k.endswith("wall_s"):
                     setattr(hist, k, v)
 
-        if run.resident_fleet:
-            engine = get_lm_engine(cfg, fleet.optimizer, fleet.spec,
-                                   kernels=run.kernels, shd=shd)
-            horizon = max(1, run.scan_horizon)
-            sp = so = step = None
-        else:
-            engine = None
-            horizon = 1                       # the oracle dispatches per round
-            sp, so = fleet.stacked_params, fleet.stacked_opt   # pytrees, ONCE
-            step = make_fleet_step(fleet)
-
-    # async dispatch pipeline (as run_simulation): depth >= 1 overlaps host
-    # plan/pack/stage with the device scan, depth 0 keeps the original
-    # lockstep dispatch path verbatim as the oracle
-    pipelined = run.resident_fleet and run.pipeline_depth > 0
-    pipe = DispatchPipeline(run.pipeline_depth, tr)
+        engine = get_lm_engine(cfg, fleet.optimizer, fleet.spec,
+                               kernels=run.kernels, shd=shd)
+        # async dispatch pipeline (as run_simulation): overlaps host
+        # plan/pack/stage with the device scan
+        pipe = DispatchPipeline(run.pipeline_depth, tr)
 
     pending: List[Tuple[PlannedRound, Dict[str, np.ndarray]]] = []
-    # per entry: (device losses, active mask(s)) — the oracle paths queue one
-    # (N,) slice per round; the pipelined path queues the whole (H, N) chunk
-    # block with its H masks, so no per-round slice ops land on the dispatch
-    # critical path and nothing is fetched before a history boundary
-    loss_rows: List[Tuple[Any, Any]] = []
+    # per entry: one chunk's (H, N) device loss block with its H active
+    # masks, so no per-round slice ops land on the dispatch critical path
+    # and nothing is fetched before a history boundary
+    loss_rows: List[Tuple[Any, List[np.ndarray]]] = []
 
     def flush():
-        nonlocal sp, so
         plans = [p for p, _ in pending]
-        if run.resident_fleet:
+        with tr.span("pack"):
+            spans = list(chunk_spans(plans, n, min_bucket=run.min_bucket,
+                                     mesh_shards=run.mesh_shards))
+        for lo, hi, key in spans:
+            chunk = plans[lo:hi]
+            col = prefer_cols(key[0], key[2], n)
+            fuse = all(mix_is_train(p) for p in chunk)
             with tr.span("pack"):
-                spans = list(chunk_spans(plans, n,
-                                         col_sparse=run.col_sparse_mix,
-                                         min_bucket=run.min_bucket,
-                                         mesh_shards=run.mesh_shards))
-            for lo, hi, key in spans:
-                chunk = plans[lo:hi]
-                col = run.col_sparse_mix and prefer_cols(key[0], key[2], n)
-                fuse = all(mix_is_train(p) for p in chunk)
-                with tr.span("pack"):
-                    tokens = np.stack([b["tokens"]
-                                       for _, b in pending[lo:hi]])
-                    labels = np.stack([b["labels"]
-                                       for _, b in pending[lo:hi]])
-                if pipelined:
-                    fleet.pbuf, fleet.obuf, losses = engine.dispatch_chunk(
-                        fleet.pbuf, fleet.obuf, chunk, tokens, labels,
-                        col_sparse=col, fuse=fuse, min_bucket=run.min_bucket,
-                        pregather=run.host_batch_gather, key=key, trace=tr)
-                    loss_rows.append((losses, [p.active for p in chunk]))
-                    # the loss block is the non-donated output of the chunk's
-                    # executable — the in-flight token (pbuf/obuf are donated
-                    # into the next dispatch, see DispatchPipeline)
-                    pipe.submit(losses)
-                else:
-                    fleet.pbuf, fleet.obuf, losses = engine.dispatch_chunk(
-                        fleet.pbuf, fleet.obuf, chunk, tokens, labels,
-                        col_sparse=col, fuse=fuse, min_bucket=run.min_bucket,
-                        pregather=run.host_batch_gather, trace=tr)
-                    for j, p in enumerate(chunk):
-                        loss_rows.append((losses[j], p.active))
-        else:
-            for p, b in pending:
-                with tr.span("stage"):
-                    batch = {k: jnp.asarray(v) for k, v in b.items()}
-                with tr.span("enqueue"):
-                    sp = fleet_mix_stacked(sp, p.W, p.active, p.links,
-                                           kernels=run.kernels)
-                    sp, so, losses = step(sp, so, batch,
-                                          jnp.asarray(p.active))
-                count_dispatch(tr, 1, n, n, narrow_mix=WK.narrow_mix(
-                    run.kernels, p.W.shape, False))
-                loss_rows.append((losses, p.active))
+                tokens = np.stack([b["tokens"] for _, b in pending[lo:hi]])
+                labels = np.stack([b["labels"] for _, b in pending[lo:hi]])
+            fleet.pbuf, fleet.obuf, losses = engine.dispatch_chunk(
+                fleet.pbuf, fleet.obuf, chunk, tokens, labels, key=key,
+                col_sparse=col, fuse=fuse, min_bucket=run.min_bucket,
+                trace=tr)
+            loss_rows.append((losses, [p.active for p in chunk]))
+            # the loss block is the non-donated output of the chunk's
+            # executable — the in-flight token (pbuf/obuf are donated into
+            # the next dispatch, see DispatchPipeline)
+            pipe.submit(losses)
         pending.clear()
 
     def drain_losses():
         """Materialize queued per-round losses (device sync happens at eval
         boundaries only, so round dispatches stay queued in between)."""
         for losses, actives in loss_rows:
-            arr = np.asarray(losses)
-            if isinstance(actives, np.ndarray):  # per-round (oracle paths)
-                arr, actives = arr[None], [actives]
-            for row, active in zip(arr, actives):
+            for row, active in zip(np.asarray(losses), actives):
                 row = row[:len(active)]          # drop shard padding
                 hist.round_loss.append(float(row[active].mean())
                                        if active.any() else 0.0)
@@ -979,14 +767,9 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
     def save_snapshot(t: int) -> None:
         """Atomic full-state snapshot (see ``run_simulation``).  The f32
         residency buffers hold the bf16/int32 leaves losslessly, so writing
-        them is the bitwise checkpoint of the whole fleet; the oracle path
-        flattens its stacked pytrees through the same exact round-trip."""
+        them is the bitwise checkpoint of the whole fleet."""
         snap = planner.state_dict()
-        if run.resident_fleet:
-            pb, ob = fleet.pbuf, fleet.obuf
-        else:
-            pb, _ = FS.flatten_stacked(sp)
-            ob, _ = FS.flatten_stacked(so)
+        pb, ob = fleet.pbuf, fleet.obuf
         pb = pb if pb.shape[0] == n else pb[:n]
         with tr.span("drain"):
             jax.block_until_ready(pb)
@@ -998,7 +781,6 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
             "planner_rng": snap["rng_state"],
             "history": hist.to_dict(),
             "config": {"plane": "lm", "n_workers": n, "seed": run.seed,
-                       "resident_fleet": run.resident_fleet,
                        "mesh_shards": run.mesh_shards,
                        "arch": cfg.arch_id, "optimizer": run.optimizer,
                        "scenario": scen.schedule.name if scen else None},
@@ -1010,14 +792,12 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
     while planner.t < run.n_rounds:
         with tr.span("plan"):
             p = planner.plan_round()
-            if run.resident_fleet:
-                # resolve the shape-bucket key at plan time (memoized on the
-                # plan; as run_simulation) so chunk_spans only does lookups
-                bucket_key(p, n, col_sparse=run.col_sparse_mix,
-                           min_bucket=run.min_bucket,
-                           mesh_shards=run.mesh_shards)
+            # resolve the shape-bucket key at plan time (memoized on the
+            # plan; as run_simulation) so chunk_spans only does lookups
+            bucket_key(p, n, min_bucket=run.min_bucket,
+                       mesh_shards=run.mesh_shards)
         with tr.span("stream"):
-            b = next(streams)             # one draw per round, EITHER path
+            b = next(streams)             # one draw per planned round
         hist.round_durations.append(p.duration)
         hist.round_active.append(int(p.active.sum()))
         pending.append((p, b))
@@ -1025,26 +805,20 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
         do_ckpt = (run.checkpoint_every > 0
                    and p.t % run.checkpoint_every == 0)
         at_boundary = scen is not None and (p.t + 1) in scen.boundaries
-        if do_eval or do_ckpt or at_boundary or len(pending) >= horizon:
+        read_back = do_eval or do_ckpt or at_boundary
+        if read_back or len(pending) >= run.scan_horizon:
             flush()
             # read-back boundaries drain: eval / drain_losses /
             # save_snapshot must see round-consistent resident buffers
-            if pipelined and (do_eval or do_ckpt or at_boundary):
+            if read_back:
                 pipe.drain()
         if do_eval:
             with tr.span("drain"):
-                jax.block_until_ready(fleet.pbuf if run.resident_fleet
-                                      else jax.tree.leaves(sp)[0])
+                jax.block_until_ready(fleet.pbuf)
             with tr.span("eval"):
                 drain_losses()
-                if run.resident_fleet:
-                    lg = float(engine.eval_global(fleet.pbuf, alpha_eval,
-                                                  eval_tok, eval_lab))
-                else:
-                    lg = fleet_eval_stacked(
-                        cfg, sp, {"tokens": eval_tok, "labels": eval_lab,
-                                  "loss_mask": jnp.ones(eval_tok.shape,
-                                                        jnp.float32)}, alpha)
+                lg = float(engine.eval_global(fleet.pbuf, alpha_eval,
+                                              eval_tok, eval_lab))
                 hist.rounds.append(p.t)
                 hist.sim_time.append(planner.sim_clock)
                 hist.comm_gb.append(planner.comm_bytes / 1e9)
@@ -1063,9 +837,6 @@ def run_lm_federation(mechanism, cfg: ModelConfig, run: LMRunConfig,
     pipe.drain()
     with tr.span("drain"):
         drain_losses()
-    if not run.resident_fleet:
-        fleet.stacked_params = sp         # write the oracle state back once
-        fleet.stacked_opt = so
     if shd is not None and fleet.pbuf.shape[0] != n:
         fleet.pbuf = fleet.pbuf[:n]       # shed the shard padding: callers
         fleet.obuf = fleet.obuf[:n]       #   see the (N, ·) contract

@@ -1,38 +1,26 @@
-"""Double-buffered host↔device dispatch pipeline (ROADMAP item 5).
+"""Double-buffered host↔device dispatch pipeline.
 
 JAX dispatch is asynchronous: a donated ``mega_round_step`` /
 ``LMEngine._mega`` call returns immediately with futures while XLA executes
-in the background.  The lockstep drive loops never exploited that — the next
-host action after a dispatch was either another dispatch (fine) or a blocking
-read (eval, snapshot, loss drain) that serialized host planning/packing with
-device execution.  ``DispatchPipeline`` makes the overlap explicit and
-BOUNDED: the driver ``submit()``s each in-flight chunk's output arrays, and
-the pipeline blocks only when more than ``depth`` chunks are outstanding —
-so while the device executes horizon chunk H, the host plans, packs
-(``worker.pack_chunk``) and stages (one fused non-blocking
-``jax.device_put``) chunk H+1.
+in the background.  ``DispatchPipeline`` makes the overlap explicit and
+BOUNDED: the drive loop ``submit()``s each in-flight chunk's output arrays,
+and the pipeline blocks only when more than ``depth`` chunks are
+outstanding — so while the device executes horizon chunk H, the host plans,
+packs (``worker.pack_chunk``) and stages (one fused non-blocking
+``jax.device_put``) chunk H+1.  ``depth`` is at least 1; depth 1 is classic
+double buffering, the default on both planes.
 
 Values are untouched: the pipeline never reorders dispatches, and every
 read-back boundary — eval, snapshot, scenario event, end of run — calls
-``drain()`` first, so ``save_snapshot`` still reads a round-consistent buffer
-and resume stays bit-identical to the depth-0 lockstep oracle (pinned by
-tests/test_pipeline.py and scripts/chaos_check.py).  Depth semantics:
-
-  * ``depth == 0`` — lockstep: ``submit`` blocks immediately (the drive loops
-    additionally keep their original code path verbatim as the oracle);
-  * ``depth >= 1`` — up to that many chunks in flight behind the one being
-    staged (depth 1 is classic double buffering, the default on both planes).
+``drain()`` first, so ``save_snapshot`` reads a round-consistent buffer and
+resume stays bit-identical at any depth (pinned by tests/test_pipeline.py
+and scripts/chaos_check.py).
 
 Every block is a ``drain`` span of the call's ``core.trace.Trace``
 (back-pressure inside ``submit``, counted as ``backpressure_waits``, and
 boundary drains): host time spent waiting on the device, which is not the
 device's execute time — that overlaps the host's other spans, and only the
 profiler's device trace gives it.
-
-This is also the dispatch discipline a multi-host ``jax.distributed`` lane
-would keep: the planner is model-value-independent, so broadcasting
-``PlannedRound``s to per-shard hosts ahead of their device streams is the
-same submit/drain contract with the network in the middle.
 """
 from __future__ import annotations
 
@@ -61,7 +49,10 @@ class DispatchPipeline:
     """Bounded queue of in-flight device dispatches (see module docstring)."""
 
     def __init__(self, depth: int, trace):
-        self.depth = max(0, int(depth))
+        if depth < 1:
+            raise ValueError(f"DispatchPipeline depth must be >= 1, got "
+                             f"{depth}")
+        self.depth = int(depth)
         self.trace = trace
         self._inflight: deque = deque()
 

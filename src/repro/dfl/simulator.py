@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import pathlib
-import warnings
 from typing import Dict, List, Optional
 
 import jax
@@ -23,9 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import io as CIO
-from repro.core.aggregation import (apply_mixing, mixing_rows,
-                                    mixing_rows_cols, padded_rows,
-                                    prefer_cols)
+from repro.core.aggregation import (mixing_rows, mixing_rows_cols,
+                                    padded_rows, prefer_cols)
 from repro.core.planner import (HorizonPlanner, PlannedRound, bucket_key,
                                 chunk_spans, mix_is_train)
 from repro.core.scenarios import resolve_scenario
@@ -45,27 +43,32 @@ from repro.kernels.config import KernelConfig
 class SimConfig:
     """Simulation-plane configuration.
 
-    ``scan_horizon`` (fused engine only): the control plane is
-    model-value-independent, so ``core.planner.HorizonPlanner`` resolves up to
-    this many rounds of WAA/PTCA/staleness bookkeeping ahead on host and the
-    engine executes them as ONE donated ``lax.scan`` mega-dispatch
-    (``dfl.worker.mega_round_step``) — amortizing the per-round host↔device
-    dispatch that dominates steady-regime cost.  Horizons are chopped at eval
-    / history points and at the round cap, so histories are identical at any
-    horizon; ``scan_horizon=1`` dispatches per-round via ``round_step`` (the
-    PR-1 oracle path, bit-for-bit).  Ignored by the legacy per-leaf path.
+    One engine: the fleet's models live in one resident, donated (N, P)
+    buffer, and each bucket-uniform chunk of planned rounds runs as one
+    jitted dispatch of Eq. 4 mixing, on-device batch sampling and masked
+    Eq. 5 SGD over the gathered active rows (``dfl.worker.round_step`` /
+    ``mega_round_step``).  Per chunk the engine picks the row- or
+    column-sparse contraction from the bucketed shapes
+    (``aggregation.prefer_cols``) and, for the sim-plane MLP, the fused SGD
+    lowering (``worker.fused_sgd_supported``).
 
-    ``pipeline_depth`` (fused engine only): the async dispatch pipeline
-    (``dfl.pipeline.DispatchPipeline``).  Depth 0 is the original lockstep
-    drive loop, kept VERBATIM as the oracle; depth >= 1 (default 1 — double
-    buffering) dispatches each bucket-uniform chunk through the fast
-    uniform-bucket packer (``worker.pack_chunk``) and one fused non-blocking
-    ``jax.device_put`` staging call, letting the host plan/pack/stage chunk
-    H+1 while the device executes chunk H, with at most ``depth`` chunks in
-    flight.  Trajectories are bit-identical at any depth — evals, snapshots,
-    and scenario-event flushes drain the pipeline first, so every read-back
-    sees a round-consistent buffer (pinned by tests/test_pipeline.py,
-    including SIGKILL-resume via scripts/chaos_check.py).
+    ``scan_horizon``: the control plane is model-value-independent, so
+    ``core.planner.HorizonPlanner`` resolves up to this many rounds of
+    WAA/PTCA/staleness bookkeeping ahead on host and the engine executes
+    them as ONE donated ``lax.scan`` mega-dispatch — amortizing the
+    per-round host↔device dispatch that dominates steady-regime cost.
+    Horizons are chopped at eval / history points and at the round cap, so
+    histories are identical at any horizon; ``scan_horizon=1`` dispatches
+    per round via ``round_step``.
+
+    ``pipeline_depth``: the async dispatch pipeline
+    (``dfl.pipeline.DispatchPipeline``) lets the host plan, pack and stage
+    chunk H+1 while the device executes chunk H, with at most ``depth``
+    chunks in flight (1 = double buffering).  Trajectories are
+    bit-identical at any depth — evals, snapshots and scenario-event
+    flushes drain the pipeline first, so every read-back sees a
+    round-consistent buffer (pinned by tests/test_pipeline.py, including
+    SIGKILL-resume via scripts/chaos_check.py).
     """
     n_workers: int = 100
     n_rounds: int = 300               # round cap
@@ -103,10 +106,6 @@ class SimConfig:
     eval_every: int = 10
     target_accuracy: Optional[float] = None
     seed: int = 0
-    use_kernel: bool = False          # DEPRECATED alias: True maps to
-                                      #   kernels=KernelConfig(
-                                      #   backend="pallas") in __post_init__
-                                      #   (with a DeprecationWarning)
     kernels: Optional[KernelConfig] = None  # kernel-plane config (backend /
                                       #   interpret policy / block sizes);
                                       #   None = KernelConfig() = reference
@@ -117,49 +116,22 @@ class SimConfig:
                                       #   mode off-TPU — the CI oracle);
                                       #   composes with mesh_shards via
                                       #   per-shard shard_map
-    fused_engine: bool = True         # device-resident fused round engine: one
-                                      #   flat (N, P) buffer, single round_step
-                                      #   dispatch (sparse mix + on-device
-                                      #   batch sampling + masked SGD).  Off =
-                                      #   legacy per-leaf path (the
-                                      #   correctness oracle); control-plane
-                                      #   trajectories are identical either
-                                      #   way, only the batch RNG differs.
-    scan_horizon: int = 8             # fused engine: plan this many rounds
-                                      #   ahead and execute them as one
-                                      #   lax.scan mega-dispatch (see class
+    scan_horizon: int = 8             # plan this many rounds ahead and
+                                      #   execute them as one lax.scan
+                                      #   mega-dispatch (see class
                                       #   docstring); 1 = per-round dispatch
-    pipeline_depth: int = 1           # fused engine: max chunks in flight on
-                                      #   the async dispatch pipeline (see
-                                      #   class docstring).  0 = the lockstep
-                                      #   oracle path; 1 (default) = double-
-                                      #   buffered host/device overlap.
-                                      #   Bit-identical trajectories at any
-                                      #   depth
-    col_sparse_mix: bool = True       # fused engine: contract Eq. 4 over the
-                                      #   gathered union of nonzero mixing
-                                      #   COLUMNS — (k, u) @ (u, P) with
-                                      #   u <= k*(max_neighbors+1) — instead
-                                      #   of the row-sparse (k, N) @ (N, P).
-                                      #   Off = PR 2 row-sparse oracle path;
-                                      #   control-plane trajectories are
-                                      #   identical either way
-    fused_local_sgd: bool = True      # fused engine: unrolled manual-backward
-                                      #   multi-step SGD lowering (one fused
-                                      #   jit region over the gathered active
-                                      #   rows) instead of the per-step AD
-                                      #   lax.scan.  Off = AD oracle; only
-                                      #   f32 rounding differs.  Auto-falls
-                                      #   back to the AD path for non-MLP
-                                      #   specs
-    mesh_shards: int = 1              # fused engine: partition the resident
+    pipeline_depth: int = 1           # max chunks in flight on the async
+                                      #   dispatch pipeline (see class
+                                      #   docstring); >= 1.  Bit-identical
+                                      #   trajectories at any depth
+    mesh_shards: int = 1              # partition the resident
                                       #   (N, P) buffer + dataset row-wise
                                       #   over a 1-D device mesh
                                       #   (launch.mesh.make_fleet_mesh); the
                                       #   worker axis pads to a shard
                                       #   multiple with permanently-idle
-                                      #   rows.  1 = single-device engine
-                                      #   (the bit-exact oracle); >1 needs
+                                      #   rows.  1 = single-device engine;
+                                      #   >1 needs
                                       #   that many jax devices (CPU: set
                                       #   XLA_FLAGS=--xla_force_host_
                                       #   platform_device_count=K); both
@@ -169,7 +141,7 @@ class SimConfig:
                                       #   bit-identical at any shard count;
                                       #   learning curves agree to f32
                                       #   reduction-order tolerance
-    min_bucket: int = 8               # fused engine: smallest power-of-two
+    min_bucket: int = 8               # smallest power-of-two
                                       #   shape bucket for gathered-row /
                                       #   column-union padding (the per-plane
                                       #   knob — the LM plane's small fleets
@@ -187,8 +159,8 @@ class SimConfig:
                                       #   "blackout", "straggler_tail",
                                       #   "mobile"), or a ScenarioSchedule.
                                       #   Overlays are rng-free, so a scenario
-                                      #   replays bit-identically on every
-                                      #   engine path and shard count
+                                      #   replays bit-identically at every
+                                      #   horizon, depth and shard count
     checkpoint_every: int = 0         # rounds between atomic snapshots
                                       #   (checkpoint/io); 0 = off.  Snapshot
                                       #   rounds force a chunk flush in EVERY
@@ -214,14 +186,11 @@ class SimConfig:
                                  f"non-positive value makes Eq. 7-9 round "
                                  f"durations meaningless")
         for f in ("n_workers", "n_rounds", "batch_size", "local_steps",
-                  "eval_every", "scan_horizon", "mesh_shards", "min_bucket"):
+                  "eval_every", "scan_horizon", "pipeline_depth",
+                  "mesh_shards", "min_bucket"):
             v = getattr(self, f)
             if v < 1:
                 raise ValueError(f"SimConfig.{f} must be >= 1, got {v}")
-        if self.pipeline_depth < 0:
-            raise ValueError(f"SimConfig.pipeline_depth must be >= 0 "
-                             f"(0 = lockstep oracle), got "
-                             f"{self.pipeline_depth}")
         if self.checkpoint_every < 0:
             raise ValueError(f"SimConfig.checkpoint_every must be >= 0 "
                              f"(0 disables snapshots), got "
@@ -236,19 +205,6 @@ class SimConfig:
                 f"SimConfig.kernels must be a kernels.config.KernelConfig "
                 f"(or None for the reference default), got "
                 f"{type(self.kernels).__name__}")
-        if self.use_kernel:
-            warnings.warn(
-                "SimConfig.use_kernel is deprecated; pass "
-                "kernels=KernelConfig(backend='pallas') instead",
-                DeprecationWarning, stacklevel=2)
-            if self.kernels is None:
-                self.kernels = KernelConfig(backend="pallas")
-            elif not self.kernels.use_pallas:
-                raise ValueError(
-                    "SimConfig.use_kernel=True conflicts with "
-                    "kernels=KernelConfig(backend='reference') — drop the "
-                    "deprecated flag and select the backend on KernelConfig "
-                    "alone")
         if self.kernels is None:
             self.kernels = KernelConfig()
         self.kernels.check_executable("SimConfig.kernels")
@@ -361,54 +317,41 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
             jax.tree.map(lambda l: l[0], stacked)) * cfg.model_bytes_scale
         exp_link_time = net.expected_link_time(model_bytes)
 
-        # batch sampling draws from a dedicated stream so the control-plane
-        # rng trajectory (mechanism decisions, channels, failures) is
-        # identical between the fused engine (jax.random on device) and the
-        # legacy path (numpy on host) — histories stay comparable
-        # metric-for-metric
-        batch_rng = np.random.default_rng(cfg.seed + 0x5EED)
         batch_key = jax.random.PRNGKey(cfg.seed + 0x5EED)
+        buf, flat_spec = FS.flatten_stacked(stacked)
+        del stacked                     # the flat buffer IS the storage
+        data_x = jnp.asarray(data.x)    # device-resident dataset
+        data_y = jnp.asarray(data.y)
+        max_part = max(len(p) for p in parts)
+        part_idx = np.zeros((cfg.n_workers, max_part), np.int32)
+        for i, p in enumerate(parts):
+            part_idx[i, :len(p)] = p    # padding never sampled
+        # (uniform draws < the true size)
+        part_sizes = data_sizes.astype(np.int32)
         shd = None
         if cfg.mesh_shards > 1:
-            if not cfg.fused_engine:
-                raise ValueError(
-                    "mesh_shards > 1 requires the fused engine "
-                    "(fused_engine=True): the legacy per-leaf path has no "
-                    "resident buffer to shard")
             from repro.sharding.rules import FleetSharding
             shd = FleetSharding.create(cfg.mesh_shards)
-        if cfg.fused_engine:
-            buf, flat_spec = FS.flatten_stacked(stacked)
-            stacked = None                  # the flat buffer IS the storage
-            data_x = jnp.asarray(data.x)    # device-resident dataset
-            data_y = jnp.asarray(data.y)
-            max_part = max(len(p) for p in parts)
-            part_idx = np.zeros((cfg.n_workers, max_part), np.int32)
-            for i, p in enumerate(parts):
-                part_idx[i, :len(p)] = p    # padding never sampled
-            # (uniform draws < the true size)
-            part_sizes = data_sizes.astype(np.int32)
-            if shd is not None:
-                # pad the worker axis to a shard multiple (jax
-                # NamedShardings need even splits); padding rows are
-                # permanently idle — never activated, mixed, or evaluated —
-                # so zeros are fine.  The resident dataset partitions
-                # row-wise across the mesh too (sample padding is never
-                # indexed: part_idx holds real ids only)
-                row_pad = shd.pad(cfg.n_workers)
-                if row_pad:
-                    part_idx = np.pad(part_idx, ((0, row_pad), (0, 0)))
-                    part_sizes = np.pad(part_sizes, (0, row_pad),
-                                        constant_values=1)
-                buf = shd.put_rows_padded(buf)
-                data_x = shd.put_rows_padded(data_x)
-                data_y = shd.put_rows_padded(data_y)
-                part_idx = shd.put_rows(jnp.asarray(part_idx))
-                part_sizes = shd.put_rows(jnp.asarray(part_sizes))
-                batch_key = shd.put(batch_key)
-            else:
-                part_idx = jnp.asarray(part_idx)
-                part_sizes = jnp.asarray(part_sizes)
+            # pad the worker axis to a shard multiple (jax NamedShardings
+            # need even splits); padding rows are permanently idle — never
+            # activated, mixed, or evaluated — so zeros are fine.  The
+            # resident dataset partitions row-wise across the mesh too
+            # (sample padding is never indexed: part_idx holds real ids
+            # only)
+            row_pad = shd.pad(cfg.n_workers)
+            if row_pad:
+                part_idx = np.pad(part_idx, ((0, row_pad), (0, 0)))
+                part_sizes = np.pad(part_sizes, (0, row_pad),
+                                    constant_values=1)
+            buf = shd.put_rows_padded(buf)
+            data_x = shd.put_rows_padded(data_x)
+            data_y = shd.put_rows_padded(data_y)
+            part_idx = shd.put_rows(jnp.asarray(part_idx))
+            part_sizes = shd.put_rows(jnp.asarray(part_sizes))
+            batch_key = shd.put(batch_key)
+        else:
+            part_idx = jnp.asarray(part_idx)
+            part_sizes = jnp.asarray(part_sizes)
 
         # --- control plane: the horizon planner owns all mutable control
         # state (staleness, pull counts, readiness clocks, failure mask, sim
@@ -438,8 +381,8 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         # --- crash-safe resume: overwrite the deterministic setup's mutable
         # state with the snapshot.  Setup above consumed the exact same rng
         # draws as the original run's setup, so only the planner state, the
-        # model rows, the (legacy) batch stream, and the history need
-        # restoring.  The history's host times stay this call's own.
+        # model rows and the history need restoring.  The history's host
+        # times stay this call's own.
         if resume_from is not None:
             ck = pathlib.Path(resume_from)
             if ck.is_dir():
@@ -451,18 +394,13 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
                 ck = found
             arr_tmpl = {k: np.zeros_like(v)
                         for k, v in planner.state_dict()["arrays"].items()}
-            if cfg.fused_engine:
-                n_params = int(buf.shape[1])
-                model_tmpl = {"buf": np.zeros((cfg.n_workers, n_params),
-                                              np.float32)}
-                model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl,
-                                                           arr_tmpl)
-            else:
-                model, arrays, extra = CIO.load_checkpoint(ck, stacked,
-                                                           arr_tmpl)
+            model_tmpl = {"buf": np.zeros((cfg.n_workers, int(buf.shape[1])),
+                                          np.float32)}
+            model, arrays, extra = CIO.load_checkpoint(ck, model_tmpl,
+                                                       arr_tmpl)
             saved_cfg = extra.get("config", {})
-            for k in ("plane", "n_workers", "seed", "fused_engine",
-                      "mesh_shards", "scenario"):
+            for k in ("plane", "n_workers", "seed", "mesh_shards",
+                      "scenario"):
                 want = {"plane": "sim",
                         "scenario": scen.schedule.name if scen else None
                         }.get(k, getattr(cfg, k, None))
@@ -475,162 +413,44 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
             planner.load_state({"arrays": arrays,
                                 "scalars": extra["planner_scalars"],
                                 "rng_state": extra["planner_rng"]})
-            if cfg.fused_engine:
-                restored = jnp.asarray(model["buf"])
-                # rebuild the padded+sharded residency exactly as first init
-                buf = (shd.put_rows_padded(restored) if shd is not None
-                       else restored)
-            else:
-                stacked = model
-                batch_rng.bit_generator.state = extra["batch_rng"]
+            restored = jnp.asarray(model["buf"])
+            # rebuild the padded+sharded residency exactly as first init
+            buf = (shd.put_rows_padded(restored) if shd is not None
+                   else restored)
             for k, v in extra["history"].items():
                 if hasattr(hist, k) and not k.endswith("wall_s"):
                     setattr(hist, k, v)
-        horizon = max(1, cfg.scan_horizon) if cfg.fused_engine else 1
         # the fused SGD lowering hand-differentiates the sim-plane MLP; any
         # other architecture plugged into the flat buffer falls back to the
         # AD scan
-        fused_sgd = (cfg.fused_engine and cfg.fused_local_sgd
-                     and WK.fused_sgd_supported(flat_spec))
-        # async dispatch pipeline (ROADMAP item 5): depth >= 1 overlaps host
-        # plan/pack/stage with device execution, bounded at `depth` chunks in
-        # flight; depth 0 keeps the original lockstep flush() (the oracle)
-        pipelined = cfg.fused_engine and cfg.pipeline_depth > 0
+        fused_sgd = WK.fused_sgd_supported(flat_spec)
         pipe = DispatchPipeline(cfg.pipeline_depth, tr)
-
-    def use_cols(key):
-        """Column-sparse contraction for a chunk with these shape buckets?
-        The per-chunk traffic model (``aggregation.prefer_cols``) picks the
-        cheaper contraction from the bucketed (k_mix, u) shapes actually
-        dispatched — subsuming the old binary u = N fallback, so the column
-        path is never a pessimization."""
-        return cfg.col_sparse_mix and prefer_cols(key[0], key[2],
-                                                  cfg.n_workers)
+        put = shd.put if shd is not None else None
+        n_rows = cfg.n_workers + (shd.pad(cfg.n_workers) if shd else 0)
 
     def flush(plans):
         """Dispatch the pending planned rounds to the model plane (Eq. 4+5).
 
-        Fused path: consecutive rounds sharing one shape-bucket key
+        Consecutive rounds sharing one shape-bucket key
         (``core.planner.bucket_key``) go out as one ``lax.scan`` mega-round;
         ``core.planner.chunk_spans`` splits at bucket changes rather than
         padding to the horizon max, so no round ever pays a larger bucket
-        than its own single-dispatch shape (in the steady regime buckets
-        rarely change, so chunks stay horizon-length).
-        """
-        nonlocal buf, stacked
-        if cfg.fused_engine:
-            put = shd.put if shd is not None else jnp.asarray
-            n_rows = cfg.n_workers + (shd.pad(cfg.n_workers) if shd else 0)
-            with tr.span("pack"):
-                spans = list(chunk_spans(plans, cfg.n_workers,
-                                         col_sparse=cfg.col_sparse_mix,
-                                         min_bucket=cfg.min_bucket,
-                                         mesh_shards=cfg.mesh_shards))
-            for lo, hi, key in spans:
-                chunk = plans[lo:hi]
-                col = use_cols(key)
-                if len(chunk) > 1:
-                    with tr.span("pack"):
-                        w_rows_h, ctrl_h, ts = WK.pack_horizon(
-                            chunk, min_bucket=cfg.min_bucket, col_sparse=col,
-                            shards=cfg.mesh_shards)
-                        if not col:
-                            w_rows_h = WK.pad_w_cols(w_rows_h, n_rows)
-                    with tr.span("stage"):
-                        w_j, c_j, ts_j = put(w_rows_h), put(ctrl_h), put(ts)
-                    with tr.span("enqueue"):
-                        buf, _ = WK.mega_round_step(
-                            buf, w_j, c_j, ts_j, data_x, data_y, part_idx,
-                            part_sizes, batch_key, spec=flat_spec, lr=cfg.lr,
-                            local_steps=cfg.local_steps,
-                            batch_size=cfg.batch_size, kernels=cfg.kernels,
-                            col_sparse=col, fused_sgd=fused_sgd,
-                            with_losses=False,
-                            mix_is_train=(fused_sgd
-                                          and all(mix_is_train(p)
-                                                  for p in chunk)),
-                            shd=shd)
-                    count_dispatch(tr, len(chunk), key[0], key[1],
-                                   w_rows_h.nbytes + ctrl_h.nbytes
-                                   + ts.nbytes,
-                                   WK.narrow_mix(cfg.kernels, w_rows_h.shape,
-                                                 col, cfg.mesh_shards))
-                    continue
-                # single-round path: one donated round_step dispatch; with
-                # col_sparse_mix/fused_local_sgd off this is bit-for-bit the
-                # pre-horizon PR 1 engine (the correctness oracle)
-                p = chunk[0]
-                with tr.span("pack"):
-                    if col:
-                        w_rows, mix_ids, col_ids = mixing_rows_cols(
-                            p.W, p.active, p.links, cols_mask=p.mix_cols,
-                            min_bucket=cfg.min_bucket,
-                            shards=cfg.mesh_shards)
-                    else:
-                        w_rows, mix_ids = mixing_rows(
-                            p.W, p.active, p.links,
-                            min_bucket=cfg.min_bucket,
-                            shards=cfg.mesh_shards)
-                        w_rows = WK.pad_w_cols(w_rows, n_rows)
-                        col_ids = None
-                    train_ids, train_mask = padded_rows(
-                        p.active, min_bucket=cfg.min_bucket,
-                        shards=cfg.mesh_shards)
-                    ctrl = WK.pack_round_ctrl(mix_ids, train_ids, train_mask,
-                                              col_ids=col_ids)
-                with tr.span("stage"):
-                    w_j, c_j = put(w_rows), put(ctrl)
-                with tr.span("enqueue"):
-                    buf, _ = WK.round_step(
-                        buf, w_j, c_j,
-                        data_x, data_y, part_idx, part_sizes, batch_key,
-                        np.int32(p.t), spec=flat_spec, lr=cfg.lr,
-                        local_steps=cfg.local_steps,
-                        batch_size=cfg.batch_size, kernels=cfg.kernels,
-                        col_sparse=col, fused_sgd=fused_sgd,
-                        with_losses=False,
-                        mix_is_train=fused_sgd and mix_is_train(p), shd=shd)
-                count_dispatch(tr, 1, key[0], key[1],
-                               w_rows.nbytes + ctrl.nbytes,
-                               WK.narrow_mix(cfg.kernels, w_rows.shape, col,
-                                             cfg.mesh_shards))
-        else:
-            for p in plans:
-                with tr.span("pack"):
-                    xb, yb = _sample_batches(parts, data, cfg, batch_rng)
-                with tr.span("enqueue"):
-                    stacked = apply_mixing(jnp.asarray(p.W), stacked,
-                                           kernels=cfg.kernels)
-                    stacked, _ = WK.local_train(stacked, xb, yb,
-                                                jnp.asarray(p.active),
-                                                lr=cfg.lr,
-                                                local_steps=cfg.local_steps)
-                count_dispatch(tr, 1, cfg.n_workers, cfg.n_workers,
-                               narrow_mix=WK.narrow_mix(
-                                   cfg.kernels, p.W.shape, False))
-
-    def flush_pipelined(plans):
-        """The depth >= 1 twin of ``flush``: identical dispatches (same
-        chunk splits, same jitted step functions, same values — pinned
-        bit-identical by tests/test_pipeline.py), different host schedule.
-        Three host-side cuts keep the critical path short so the device
-        never waits on packing: the uniform-bucket fast packer
-        (``worker.pack_chunk``, using the planner-resolved ``mix_rows``),
-        ONE fused non-blocking ``jax.device_put`` per chunk instead of three
-        ``jnp.asarray`` round-trips, and no implicit block — ``pipe.submit``
-        bounds the in-flight chunks and the drive loop drains only at
-        read-back boundaries."""
+        than its own single-dispatch shape.  Per chunk the traffic model
+        (``aggregation.prefer_cols``) picks the row- or column-sparse
+        contraction from the bucketed (k_mix, u) shapes.  The host side is
+        kept short so the device never waits on packing: the uniform-bucket
+        packer (``worker.pack_chunk``, using the planner-resolved
+        ``mix_rows``), ONE fused non-blocking ``jax.device_put`` per chunk,
+        and no implicit block — ``pipe.submit`` bounds the in-flight chunks
+        and the drive loop drains only at read-back boundaries."""
         nonlocal buf
-        put = shd.put if shd is not None else None
-        n_rows = cfg.n_workers + (shd.pad(cfg.n_workers) if shd else 0)
         with tr.span("pack"):
             spans = list(chunk_spans(plans, cfg.n_workers,
-                                     col_sparse=cfg.col_sparse_mix,
                                      min_bucket=cfg.min_bucket,
                                      mesh_shards=cfg.mesh_shards))
         for lo, hi, key in spans:
             chunk = plans[lo:hi]
-            col = use_cols(key)
+            col = prefer_cols(key[0], key[2], cfg.n_workers)
             if len(chunk) > 1:
                 with tr.span("pack"):
                     host = WK.pack_chunk(
@@ -694,31 +514,24 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
 
     def save_snapshot(t: int) -> None:
         """Atomic full-state snapshot: model rows + complete planner control
-        state + rng streams + history.  Called only at flush boundaries, so
-        the device buffer is round-consistent when read back to host."""
+        state + rng stream + history.  Called only at drained flush
+        boundaries, so the buffer is round-consistent when read back."""
         snap = planner.state_dict()
-        if cfg.fused_engine:
-            view = buf if buf.shape[0] == cfg.n_workers \
-                else buf[:cfg.n_workers]
-            with tr.span("drain"):
-                jax.block_until_ready(view)
-            model = {"buf": np.asarray(view)}
-        else:
-            model = stacked
+        view = buf if buf.shape[0] == cfg.n_workers else buf[:cfg.n_workers]
+        with tr.span("drain"):
+            jax.block_until_ready(view)
         extra = {
             "round": t,
             "planner_scalars": snap["scalars"],
             "planner_rng": snap["rng_state"],
             "history": hist.to_dict(),
             "config": {"plane": "sim", "n_workers": cfg.n_workers,
-                       "seed": cfg.seed, "fused_engine": cfg.fused_engine,
-                       "mesh_shards": cfg.mesh_shards,
+                       "seed": cfg.seed, "mesh_shards": cfg.mesh_shards,
                        "scenario": scen.schedule.name if scen else None},
         }
-        if not cfg.fused_engine:
-            extra["batch_rng"] = batch_rng.bit_generator.state
         CIO.save_checkpoint(CIO.checkpoint_path(cfg.checkpoint_dir, t),
-                            model, opt_state=snap["arrays"], extra=extra)
+                            {"buf": np.asarray(view)},
+                            opt_state=snap["arrays"], extra=extra)
         CIO.prune_checkpoints(cfg.checkpoint_dir, cfg.checkpoint_keep)
 
     pending: list[PlannedRound] = []
@@ -726,15 +539,12 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
     while planner.t < cfg.n_rounds and not stop:
         with tr.span("plan"):
             p = planner.plan_round()
-            if cfg.fused_engine:
-                # resolve the round's shape-bucket key at plan time
-                # (memoized on the plan, every depth): dispatch-path
-                # chunk_spans then only does lookups — bucketing is
-                # control-plane work and belongs with the planner, not on
-                # the dispatch critical path
-                bucket_key(p, cfg.n_workers, col_sparse=cfg.col_sparse_mix,
-                           min_bucket=cfg.min_bucket,
-                           mesh_shards=cfg.mesh_shards)
+            # resolve the round's shape-bucket key at plan time (memoized on
+            # the plan): chunk_spans then only does lookups — bucketing is
+            # control-plane work and belongs with the planner, not on the
+            # dispatch critical path
+            bucket_key(p, cfg.n_workers, min_bucket=cfg.min_bucket,
+                       mesh_shards=cfg.mesh_shards)
         t = p.t
         sim_clock = planner.sim_clock
         hist.round_durations.append(p.duration)
@@ -764,38 +574,33 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
         # per-round and chunk splits are bit-exact anyway)
         do_ckpt = cfg.checkpoint_every > 0 and t % cfg.checkpoint_every == 0
         at_boundary = scen is not None and (t + 1) in scen.boundaries
-        if (do_eval or stop or t == cfg.n_rounds or do_ckpt or at_boundary
-                or len(pending) >= horizon):
-            (flush_pipelined if pipelined else flush)(pending)
+        read_back = (do_eval or stop or t == cfg.n_rounds or do_ckpt
+                     or at_boundary)
+        if read_back or len(pending) >= cfg.scan_horizon:
+            flush(pending)
             pending = []
             # read-back boundaries drain the pipeline: eval and
             # save_snapshot must see a round-consistent buffer, and a
             # scenario-event flush keeps host plan-ahead from racing past
             # the fault-phase change it just chopped the chunk for
-            if pipelined and (do_eval or stop or do_ckpt or at_boundary
-                              or t == cfg.n_rounds):
+            if read_back:
                 pipe.drain()
         if do_eval:
             # drain queued round dispatches first so their device time is
             # charged to the rounds, not to the eval
             with tr.span("drain"):
-                jax.block_until_ready(buf if cfg.fused_engine else stacked)
+                jax.block_until_ready(buf)
             with tr.span("eval"):
-                if cfg.fused_engine:
-                    # flat-native eval: Eq. 11 global model is one alpha @ buf
-                    # matvec; no stacked pytree is materialized.  A padded
-                    # sharded buffer evals its first N rows only (padding rows
-                    # are idle replicas of w_0 and must not enter the means)
-                    view = buf if buf.shape[0] == cfg.n_workers \
-                        else buf[:cfg.n_workers]
-                    accg, lossg = WK.evaluate_global_flat(
-                        view, alpha, x_test, y_test, spec=flat_spec)
-                    accl, _ = WK.evaluate_stacked_flat(view, x_test, y_test,
-                                                       spec=flat_spec)
-                else:
-                    accg, lossg = WK.evaluate_global(stacked, alpha, x_test,
-                                                     y_test)
-                    accl, _ = WK.evaluate_stacked(stacked, x_test, y_test)
+                # flat-native eval: Eq. 11 global model is one alpha @ buf
+                # matvec; no stacked pytree is materialized.  A padded
+                # sharded buffer evals its first N rows only (padding rows
+                # are idle replicas of w_0 and must not enter the means)
+                view = buf if buf.shape[0] == cfg.n_workers \
+                    else buf[:cfg.n_workers]
+                accg, lossg = WK.evaluate_global_flat(
+                    view, alpha, x_test, y_test, spec=flat_spec)
+                accl, _ = WK.evaluate_stacked_flat(view, x_test, y_test,
+                                                   spec=flat_spec)
                 hist.rounds.append(t)
                 hist.sim_time.append(sim_clock)
                 hist.comm_gb.append(planner.comm_bytes / 1e9)
@@ -820,16 +625,3 @@ def run_simulation(mechanism: Mechanism, cfg: SimConfig,
     if bound_log is not None:
         hist.bound_log = bound_log  # type: ignore[attr-defined]
     return hist
-
-
-def _sample_batches(parts, data: ClassificationData, cfg: SimConfig,
-                    rng: np.random.Generator):
-    """Per-worker minibatches: (N, local_steps, batch, dim) / (N, steps, batch)."""
-    n = cfg.n_workers
-    xb = np.empty((n, cfg.local_steps, cfg.batch_size, data.x.shape[1]), np.float32)
-    yb = np.empty((n, cfg.local_steps, cfg.batch_size), np.int32)
-    for i in range(n):
-        idx = rng.choice(parts[i], size=(cfg.local_steps, cfg.batch_size))
-        xb[i] = data.x[idx]
-        yb[i] = data.y[idx]
-    return jnp.asarray(xb), jnp.asarray(yb)

@@ -13,12 +13,14 @@ donated jit — one dispatch per simulated round instead of per-leaf mixing +
 a host sampling loop + a separate train dispatch.  ``mega_round_step``
 executes a whole planned horizon as one ``lax.scan``.
 
-Default hot paths (each with a flag-gated slower oracle):
-  * column-sparse mixing — Eq. 4 contracts (k, u) @ (u, P) over the gathered
-    union of nonzero columns (``mix_flat_cols``; oracle ``mix_flat``);
-  * fused local-steps SGD — Eq. 5 as one unrolled manual-backward jit region
-    over the gathered active rows (``local_sgd_flat_fused``; oracle
-    ``local_sgd_flat``, the per-step AD scan).
+Lowerings the drive loops select from what they observe:
+  * Eq. 4 contracts either the (k, N) row-sparse rows (``mix_flat``) or the
+    (k, u) @ (u, P) gathered union of nonzero columns (``mix_flat_cols``),
+    per chunk by the ``aggregation.prefer_cols`` traffic model;
+  * Eq. 5 runs as one unrolled manual-backward jit region over the gathered
+    active rows (``local_sgd_flat_fused``) for the sim-plane MLP
+    (``fused_sgd_supported``), and as the per-step AD scan
+    (``local_sgd_flat``) for any other spec.
 
 Mesh-sharded fleet: every dispatch takes an optional static ``shd``
 (``sharding.rules.FleetSharding``).  When set, the (N_pad, P) buffer is
@@ -463,9 +465,9 @@ def _mix_train_body(buf: jnp.ndarray, w_rows: jnp.ndarray,
     a round, shared by ``round_step`` and ``mega_round_step``'s scan body
     (batch sampling is buffer-INdependent, so the mega path hoists it out of
     the scan and draws the whole horizon in one batched op).  ``col_ids``
-    non-None selects the column-sparse contraction; ``fused_sgd`` the
-    unrolled manual-backward SGD lowering (both default-on hot paths, with
-    ``mix_flat``/``local_sgd_flat`` as the flag-gated oracles).
+    non-None selects the column-sparse contraction (else ``mix_flat``);
+    ``fused_sgd`` the unrolled manual-backward SGD lowering (else the AD
+    scan ``local_sgd_flat``).
 
     ``mix_is_train`` (host-verified: the mix row ids EQUAL the train row
     ids, as in every DySTop round — activated workers are exactly the
@@ -675,16 +677,14 @@ def pack_chunk(plans, key, *, min_bucket: int = 8, col_sparse: bool = False,
                shards: int = 1) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """``pack_horizon`` specialized to a bucket-uniform ``chunk_spans`` chunk.
 
-    The pipelined dispatcher's packer: every plan in a chunk shares the
+    The drive loops' packer: every plan in a chunk shares the
     ``bucket_key`` triple ``key`` by construction, so the per-plan bucket
     re-derivation (``plan_buckets`` + column-union counting) and the
     general-purpose gather helpers collapse into one direct loop — the padded
     shapes are ``key`` itself.  Uses ``PlannedRound.mix_rows`` (the
     non-identity row ids the planner already resolved) when present.  Output
     is BIT-IDENTICAL to ``pack_horizon`` on the same chunk (pinned by
-    tests/test_pipeline.py) at roughly half the host cost — this packer plus
-    the single fused ``jax.device_put`` staging is where the pipelined
-    dispatch path buys its host-side headroom.
+    tests/test_pipeline.py) at roughly half the host cost.
 
     Falls back to ``pack_horizon`` verbatim for the cases the fast loop does
     not specialize: sharded padding layouts (``shards > 1``), all-idle chunks
@@ -696,7 +696,7 @@ def pack_chunk(plans, key, *, min_bucket: int = 8, col_sparse: bool = False,
 
     n = plans[0].W.shape[0]
     k_mix, k_train = int(key[0]), int(key[1])
-    u = int(key[2]) if col_sparse and len(key) > 2 else 0
+    u = int(key[2]) if col_sparse else 0
     if shards > 1 or k_mix == 0 or (col_sparse and u >= n):
         return pack_horizon(plans, min_bucket=min_bucket,
                             col_sparse=col_sparse, shards=shards)

@@ -1,13 +1,10 @@
 """One frozen, hashable config for the whole kernel plane.
 
-``KernelConfig`` replaces the kernel knobs that used to be scattered across
-the engines — the ``use_kernel: bool`` threaded positionally through
-``round_step``/``mega_round_step``/``LMEngine``, the implicit
-backend-sniffing interpret default buried in ``kernels/aggregate.py``, and
-per-call ``p_blk``/``blk_q``/``blk_t`` block sizes.  It is a frozen
-dataclass of hashable scalars, so ONE object rides through ``jax.jit``
-static arguments, engine cache keys, and ``ModelConfig`` (the zoo forward
-passes read ``cfg.kernels``) on both DFL planes plus serving.
+``KernelConfig`` holds the kernel backend, the interpret policy and the
+per-kernel block sizes.  It is a frozen dataclass of hashable scalars, so
+ONE object rides through ``jax.jit`` static arguments, engine cache keys,
+and ``ModelConfig`` (the zoo forward passes read ``cfg.kernels``) on both
+DFL planes plus serving.
 
 Pure stdlib + jax import — safe to import from ``configs.base`` without
 cycles (nothing here touches models, engines, or the kernel modules).
@@ -107,8 +104,3 @@ class KernelConfig:
                 f"compiled Mosaic lowering, but the active jax backend is "
                 f"{jax.default_backend()!r} — use interpret='auto' "
                 f"(interpret off-TPU, compiled on TPU) or True")
-
-
-def from_use_kernel(use_kernel: bool) -> KernelConfig:
-    """The deprecated ``use_kernel`` boolean's exact modern equivalent."""
-    return KernelConfig(backend="pallas" if use_kernel else "reference")

@@ -203,11 +203,6 @@ def test_apply_mixing_kernel_equals_matmul():
     out_j = apply_mixing(W, tree)
     for k in tree:
         np.testing.assert_allclose(out_k[k], out_j[k], rtol=1e-5, atol=1e-5)
-    # deprecated boolean still routes (and warns)
-    with pytest.warns(DeprecationWarning):
-        out_d = apply_mixing(W, tree, use_kernel=True)
-    for k in tree:
-        np.testing.assert_array_equal(out_d[k], out_k[k])
 
 
 # --------------------------------------------------------------------------- #

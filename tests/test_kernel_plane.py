@@ -8,14 +8,13 @@ moves behind it.
      in interpret mode — the CI oracle for the TPU claim;
   3. ``backend="pallas"`` composes with ``mesh_shards`` ∈ {1, 2, 8}:
      control plane bit-exact, curves to f32 tolerance (multidevice lane);
-  4. the deprecated ``use_kernel`` aliases map onto ``KernelConfig`` and
-     keep producing identical trajectories.
+  4. both engine configs default to the reference ``KernelConfig`` and
+     refuse anything else in its place.
 
 Everything here runs interpret-mode Pallas on CPU; see docs/BENCHMARKS.md
 for the CPU-parity-vs-TPU claim policy.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +27,7 @@ from repro.dfl import worker as WK
 from repro.dfl.simulator import SimConfig, run_simulation
 from repro.kernels import fused_sgd as FSGD
 from repro.kernels import ops as K
-from repro.kernels.config import KernelConfig, from_use_kernel
+from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 
 
@@ -70,13 +69,6 @@ def test_kernel_config_validation():
     with pytest.raises(ValueError, match="TPU"):
         KernelConfig(backend="pallas",
                      interpret=False).check_executable("here")
-
-
-def test_from_use_kernel_mapping():
-    assert from_use_kernel(True) == KernelConfig(backend="pallas")
-    assert from_use_kernel(False) == KernelConfig()
-    assert from_use_kernel(True).use_pallas
-    assert not from_use_kernel(False).use_pallas
 
 
 # --------------------------------------------------------------------------- #
@@ -229,7 +221,7 @@ def test_lm_pallas_composes_with_mesh():
     from repro.dfl import lm_worker as LW
     cfg = R.get_smoke_config("smollm-135m")
     kw = dict(n_workers=4, n_rounds=4, batch=2, seq=16, seed=1, eval_every=2,
-              resident_fleet=True, kernels=KernelConfig(backend="pallas"))
+              kernels=KernelConfig(backend="pallas"))
     mech = lambda: DySTop(V=3.0, t_thre=3, max_neighbors=3)
     _, h1 = LW.run_lm_federation(mech(), cfg, LW.LMRunConfig(**kw))
     _, hs = LW.run_lm_federation(mech(), cfg,
@@ -263,7 +255,7 @@ def test_sharded_panel_kernels_match_dense(shards):
 
 
 # --------------------------------------------------------------------------- #
-# 4. deprecation aliases
+# 4. the engine configs' kernel field
 # --------------------------------------------------------------------------- #
 
 
@@ -274,38 +266,12 @@ def _sim_kw(**kw):
     return base
 
 
-def test_sim_use_kernel_alias_maps_and_warns():
-    with pytest.warns(DeprecationWarning, match="use_kernel"):
-        cfg = SimConfig(**_sim_kw(use_kernel=True))
-    assert cfg.kernels == KernelConfig(backend="pallas")
-    cfg2 = SimConfig(**_sim_kw())
-    assert cfg2.kernels == KernelConfig()
-    with pytest.raises(ValueError, match="conflicts"):
-        with pytest.warns(DeprecationWarning):
-            SimConfig(**_sim_kw(use_kernel=True, kernels=KernelConfig()))
-    with pytest.raises(ValueError, match="KernelConfig"):
-        SimConfig(**_sim_kw(kernels="pallas"))
-
-
-def test_lm_use_kernel_alias_maps_and_warns():
+@pytest.mark.parametrize("plane", ["sim", "lm"])
+def test_engine_config_kernels_default_and_type_check(plane):
     from repro.dfl.lm_worker import LMRunConfig
-    with pytest.warns(DeprecationWarning, match="use_kernel"):
-        run = LMRunConfig(n_workers=4, n_rounds=2, use_kernel=True)
-    assert run.kernels == KernelConfig(backend="pallas")
-    with pytest.raises(ValueError, match="conflicts"):
-        with pytest.warns(DeprecationWarning):
-            LMRunConfig(n_workers=4, n_rounds=2, use_kernel=True,
-                        kernels=KernelConfig())
-
-
-def test_sim_alias_trajectory_identical():
-    mech = lambda: DySTop(V=10.0, t_thre=10, max_neighbors=5)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        h_alias = run_simulation(mech(), SimConfig(**_sim_kw(
-            use_kernel=True)))
-    h_new = run_simulation(mech(), SimConfig(**_sim_kw(
-        kernels=KernelConfig(backend="pallas"))))
-    assert h_alias.loss_global == h_new.loss_global
-    assert h_alias.acc_global == h_new.acc_global
-    assert h_alias.sim_time == h_new.sim_time
+    make = ((lambda **kw: SimConfig(**_sim_kw(**kw))) if plane == "sim"
+            else (lambda **kw: LMRunConfig(n_workers=4, n_rounds=2, **kw)))
+    assert make().kernels == KernelConfig()
+    assert make(kernels=KernelConfig(backend="pallas")).kernels.use_pallas
+    with pytest.raises(ValueError, match="KernelConfig"):
+        make(kernels="pallas")

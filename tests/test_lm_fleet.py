@@ -1,21 +1,24 @@
 """DFL over real zoo architectures (dfl/lm_worker.py).
 
-Oracle ladder for the resident LM plane (PR 4):
-  * ``resident_fleet=False`` — per-call-flatten mixing + masked
-    train-all-N step: control plane bit-for-bit, params + optimizer state
+Oracle ladder for the resident LM plane:
+  * the per-call-flatten reference (``tests/engine_reference.py``: stacked
+    pytrees, ``fleet_mix_stacked`` + the masked train-all-N step) replaying
+    the engine's plans: control plane bit-for-bit, params + optimizer state
     to f32 tolerance, for EVERY optimizer family;
   * the planner-driven driver's control trajectory == an independently
     hand-rolled ``Mechanism.round`` loop, exactly;
   * ``worker_streams``'s stride-tricks gather == the scalar slicing loop,
     token-for-token (the rng draw order is the trajectory).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.aggregation import apply_mixing, mixing_matrix
-from repro.core.planner import PlannedRound
+from repro.core.planner import PlannedRound, bucket_key
 from repro.core.protocol import DySTop, RoundContext
 from repro.core.staleness import StalenessState
 from repro.core.trace import Trace
@@ -27,13 +30,15 @@ from repro.dfl.network import (EdgeNetwork, NetworkConfig,
 from repro.kernels.config import KernelConfig
 from repro.models import registry as R
 
+import engine_reference as ER
+
 
 def test_fleet_masked_step_moves_only_active():
     cfg = R.get_smoke_config("smollm-135m")
     n = 4
     fleet = LW.init_fleet(cfg, n, lr=1e-3)
     streams = LW.worker_streams(cfg, n, batch=2, seq=32)
-    step = LW.make_fleet_step(fleet)
+    step = ER.make_fleet_step(fleet)
     batch = {k: jnp.asarray(v) for k, v in next(streams).items()}
     active = jnp.asarray([True, False, True, False])
     p0 = fleet.stacked_params
@@ -54,10 +59,10 @@ def test_fleet_learns_and_aggregates():
     n = 3
     fleet = LW.init_fleet(cfg, n, lr=3e-3)
     streams = LW.worker_streams(cfg, n, batch=2, seq=32)
-    step = LW.make_fleet_step(fleet)
+    step = ER.make_fleet_step(fleet)
     alpha = jnp.full((n,), 1.0 / n)
     eval_batch = {k: jnp.asarray(v[0]) for k, v in next(streams).items()}
-    first = LW.fleet_eval(fleet, eval_batch, alpha)
+    first = ER.fleet_eval(fleet, eval_batch, alpha)
     mean_losses = []
     for t in range(8):
         batch = {k: jnp.asarray(v) for k, v in next(streams).items()}
@@ -73,7 +78,7 @@ def test_fleet_learns_and_aggregates():
             fleet.stacked_params, fleet.stacked_opt, batch, jnp.asarray(active))
         mean_losses.append(float(jnp.mean(losses)))
     # fixed held-out batch: the global weighted model improves
-    assert LW.fleet_eval(fleet, eval_batch, alpha) < first
+    assert ER.fleet_eval(fleet, eval_batch, alpha) < first
     # and local training losses trend down across the federation
     assert np.mean(mean_losses[-3:]) < np.mean(mean_losses[:3]) - 0.3
 
@@ -153,24 +158,69 @@ _CONTROL_FIELDS = ("rounds", "sim_time", "comm_gb", "staleness_avg",
 
 @pytest.mark.parametrize("optimizer", ["adam", "sgd", "adafactor"])
 def test_resident_matches_reflatten_oracle(optimizer):
-    """The persistent-flat engine == the per-call-flatten oracle: control
-    plane bit-for-bit, params AND optimizer state to f32 tolerance — for
-    every optimizer family (full moments, momentum-only, factored)."""
+    """The persistent-flat engine == the per-call-flatten reference on the
+    same plans: control plane bit-for-bit, params AND optimizer state to f32
+    tolerance — for every optimizer family (full moments, momentum-only,
+    factored)."""
     cfg = R.get_smoke_config("smollm-135m")
-    kw = _run_kw(optimizer=optimizer)
-    f_res, h_res = LW.run_lm_federation(
-        _mech(), cfg, LW.LMRunConfig(resident_fleet=True, **kw))
-    f_ora, h_ora = LW.run_lm_federation(
-        _mech(), cfg, LW.LMRunConfig(resident_fleet=False, **kw))
+    f_res, h_res, ref = ER.run_lm_with_reference(
+        _mech(), cfg, LW.LMRunConfig(**_run_kw(optimizer=optimizer)))
     for f in _CONTROL_FIELDS:
-        assert getattr(h_res, f) == getattr(h_ora, f), f
-    np.testing.assert_allclose(np.asarray(f_res.pbuf), np.asarray(f_ora.pbuf),
+        assert getattr(h_res, f) == getattr(ref.hist, f), f
+    np.testing.assert_allclose(np.asarray(f_res.pbuf), ref.pbuf,
                                rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(f_res.obuf), np.asarray(f_ora.obuf),
+    np.testing.assert_allclose(np.asarray(f_res.obuf), ref.obuf,
                                rtol=1e-4, atol=1e-5)
     # and the learning curves agree to eval tolerance
-    np.testing.assert_allclose(h_res.loss_global, h_ora.loss_global,
+    np.testing.assert_allclose(h_res.loss_global, ref.hist.loss_global,
                                rtol=1e-3)
+
+
+def test_lm_row_and_col_chunks_match_reference(monkeypatch):
+    """A run whose chunks take BOTH Eq. 4 contractions (``prefer_cols``
+    on the bucketed (k, u) shapes) equals the per-call-flatten reference.
+    f32 params: the two contractions sum in different orders, which bf16
+    storage would round to whole-ulp differences."""
+    picked = []
+    orig = LW.LMEngine.dispatch_chunk
+
+    def spy(self, *a, **kw):
+        picked.append(kw["col_sparse"])
+        return orig(self, *a, **kw)
+
+    monkeypatch.setattr(LW.LMEngine, "dispatch_chunk", spy)
+    cfg = dataclasses.replace(R.get_smoke_config("smollm-135m"),
+                              dtype="float32")
+    f, h, ref = ER.run_lm_with_reference(
+        DySTop(V=3.0, t_thre=3, max_neighbors=2, max_workers=2), cfg,
+        LW.LMRunConfig(**_run_kw(n_workers=16, n_rounds=8, batch=1, seq=8,
+                                 eval_every=4)))
+    assert set(picked) == {False, True}, picked
+    for fld in _CONTROL_FIELDS:
+        assert getattr(h, fld) == getattr(ref.hist, fld), fld
+    np.testing.assert_allclose(np.asarray(f.pbuf), ref.pbuf,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(f.obuf), ref.obuf,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(h.round_loss, ref.hist.round_loss, rtol=1e-5)
+
+
+def test_lm_idle_rounds_match_reference():
+    """Rounds with no activated worker (failures) dispatch chunks with
+    k_train 0, whose host-gathered batch is empty: the run still equals
+    the per-call-flatten reference."""
+    cfg = R.get_smoke_config("smollm-135m")
+    f, h, ref = ER.run_lm_with_reference(
+        _mech(), cfg, LW.LMRunConfig(**_run_kw(n_rounds=8, eval_every=4,
+                                               failure_prob=0.9)))
+    assert 0 in h.round_active
+    for fld in _CONTROL_FIELDS:
+        assert getattr(h, fld) == getattr(ref.hist, fld), fld
+    np.testing.assert_allclose(np.asarray(f.pbuf), ref.pbuf,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(f.obuf), ref.obuf,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(h.round_loss, ref.hist.round_loss, rtol=1e-6)
 
 
 def test_lm_scan_horizon_invariance():
@@ -231,9 +281,10 @@ def test_padding_rows_skip_train_step(fuse):
                               KernelConfig(), None)
 
     def dispatch(min_bucket):
+        key = bucket_key(chunk[0], n, min_bucket=min_bucket)
         out = engine.dispatch_chunk(
             jnp.asarray(p0), jnp.asarray(o0), chunk, tokens, labels,
-            col_sparse=False, fuse=fuse, min_bucket=min_bucket,
+            key=key, col_sparse=False, fuse=fuse, min_bucket=min_bucket,
             trace=Trace(LW.LMHistory()))
         return [np.asarray(x) for x in out]
 

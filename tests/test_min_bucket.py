@@ -84,10 +84,11 @@ def test_chunk_spans_min_bucket_controls_key_count():
             self.links = np.zeros((n, n), bool)
             self.mix_cols = None
 
-    plans = [P(k) for k in (1, 2, 3, 5, 7, 8, 4, 6)]
+    # k <= 7: with the padding column the column union stays within 8
+    plans = [P(k) for k in (1, 2, 3, 5, 7, 4, 6)]
     coarse = list(chunk_spans(plans, n, min_bucket=8))
     fine = list(chunk_spans(plans, n, min_bucket=1))
-    assert len({key for _, _, key in coarse}) == 1    # all k <= 8 -> one key
+    assert len({key for _, _, key in coarse}) == 1    # all k <= 7 -> one key
     assert len(coarse) == 1
     assert len({key for _, _, key in fine}) > 1
     assert len(fine) > len(coarse)

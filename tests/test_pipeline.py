@@ -4,17 +4,19 @@ The cardinal invariant: ``pipeline_depth`` NEVER changes a trajectory — the
 rng stream is the trajectory, and the pipeline only rewires host/device
 overlap.  Oracle ladder:
 
-  * unit — ``worker.pack_chunk`` (the pipelined fast packer) is bit-identical
-    to ``pack_horizon`` on every bucket-uniform chunk, across row/col-sparse
-    layouts, bucket sizes, planner-resolved and re-derived sparsity fields,
-    and the documented fallback cases (all-idle chunks, full-width unions);
-  * end-to-end sim — depth 1 == the depth-0 lockstep oracle across
-    ``scan_horizon`` x scenario presets: control plane exact, learning
-    curves to f32 tolerance (they are exact today, but the pinned contract
-    is f32);
-  * end-to-end LM — same at ``mesh_shards=1`` on the smoke zoo arch;
-  * sharded — depth invariance survives ``mesh_shards=2`` (multidevice
-    lane, skipped unless the backend exposes the devices);
+  * unit — ``worker.pack_chunk`` (the drive loops' fast packer) is
+    bit-identical to ``pack_horizon`` on every bucket-uniform chunk, across
+    row/col-sparse layouts, bucket sizes, planner-resolved and re-derived
+    sparsity fields, and the documented fallback cases (all-idle chunks,
+    full-width unions);
+  * end-to-end sim — the depth-1 engine == the plain per-round pytree
+    reference (``tests/engine_reference.py``) across ``scan_horizon`` x
+    scenario presets: control plane exact, learning curves to f32
+    tolerance; depth 2 == depth 1;
+  * end-to-end LM — the depth-1 engine == the per-call-flatten reference on
+    the smoke zoo arch;
+  * sharded — ``mesh_shards=2`` == ``mesh_shards=1`` at depth 1
+    (multidevice lane, skipped unless the backend exposes the devices);
   * resume — a depth-1 run resumed from a mid-run snapshot (a drained
     pipeline boundary by construction) finishes on the uninterrupted run's
     exact trajectory.  The real SIGKILL cycle rides scripts/chaos_check.py.
@@ -26,7 +28,7 @@ import pytest
 from repro.checkpoint import io as CIO
 from repro.core.aggregation import (col_union_mask, mixing_matrix,
                                     mixing_matrix_rows)
-from repro.core.planner import PlannedRound, bucket_key, chunk_spans
+from repro.core.planner import PlannedRound, chunk_spans
 from repro.core.protocol import DySTop
 from repro.core.trace import Trace
 from repro.dfl import lm_worker as LW
@@ -35,6 +37,8 @@ from repro.dfl.pipeline import DispatchPipeline
 from repro.dfl.simulator import History as SimHistory
 from repro.dfl.simulator import SimConfig, run_simulation
 from repro.models import registry as R
+
+from engine_reference import run_lm_with_reference, run_sim_with_reference
 
 N_DEV = jax.device_count()
 
@@ -54,20 +58,6 @@ def needs_devices(k: int):
 class _Token:
     def __init__(self):
         self.waited = False
-
-
-def test_pipeline_depth0_blocks_inline(monkeypatch):
-    waited = []
-    monkeypatch.setattr(jax, "block_until_ready",
-                        lambda tok: waited.append(tok))
-    pipe = DispatchPipeline(0, Trace(SimHistory()))
-    a, b = _Token(), _Token()
-    pipe.submit(a)
-    assert waited == [a]          # lockstep: every submit waits immediately
-    pipe.submit(b)
-    assert waited == [a, b]
-    pipe.drain()
-    assert waited == [a, b]       # nothing left in flight
 
 
 def test_pipeline_bounds_in_flight_and_drains_fifo(monkeypatch):
@@ -142,8 +132,7 @@ def test_pack_chunk_matches_pack_horizon(col_sparse, min_bucket, resolved):
     n = 32
     plans = _random_plans(n, 32, rng, resolved=resolved)
     seen_fast = 0
-    for lo, hi, key in chunk_spans(plans, n, col_sparse=col_sparse,
-                                   min_bucket=min_bucket):
+    for lo, hi, key in chunk_spans(plans, n, min_bucket=min_bucket):
         chunk = plans[lo:hi]
         ref = WK.pack_horizon(chunk, min_bucket=min_bucket,
                               col_sparse=col_sparse)
@@ -173,7 +162,7 @@ def test_pack_chunk_fallback_cases():
     # dense links: the column union goes full-width (u >= n), the
     # documented col-sparse fallback
     dense = _random_plans(n, 4, rng, dense_links=True)
-    for lo, hi, key in chunk_spans(dense, n, col_sparse=True, min_bucket=2):
+    for lo, hi, key in chunk_spans(dense, n, min_bucket=2):
         assert int(key[2]) >= n
         ref = WK.pack_horizon(dense[lo:hi], min_bucket=2, col_sparse=True)
         out = WK.pack_chunk(dense[lo:hi], key, min_bucket=2, col_sparse=True)
@@ -199,11 +188,10 @@ def test_planner_resolved_pad_fields_match_rederived():
                          synchronous=p.synchronous, W=p.W,
                          duration=p.duration, n_transfers=p.n_transfers)
             for p in resolved]
-    for cs in (False, True):
-        for (lo, hi, key), (lo2, hi2, key2) in zip(
-                chunk_spans(resolved, n, col_sparse=cs),
-                chunk_spans(bare, n, col_sparse=cs)):
-            assert (lo, hi, key) == (lo2, hi2, key2)
+    for (lo, hi, key), (lo2, hi2, key2) in zip(chunk_spans(resolved, n),
+                                               chunk_spans(bare, n)):
+        assert (lo, hi, key) == (lo2, hi2, key2)
+        for cs in (False, True):
             a = WK.pack_chunk(resolved[lo:hi], key, col_sparse=cs)
             b = WK.pack_chunk(bare[lo:hi], key, col_sparse=cs)
             for x, y in zip(a, b):
@@ -211,7 +199,7 @@ def test_planner_resolved_pad_fields_match_rederived():
 
 
 # --------------------------------------------------------------------------- #
-# end-to-end: depth 1 == the depth-0 lockstep oracle (sim plane)
+# end-to-end: the depth-1 engine == the plain reference (sim plane)
 # --------------------------------------------------------------------------- #
 
 _CONTROL_FIELDS = ("rounds", "sim_time", "comm_gb", "staleness_avg",
@@ -233,16 +221,16 @@ def _sim_cfg(**kw):
 @pytest.mark.parametrize("horizon", [1, 8])
 @pytest.mark.parametrize("scenario", ["churn20", "blackout"])
 def test_sim_depth1_matches_lockstep_oracle(horizon, scenario):
-    h0 = run_simulation(_mech(), _sim_cfg(scan_horizon=horizon,
-                                          scenario=scenario,
-                                          pipeline_depth=0))
-    h1 = run_simulation(_mech(), _sim_cfg(scan_horizon=horizon,
-                                          scenario=scenario,
-                                          pipeline_depth=1))
+    """The pipelined, chunked engine against the per-round pytree
+    reference fed the same batches: control plane exact, learning curves to
+    f32 rounding (dense vs sparse Eq. 4, AD vs fused Eq. 5)."""
+    h1, ref = run_sim_with_reference(
+        _mech(), _sim_cfg(scan_horizon=horizon, scenario=scenario,
+                          pipeline_depth=1))
     for f in _CONTROL_FIELDS:
-        assert getattr(h0, f) == getattr(h1, f), f
+        assert getattr(ref, f) == getattr(h1, f), f
     for f in _MODEL_FIELDS:
-        np.testing.assert_allclose(getattr(h0, f), getattr(h1, f),
+        np.testing.assert_allclose(getattr(ref, f), getattr(h1, f),
                                    rtol=1e-6, atol=1e-7, err_msg=f)
 
 
@@ -276,11 +264,19 @@ def test_sim_depth1_resume_is_bit_identical(tmp_path):
                                    rtol=1e-6, atol=1e-7, err_msg=f)
 
 
-def test_pipeline_depth_validation():
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        SimConfig(pipeline_depth=-1)
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        LW.LMRunConfig(pipeline_depth=-1)
+@pytest.mark.parametrize("plane", ["sim", "lm"])
+@pytest.mark.parametrize("depth", [-1, 0])
+def test_pipeline_depth_validation(plane, depth):
+    """One engine path: every depth runs the pipeline, so a depth below 1
+    is refused at construction, not silently run lockstep."""
+    make = SimConfig if plane == "sim" else LW.LMRunConfig
+    with pytest.raises(ValueError, match="pipeline_depth must be >= 1"):
+        make(pipeline_depth=depth)
+
+
+def test_dispatch_pipeline_rejects_depth_below_one():
+    with pytest.raises(ValueError, match=">= 1"):
+        DispatchPipeline(0, Trace(SimHistory()))
 
 
 # --------------------------------------------------------------------------- #
@@ -301,34 +297,34 @@ def _lm_kw(**kw):
 
 @pytest.mark.parametrize("horizon", [1, 8])
 def test_lm_depth1_matches_lockstep_oracle(horizon):
+    """The pipelined LM engine against the per-call-flatten reference on
+    the same plans and token streams."""
     cfg = R.get_smoke_config("smollm-135m")
-    f0, h0 = LW.run_lm_federation(
-        _lm_mech(), cfg,
-        LW.LMRunConfig(scan_horizon=horizon, pipeline_depth=0, **_lm_kw()))
-    f1, h1 = LW.run_lm_federation(
+    f1, h1, ref = run_lm_with_reference(
         _lm_mech(), cfg,
         LW.LMRunConfig(scan_horizon=horizon, pipeline_depth=1, **_lm_kw()))
+    h0 = ref.hist
     for f in _CONTROL_FIELDS:
         assert getattr(h0, f) == getattr(h1, f), f
-    # per-round losses drain at eval/history boundaries only on the
-    # pipelined path — values still match the lockstep oracle's
+    # per-round losses drain at eval/history boundaries only — values
+    # still match the per-round reference's
     np.testing.assert_allclose(h0.round_loss, h1.round_loss,
                                rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(h0.loss_global, h1.loss_global, rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(f0.pbuf), np.asarray(f1.pbuf),
+    np.testing.assert_allclose(ref.pbuf, np.asarray(f1.pbuf),
                                rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(f0.obuf), np.asarray(f1.obuf),
+    np.testing.assert_allclose(ref.obuf, np.asarray(f1.obuf),
                                rtol=1e-6, atol=1e-7)
 
 
 # --------------------------------------------------------------------------- #
-# sharded: depth invariance at mesh_shards=2 (multidevice lane)
+# sharded: the depth-1 engine at mesh_shards=2 (multidevice lane)
 # --------------------------------------------------------------------------- #
 
 
 @needs_devices(2)
 def test_sim_depth1_matches_oracle_sharded():
-    h0 = run_simulation(_mech(), _sim_cfg(mesh_shards=2, pipeline_depth=0))
+    h0 = run_simulation(_mech(), _sim_cfg(mesh_shards=1, pipeline_depth=1))
     h1 = run_simulation(_mech(), _sim_cfg(mesh_shards=2, pipeline_depth=1))
     for f in _CONTROL_FIELDS:
         assert getattr(h0, f) == getattr(h1, f), f

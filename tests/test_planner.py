@@ -342,10 +342,6 @@ def test_scan_horizon_history_invariance(horizon):
     hH = run_simulation(mech(), _cfg(scan_horizon=horizon))
     for f in _CONTROL_FIELDS + _MODEL_FIELDS:
         assert getattr(h1, f) == getattr(hH, f), f
-    # and the legacy per-leaf oracle still shares the whole control plane
-    hl = run_simulation(mech(), _cfg(fused_engine=False))
-    for f in _CONTROL_FIELDS:
-        assert getattr(h1, f) == getattr(hl, f), f
 
 
 def test_scan_horizon_invariance_under_sim_time_grid():

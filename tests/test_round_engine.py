@@ -1,14 +1,16 @@
-"""Fused round engine: flat-buffer equivalence with the legacy per-leaf path.
+"""Fused round engine: flat-buffer equivalence with the per-leaf pytree
+reference.
 
 Three layers of oracle:
   1. numerics — mix/train on the flat (N, P) buffer vs apply_mixing +
      local_train on the stacked pytree with IDENTICAL inputs (tight rtol);
   2. sparse aggregation — active-row gather/matmul/scatter vs the dense
      W @ X product over random masks (includes the Pallas kernel path);
-  3. end-to-end — run_simulation(fused) vs run_simulation(legacy): the
+  3. end-to-end — run_simulation vs the per-round pytree reference
+     (tests/engine_reference.py) replaying its planned rounds: the
      control-plane trajectory (sim time, comm, staleness, activations) must
-     match EXACTLY (same host rng stream), accuracy to a loose tolerance
-     (the two paths draw batches from different RNGs by design).
+     match EXACTLY, accuracy to a loose tolerance (the reference here draws
+     numpy batches, not the engine's device stream).
 """
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,8 @@ from repro.dfl import worker as WK
 from repro.dfl.simulator import SimConfig, run_simulation
 from repro.kernels import ops as K
 from repro.kernels.config import KernelConfig
+
+from engine_reference import run_sim_with_reference
 
 
 def _random_tree(key, n=12):
@@ -225,9 +229,11 @@ def _cfg(**kw):
 
 
 def test_fused_history_matches_legacy():
+    """The engine against the per-round pytree reference drawing its own
+    numpy batches: identical control plane, learning dynamics to tolerance
+    (different batch streams)."""
     mech = lambda: DySTop(V=10.0, t_thre=20, max_neighbors=5)
-    h_f = run_simulation(mech(), _cfg(fused_engine=True))
-    h_l = run_simulation(mech(), _cfg(fused_engine=False))
+    h_f, h_l = run_sim_with_reference(mech(), _cfg(), batches="host")
     # identical control plane: same rounds, times, comm, staleness, activity
     assert h_f.rounds == h_l.rounds
     np.testing.assert_allclose(h_f.sim_time, h_l.sim_time, rtol=0)

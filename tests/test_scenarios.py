@@ -243,22 +243,19 @@ def _cfg(**kw):
 
 @pytest.mark.parametrize("preset", ["churn20", "blackout"])
 def test_preset_replays_bit_identically_across_engines(preset):
-    """Fused (any horizon) and legacy engines share the scenario trajectory
-    bit-for-bit; fused horizons also share the learning curve exactly."""
+    """Every horizon shares the scenario trajectory bit-for-bit, control
+    plane and learning curve alike."""
     mech = lambda: DySTop(V=10.0, t_thre=8, max_neighbors=4)
     h1 = run_simulation(mech(), _cfg(scenario=preset, scan_horizon=1))
     h8 = run_simulation(mech(), _cfg(scenario=preset, scan_horizon=8))
-    hl = run_simulation(mech(), _cfg(scenario=preset, fused_engine=False))
     for f in _CONTROL_FIELDS + _MODEL_FIELDS:
         assert getattr(h1, f) == getattr(h8, f), f
-    for f in _CONTROL_FIELDS:
-        assert getattr(h1, f) == getattr(hl, f), f
 
 
 def test_simulation_resume_is_bit_identical(tmp_path):
     """Kill-free half of the chaos check: resume from a mid-run snapshot and
-    finish with the uninterrupted run's exact trajectory (fused engine; the
-    legacy path and the real-SIGKILL cycle ride scripts/chaos_check.py)."""
+    finish with the uninterrupted run's exact trajectory (the real-SIGKILL
+    cycle rides scripts/chaos_check.py)."""
     mech = lambda: DySTop(V=10.0, t_thre=8, max_neighbors=4)
     ref = run_simulation(mech(), _cfg(scenario="churn20"))
     ck = _cfg(scenario="churn20", checkpoint_every=10,

@@ -12,8 +12,6 @@ Oracle ladder (ISSUE 5 acceptance):
     bit-exact vs the single-device engine, learning curves / model state to
     f32 reduction-order tolerance, for N both divisible and NOT divisible
     by the shard count;
-  * the host-side LM batch-gather path (``host_batch_gather``) equals the
-    ship-full-N path bit-for-bit (single-device, runs in tier-1).
 
 Multi-device cases skip unless the backend exposes enough devices — CI runs
 them in the ``tests-multidevice`` lane under
@@ -289,17 +287,26 @@ def test_sim_sharded_matches_oracle_n100(shards):
 
 
 @needs_devices(2)
-def test_sim_sharded_row_sparse_path(shards=2):
-    """The row-sparse mix (col_sparse_mix off) exercises the psum lowering
-    + the zero-padded W columns; control stays exact."""
+def test_sim_sharded_row_sparse_path(monkeypatch, shards=2):
+    """The row-sparse mix exercises the psum lowering + the zero-padded W
+    columns; control stays exact.  At N=10 with 8-row buckets every chunk's
+    column union is as wide as its rows, so ``prefer_cols`` picks rows."""
+    picked = []
+    for name in ("round_step", "mega_round_step"):
+        orig = getattr(WK, name)
+
+        def spy(*a, _orig=orig, **kw):
+            picked.append(kw["col_sparse"])
+            return _orig(*a, **kw)
+
+        monkeypatch.setattr(WK, name, spy)
     h1 = run_simulation(_sim_mech(),
                         _sim_cfg(n_workers=10, n_rounds=12, eval_every=6,
-                                 n_samples=1500, col_sparse_mix=False,
-                                 mesh_shards=1))
+                                 n_samples=1500, mesh_shards=1))
     hs = run_simulation(_sim_mech(),
                         _sim_cfg(n_workers=10, n_rounds=12, eval_every=6,
-                                 n_samples=1500, col_sparse_mix=False,
-                                 mesh_shards=shards))
+                                 n_samples=1500, mesh_shards=shards))
+    assert picked and not any(picked), picked
     for f in _CONTROL_FIELDS:
         assert getattr(hs, f) == getattr(h1, f), f
     np.testing.assert_allclose(hs.acc_global, h1.acc_global, atol=2e-2)
@@ -319,14 +326,6 @@ def test_sim_mesh_with_kernel_composes():
     for f in _CONTROL_FIELDS:
         assert getattr(hs, f) == getattr(h1, f), f
     np.testing.assert_allclose(hs.acc_global, h1.acc_global, atol=2e-2)
-
-
-def test_sim_mesh_requires_fused_engine():
-    """mesh_shards on the legacy path must raise, not silently run
-    unsharded — the whole point of the knob is the memory partition."""
-    with pytest.raises(ValueError, match="fused"):
-        run_simulation(_sim_mech(),
-                       _sim_cfg(mesh_shards=2, fused_engine=False))
 
 
 def _lm_kw(**kw):
@@ -365,31 +364,3 @@ def test_lm_sharded_matches_oracle(n_workers, shards, min_bucket):
     np.testing.assert_allclose(np.asarray(fs.obuf), np.asarray(f1.obuf),
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(hs.loss_global, h1.loss_global, rtol=1e-3)
-
-
-def test_lm_host_batch_gather_matches_device_gather():
-    """The host-side k-row batch gather ships (H, k, B, S) instead of
-    (H, N, B, S); same values reach the train step, so the fleets match
-    (single-device — this is a transfer-path refactor, not a numeric one)."""
-    cfg = R.get_smoke_config("smollm-135m")
-    kw = _lm_kw(n_workers=6)
-    f_on, h_on = LW.run_lm_federation(
-        _lm_mech(), cfg, LW.LMRunConfig(host_batch_gather=True, **kw))
-    f_off, h_off = LW.run_lm_federation(
-        _lm_mech(), cfg, LW.LMRunConfig(host_batch_gather=False, **kw))
-    for f in _CONTROL_FIELDS:
-        assert getattr(h_on, f) == getattr(h_off, f), f
-    assert h_on.round_loss == h_off.round_loss
-    np.testing.assert_allclose(np.asarray(f_on.pbuf), np.asarray(f_off.pbuf),
-                               rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(f_on.obuf), np.asarray(f_off.obuf),
-                               rtol=1e-6, atol=1e-7)
-
-
-def test_lm_mesh_requires_resident_fleet():
-    cfg = R.get_smoke_config("smollm-135m")
-    with pytest.raises(ValueError, match="resident"):
-        LW.run_lm_federation(
-            _lm_mech(), cfg,
-            LW.LMRunConfig(resident_fleet=False, mesh_shards=2,
-                           **_lm_kw(n_workers=4)))

@@ -1,7 +1,8 @@
 """Column-sparse mixing + fused local-steps SGD: oracle equivalence.
 
-The PR 3 engine defaults (``SimConfig.col_sparse_mix``,
-``SimConfig.fused_local_sgd``) are pinned against three oracles:
+The engine's two selected lowerings — the column-sparse Eq. 4 contraction
+(picked per chunk by ``aggregation.prefer_cols``) and the fused SGD lowering
+(``worker.fused_sgd_supported``) — are pinned against three oracles:
 
   1. kernel — ``aggregate_rows_cols`` (Pallas, interpret on CPU) vs the
      dense ``W @ X`` product and the row-sparse ``aggregate_rows`` path,
@@ -9,9 +10,10 @@ The PR 3 engine defaults (``SimConfig.col_sparse_mix``,
      empty rounds;
   2. lowering — ``local_sgd_flat_fused`` (unrolled manual backward) vs the
      per-step AD scan ``local_sgd_flat`` on identical batches;
-  3. trajectory — ``run_simulation`` with the new defaults vs both flags
-     off (the PR 2 engine): control-plane histories EXACTLY equal (same
-     host rng stream), learning curves to f32-rounding tolerance.
+  3. trajectory — ``run_simulation`` vs the per-round pytree reference
+     (tests/engine_reference.py) on the same batches: control-plane
+     histories EXACTLY equal, learning curves to f32-rounding tolerance,
+     including runs whose chunks take both contractions.
 """
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,8 @@ from repro.dfl.simulator import SimConfig, run_simulation
 from repro.kernels import ops as K
 from repro.kernels.config import KernelConfig
 from repro.kernels.ref import aggregate_rows_cols_ref
+
+from engine_reference import run_sim_with_reference
 
 
 def _random_round(rng, n, act_frac, link_p=0.15):
@@ -313,7 +317,7 @@ def test_mega_round_step_col_sparse_matches_sequential():
 
 
 # --------------------------------------------------------------------------- #
-# end-to-end: new defaults vs the PR 2 oracle engine
+# end-to-end: the engine vs the plain pytree reference
 # --------------------------------------------------------------------------- #
 
 
@@ -326,10 +330,8 @@ def _cfg(**kw):
 
 def test_new_engine_history_matches_pr2_oracle():
     mech = lambda: DySTop(V=10.0, t_thre=10, max_neighbors=5)
-    h_new = run_simulation(mech(), _cfg())          # both new flags default-on
-    h_old = run_simulation(mech(), _cfg(col_sparse_mix=False,
-                                        fused_local_sgd=False))
-    # bit-for-bit identical control plane (same host rng stream)
+    h_new, h_old = run_sim_with_reference(mech(), _cfg())
+    # bit-for-bit identical control plane (same planned rounds)
     assert h_new.rounds == h_old.rounds
     np.testing.assert_allclose(h_new.sim_time, h_old.sim_time, rtol=0)
     np.testing.assert_allclose(h_new.comm_gb, h_old.comm_gb, rtol=0)
@@ -341,6 +343,34 @@ def test_new_engine_history_matches_pr2_oracle():
     np.testing.assert_allclose(h_new.acc_global, h_old.acc_global, atol=0.03)
     np.testing.assert_allclose(h_new.loss_global, h_old.loss_global,
                                rtol=0.05, atol=0.02)
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_engine_row_and_col_chunks_match_reference(backend, monkeypatch):
+    """A run whose chunks take BOTH Eq. 4 contractions (``prefer_cols``
+    picks rows for wide unions, columns for narrow ones) equals the dense
+    pytree reference: control exact, curves to f32 rounding."""
+    picked = []
+    for name in ("round_step", "mega_round_step"):
+        orig = getattr(WK, name)
+
+        def spy(*a, _orig=orig, **kw):
+            picked.append(kw["col_sparse"])
+            return _orig(*a, **kw)
+
+        monkeypatch.setattr(WK, name, spy)
+    kernels = KernelConfig(backend=backend)
+    h, ref = run_sim_with_reference(
+        DySTop(V=10.0, t_thre=8, max_neighbors=2),
+        _cfg(n_rounds=24, eval_every=6, hidden=16, n_samples=1200, dim=8,
+             min_bucket=2, kernels=kernels))
+    assert set(picked) == {False, True}, picked
+    for f in ("rounds", "sim_time", "comm_gb", "staleness_avg",
+              "staleness_max", "round_durations", "round_active"):
+        assert getattr(h, f) == getattr(ref, f), f
+    for f in ("acc_global", "acc_local", "loss_global"):
+        np.testing.assert_allclose(getattr(h, f), getattr(ref, f),
+                                   rtol=1e-6, atol=1e-7, err_msg=f)
 
 
 def test_new_engine_reproducible_and_horizon_invariant():

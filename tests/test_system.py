@@ -1,10 +1,14 @@
 """End-to-end behaviour tests for the DySTop system (integration level)."""
+import json
+import pathlib
+
 import numpy as np
 import pytest
 
 from repro.core.baselines import MATCHA, AsyDFL, SAADFL, get_mechanism
 from repro.core.protocol import DySTop
 from repro.dfl.simulator import SimConfig, run_simulation
+from repro.kernels.config import KernelConfig
 
 
 def _cfg(**kw):
@@ -69,7 +73,8 @@ def test_non_iid_hurts_everyone_less_dystop():
 
 def test_kernel_aggregation_path_in_simulator():
     h = run_simulation(DySTop(V=10.0, t_thre=10),
-                       _cfg(n_rounds=8, eval_every=8, use_kernel=True))
+                       _cfg(n_rounds=8, eval_every=8,
+                            kernels=KernelConfig(backend="pallas")))
     assert np.isfinite(h.acc_global[-1])
 
 
@@ -92,3 +97,19 @@ def test_edge_dynamics_failures():
     hist_m = run_simulation(MATCHA(), _cfg(n_rounds=20, eval_every=20,
                                            failure_prob=0.1))
     assert np.isfinite(hist_m.acc_global[-1])
+
+
+_CONFIGS = sorted((pathlib.Path(__file__).parent.parent / "chipbench"
+                   / "configs").glob("*.json"))
+
+
+@pytest.mark.parametrize("path", _CONFIGS, ids=lambda p: p.stem)
+def test_benchmark_run_blocks_configure_the_engine(path):
+    """Every key a benchmark configuration's ``run`` block passes is a
+    field of its plane's engine config, and lands there as given."""
+    from repro.dfl.lm_worker import LMRunConfig
+    c = json.loads(path.read_text())
+    make = SimConfig if c["plane"] == "sim" else LMRunConfig
+    cfg = make(checkpoint_dir="unused", **c["run"])
+    for k, v in c["run"].items():
+        assert getattr(cfg, k) == v, k

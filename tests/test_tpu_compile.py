@@ -166,7 +166,7 @@ def test_lm_mega_round_skips_padding_in_place(one_chip):
                         opt=stacked(jax.eval_shape(opt.init, params)))
     p, o = spec.params.n_params, spec.opt.n_params
     mega = LW.LMEngine(cfg, opt, spec, kernels=kern)._mega(
-        col_sparse=False, fuse=True, pregather=True)
+        col_sparse=False, fuse=True)
     txt = mega.lower(
         _sds((n, p), one_chip), _sds((n, o), one_chip),
         _sds((h, k, n), one_chip), _sds((h, 3 * k), one_chip, jnp.int32),
@@ -211,7 +211,7 @@ def test_mamba2_mega_round_fits_one_chip(one_chip, tmp_path):
                         opt=stacked(jax.eval_shape(opt.init, params)))
     p, o = spec.params.n_params, spec.opt.n_params
     mega = LW.LMEngine(cfg, opt, spec, kernels=kern)._mega(
-        col_sparse=False, fuse=True, pregather=True)
+        col_sparse=False, fuse=True)
     compiled = mega.lower(
         _sds((n, p), one_chip), _sds((n, o), one_chip),
         _sds((h, k, n), one_chip), _sds((h, 3 * k), one_chip, jnp.int32),

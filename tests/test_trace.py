@@ -207,7 +207,7 @@ def test_mix_narrow_rounds_counts_the_vpu_body(plane, monkeypatch):
     assert h.counts["mix_narrow_rounds"] == (mixing if plane == "lm" else 0)
 
 
-@pytest.mark.parametrize("depth", [0, 1])
+@pytest.mark.parametrize("depth", [1, 2])
 def test_sim_counters_at_every_pipeline_depth(depth):
     h = run_simulation(DySTop(V=10.0, t_thre=8, max_neighbors=4),
                        SimConfig(n_workers=16, n_rounds=12, hidden=16,
